@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..models import shardings
 from ..optim import compression
 
 REDUCE_MODES = ("allreduce", "hierarchical", "compressed")
@@ -147,15 +146,15 @@ def mapreduce_value_and_grad(
             def local2(params, batch):
                 l, g, _, a = local(params, batch, None)
                 return l, g, a
-            fm = shardings.shard_map_compat(
-                local2, mesh,
+            fm = jax.shard_map(
+                local2, mesh=mesh,
                 in_specs=in_specs[:2],
                 out_specs=(P(), jax.tree.map(lambda _: P(), params), P()),
                 axis_names=set(dp), check_vma=False)
             l, g, a = fm(params, batch)
             return l, g, None, a
-        fm = shardings.shard_map_compat(
-            lambda p, b, e: local(p, b, e), mesh,
+        fm = jax.shard_map(
+            lambda p, b, e: local(p, b, e), mesh=mesh,
             in_specs=in_specs, out_specs=out_specs,
             axis_names=set(dp), check_vma=False)
         return fm(params, batch, err)
@@ -191,8 +190,8 @@ def map_reduce_job(
 
     def run(params, batch):
         out_spec = P() if reduce in ("sum", "mean") else batch_spec
-        fm = shardings.shard_map_compat(
-            local, mesh,
+        fm = jax.shard_map(
+            local, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), params),
                       jax.tree.map(lambda _: batch_spec, batch)),
             out_specs=jax.tree.map(lambda _: out_spec, jax.eval_shape(

@@ -1,7 +1,7 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-"""Shared Pallas-TPU helpers (version compat + interpret-mode fallback).
+"""Shared Pallas-TPU helper: the interpret-mode rule.
 
 Every kernel package in this tree (``flash_attention``, ``rbm_cd``,
 ``paged_attention``, ``ragged_prefill``) follows the same shape: ``kernel.py`` holds the
@@ -15,13 +15,6 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` on new jax, ``pltpu.TPUCompilerParams`` on old."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
 
 
 def on_cpu() -> bool:

@@ -13,10 +13,12 @@ Two kernel bodies cover every paged decode family in ``models.cache_spec``:
 * ``_paged_decode_kernel`` — vanilla GQA (mask ``idx <= pos``) and
   sliding-window page *rings* (``window > 0``: absolute positions are
   recovered from the ring layout and masked to the window, exactly the
-  reference ring rule).  Grid ``(B, K, n_pages)``; the innermost dimension
-  sweeps the request's pages with online-softmax state (running max ``m``,
-  normalizer ``l``, accumulator ``acc``) in fp32 VMEM scratch.  GQA never
-  replicates KV: the q block is the ``G = H // K`` head group of one KV head.
+  reference ring rule).  Grid ``(B, n_pages)``; the inner dimension sweeps
+  the request's pages with online-softmax state (running max ``m``,
+  normalizer ``l``, accumulator ``acc``) in fp32 VMEM scratch, one set per
+  KV head.  Each grid step fetches a whole page (every KV head) and each
+  head's ``G = H // K`` query group attends its slice — GQA never
+  replicates KV, and a page crosses HBM once per step.
 * ``_mla_paged_decode_kernel`` — DeepSeek-style absorbed-latent decode.
   Scores are ``q_eff·ckv + q_rope·krope`` against the rank-``L`` latent pages
   (one shared "KV head"); the context accumulator stays in latent space
@@ -45,31 +47,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import tpu_compiler_params
 
 NEG_INF = float("-inf")
 
 
-def _online_softmax_update(s, v, m_scr, l_scr, acc_scr):
+def _online_softmax_update(s, v, m_ref, l_ref, acc_ref):
     """Fold one masked score block ``s`` ([rows, ps]) and its values ``v``
-    ([ps, d]) into the running (m, l, acc) scratch state."""
-    m_prev = m_scr[...]
-    l_prev = l_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    ([ps, d]) into the running (m, l, acc) scratch state.  ``m``/``l`` are
+    [rows, 1] columns: kept 2-D so no lane-to-sublane shape cast is needed
+    to broadcast them against ``s`` and ``acc``."""
+    m_prev = m_ref[...]
+    l_prev = l_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     # guard fully-masked rows (m_new == -inf)
-    safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p = jnp.exp(s - safe_m[:, None])
-    p = jnp.where(jnp.isfinite(m_new)[:, None], p, 0.0)
+    finite = jnp.isfinite(m_new)
+    safe_m = jnp.where(finite, m_new, 0.0)
+    p = jnp.where(finite, jnp.exp(s - safe_m), 0.0)
     alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - safe_m), 0.0)
-    l_scr[...] = l_prev * alpha + jnp.sum(p, axis=1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+    l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+    m_ref[...] = m_new
 
 
-def _finish(o_ref, m_scr, l_scr, acc_scr):
-    o_ref[0, 0] = (acc_scr[...]
-                   / jnp.maximum(l_scr[...], 1e-20)[:, None]).astype(o_ref.dtype)
+def _normalized(l_ref, acc_ref):
+    return acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
 
 
 def _init(m_scr, l_scr, acc_scr):
@@ -91,15 +93,57 @@ def _page_mask(s, page_idx, pos, *, page_size, window, ring):
     return (k_abs >= 0) & (k_abs <= pos) & (k_abs > pos - window)
 
 
+def page_head(ref, kh, scale_ref=None):
+    """KV head ``kh`` (static) of a whole-page block ``ref`` [1, ps, K, D] as
+    fp32 [ps, D], dequantized in-register when ``scale_ref`` ([1, ps, K]
+    per-token-per-head scales) is given: f32(q8) * f32(bf16 scale) — the
+    page DMA moved int8, half the bf16 bytes.
+
+    The block spans every KV head of the page because the (8, 128) tiling
+    rule forbids a one-head block of the [P, ps, K, D] pool; a static head
+    index is a strided sublane load Mosaic accepts for bf16 and int8 alike."""
+    x = ref[0, :, kh, :].astype(jnp.float32)
+    if scale_ref is not None:
+        x = x * scale_ref[0].astype(jnp.float32)[:, kh:kh + 1]
+    return x
+
+
+def _fold_page(s, v, i, pos, m_ref, l_ref, acc_ref, *, page_size,
+               window=0, ring=0, row_ok=None):
+    """Mask page ``i``'s scores ``s`` [rows, ps] at per-row positions
+    ``pos`` (and live rows ``row_ok``) and fold them with values ``v`` into
+    the online-softmax state."""
+    valid = _page_mask(s, i, pos, page_size=page_size, window=window,
+                       ring=ring)
+    if row_ok is not None:
+        valid = valid & row_ok
+    _online_softmax_update(jnp.where(valid, s, NEG_INF), v, m_ref, l_ref,
+                           acc_ref)
+
+
+def _attend_page(q, k, v, i, pos, m_ref, l_ref, acc_ref, *, scale, softcap,
+                 **mask):
+    """Scores of query rows ``q`` [rows, D] against one page's keys ``k``,
+    folded into the online-softmax state (``mask``: ``_fold_page``'s)."""
+    # scale after the dot, the reference ordering, so the two backends'
+    # fp32 scores round identically
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if softcap:
+        s = softcap * jnp.tanh(s / softcap)
+    _fold_page(s, v, i, pos, m_ref, l_ref, acc_ref, **mask)
+
+
 def _paged_decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
                          page_size: int, scale: float, softcap: float,
                          window: int, ring: int, quantized: bool):
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
+        ks_ref = vs_ref = None
         o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _():
@@ -112,28 +156,38 @@ def _paged_decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(live)
     def _():
-        q = q_ref[0, 0].astype(jnp.float32)                  # [G, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)               # [ps, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)               # [ps, D]
-        if quantized:
-            # in-register dequant: f32(q8) * f32(bf16 per-token scale) — the
-            # HBM gather above moved int8, half the bf16 bytes
-            k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        # scale after the dot, the reference ordering, so the two backends'
-        # fp32 scores round identically
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap:
-            s = softcap * jnp.tanh(s / softcap)
-        valid = _page_mask(s, i, pos, page_size=page_size, window=window,
-                           ring=ring)
-        _online_softmax_update(jnp.where(valid, s, NEG_INF), v,
-                               m_scr, l_scr, acc_scr)
+        for kh in range(q_ref.shape[1]):       # each KV head of the page
+            _attend_page(q_ref[0, kh].astype(jnp.float32),     # [G, D]
+                         page_head(k_ref, kh, ks_ref),
+                         page_head(v_ref, kh, vs_ref), i, pos,
+                         m_scr.at[kh], l_scr.at[kh], acc_scr.at[kh],
+                         page_size=page_size, scale=scale, softcap=softcap,
+                         window=window, ring=ring)
 
-    @pl.when(i == pl.num_programs(2) - 1)
+    @pl.when(i == pl.num_programs(1) - 1)
     def _():
-        _finish(o_ref, m_scr, l_scr, acc_scr)
+        for kh in range(q_ref.shape[1]):
+            o_ref[0, kh] = _normalized(l_scr.at[kh], acc_scr.at[kh]).astype(
+                o_ref.dtype)
+
+
+def _kv_specs(ps, K, D, index_map, quantized):
+    """Whole-page K/V blocks [1, ps, K, D] (plus [1, ps, K] scale blocks when
+    int8): the last two block dims equal the pool's, as Mosaic requires."""
+    page = pl.BlockSpec((1, ps, K, D), lambda *a: index_map(*a) + (0, 0, 0))
+    specs = [page, page]
+    if quantized:
+        sc = pl.BlockSpec((1, ps, K), lambda *a: index_map(*a) + (0, 0))
+        specs += [sc, sc]
+    return specs
+
+
+def _online_scratch(rows, D):
+    """fp32 (m, l, acc) online-softmax state for query rows ``rows`` (a
+    shape tuple, e.g. (K, G) or (H,)) of width ``D``."""
+    return [pltpu.VMEM(rows + (1,), jnp.float32),
+            pltpu.VMEM(rows + (1,), jnp.float32),
+            pltpu.VMEM(rows + (D,), jnp.float32)]
 
 
 def paged_decode_fwd(q, k_pages, v_pages, tables, pos, *, scale: float,
@@ -144,7 +198,8 @@ def paged_decode_fwd(q, k_pages, v_pages, tables, pos, *, scale: float,
     [B, K, G, D].  ``window > 0`` treats the table as a page ring of
     ``n_pages * ps`` token slots.  ``k_scale``/``v_scale``: [P, ps, K] bf16
     per-token-per-head absmax scales when the pool is int8-quantized — the
-    kernel dequantizes in-register after the page DMA."""
+    kernel dequantizes in-register after the page DMA.  Grid ``(B,
+    n_pages)``: each page is fetched once and every KV head attends it."""
     B, K, G, D = q.shape
     ps = k_pages.shape[1]
     n_pages = tables.shape[1]
@@ -152,50 +207,40 @@ def paged_decode_fwd(q, k_pages, v_pages, tables, pos, *, scale: float,
     kernel = functools.partial(
         _paged_decode_kernel, page_size=ps, scale=scale, softcap=softcap,
         window=window, ring=n_pages * ps, quantized=quantized)
-    page_spec = pl.BlockSpec((1, ps, 1, D),
-                             lambda b, kh, i, tr, pr: (tr[b, i], 0, kh, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, G, D), lambda b, kh, i, tr, pr: (b, kh, 0, 0)),
-        page_spec,
-        page_spec,
-    ]
+    in_specs = [pl.BlockSpec((1, K, G, D), lambda b, i, tr, pr: (b, 0, 0, 0))]
+    in_specs += _kv_specs(ps, K, D, lambda b, i, tr, pr: (tr[b, i],),
+                          quantized)
     operands = [tables, pos, q, k_pages, v_pages]
     if quantized:
-        scale_spec = pl.BlockSpec((1, ps, 1),
-                                  lambda b, kh, i, tr, pr: (tr[b, i], 0, kh))
-        in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, K, n_pages),
+        grid=(B, n_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, kh, i, tr, pr: (b, kh, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, K, G, D),
+                               lambda b, i, tr, pr: (b, 0, 0, 0)),
+        scratch_shapes=_online_scratch((K, G), D),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
 
 
 def _paged_verify_kernel(tables_ref, pos_ref, nq_ref, q_ref, k_ref, v_ref,
                          *rest, page_size: int, scale: float, softcap: float,
-                         window: int, ring: int, quantized: bool):
+                         window: int, ring: int, quantized: bool, G: int):
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
+        ks_ref = vs_ref = None
         o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _():
@@ -210,32 +255,22 @@ def _paged_verify_kernel(tables_ref, pos_ref, nq_ref, q_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _():
-        q = q_ref[0, 0].astype(jnp.float32)                  # [Q, G, D]
-        Q, G, D = q.shape
-        q = q.reshape(Q * G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)               # [ps, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)               # [ps, D]
-        if quantized:
-            k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap:
-            s = softcap * jnp.tanh(s / softcap)
-        # flattened row j*G + g is query j of head group g, at absolute
-        # position pos + j — the decode mask evaluated per row
-        qi = jax.lax.broadcasted_iota(jnp.int32, (Q, G), 0).reshape(Q * G, 1)
-        valid = _page_mask(s, i, pos + qi, page_size=page_size,
-                           window=window, ring=ring)
-        valid = valid & (qi < n_q)
-        _online_softmax_update(jnp.where(valid, s, NEG_INF), v,
-                               m_scr, l_scr, acc_scr)
+        # row j*G + g is query j of head group g, at absolute position
+        # pos + j — the decode mask evaluated per row
+        qi = jax.lax.broadcasted_iota(jnp.int32, (q_ref.shape[2], 1), 0) // G
+        for kh in range(q_ref.shape[1]):
+            _attend_page(q_ref[0, kh].astype(jnp.float32),    # [Q*G, D]
+                         page_head(k_ref, kh, ks_ref),
+                         page_head(v_ref, kh, vs_ref), i, pos + qi,
+                         m_scr.at[kh], l_scr.at[kh], acc_scr.at[kh],
+                         page_size=page_size, scale=scale, softcap=softcap,
+                         window=window, ring=ring, row_ok=qi < n_q)
 
-    @pl.when(i == pl.num_programs(2) - 1)
+    @pl.when(i == pl.num_programs(1) - 1)
     def _():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[...], 1e-20)[:, None]).reshape(
-                           o_ref.shape[2:]).astype(o_ref.dtype)
+        for kh in range(q_ref.shape[1]):
+            o_ref[0, kh] = _normalized(l_scr.at[kh], acc_scr.at[kh]).astype(
+                o_ref.dtype)
 
 
 def paged_verify_fwd(q, k_pages, v_pages, tables, pos, n_q, *, scale: float,
@@ -253,41 +288,55 @@ def paged_verify_fwd(q, k_pages, v_pages, tables, pos, n_q, *, scale: float,
     quantized = k_scale is not None
     kernel = functools.partial(
         _paged_verify_kernel, page_size=ps, scale=scale, softcap=softcap,
-        window=window, ring=n_pages * ps, quantized=quantized)
-    page_spec = pl.BlockSpec(
-        (1, ps, 1, D), lambda b, kh, i, tr, pr, nr: (tr[b, i], 0, kh, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, Q, G, D),
-                     lambda b, kh, i, tr, pr, nr: (b, kh, 0, 0, 0)),
-        page_spec,
-        page_spec,
-    ]
-    operands = [tables, pos, n_q, q, k_pages, v_pages]
+        window=window, ring=n_pages * ps, quantized=quantized, G=G)
+    # the (Q, G) query rows flatten outside the kernel: an in-kernel
+    # [Q, G, D] -> [Q*G, D] reshape is a shape cast Mosaic refuses for G % 8
+    q_spec = pl.BlockSpec((1, K, Q * G, D),
+                          lambda b, i, tr, pr, nr: (b, 0, 0, 0))
+    in_specs = [q_spec] + _kv_specs(
+        ps, K, D, lambda b, i, tr, pr, nr: (tr[b, i],), quantized)
+    operands = [tables, pos, n_q, q.reshape(B, K, Q * G, D), k_pages, v_pages]
     if quantized:
-        scale_spec = pl.BlockSpec(
-            (1, ps, 1), lambda b, kh, i, tr, pr, nr: (tr[b, i], 0, kh))
-        in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, K, n_pages),
+        grid=(B, n_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, Q, G, D),
-                               lambda b, kh, i, tr, pr, nr: (b, kh, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Q * G,), jnp.float32),
-            pltpu.VMEM((Q * G,), jnp.float32),
-            pltpu.VMEM((Q * G, D), jnp.float32),
-        ],
+        out_specs=q_spec,
+        scratch_shapes=_online_scratch((K, Q * G), D),
     )
-    return pl.pallas_call(
+    o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, Q, G, D), q.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((B, K, Q * G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
+    return o.reshape(B, K, Q, G, D)
+
+
+def _mla_page(ckv_ref, krope_ref, cs_ref, rs_ref):
+    """One latent page as fp32 ([ps, L], [ps, R]).  int8 pages carry one
+    scale per latent token slot ([1, ps, 1] blocks; the latent vector is the
+    quantization granule), so the dequantized ckv that feeds the latent
+    accumulator picks up the scales too."""
+    ckv = ckv_ref[0].astype(jnp.float32)
+    kr = krope_ref[0].astype(jnp.float32)
+    if cs_ref is not None:
+        ckv = ckv * cs_ref[0].astype(jnp.float32)
+        kr = kr * rs_ref[0].astype(jnp.float32)
+    return ckv, kr
+
+
+def _mla_attend_page(qe, qr, ckv, kr, i, pos, m_scr, l_scr, acc_scr, *,
+                     scale, **mask):
+    s = jax.lax.dot_general(qe, ckv, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    s = s + jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+    # context accumulates in latent space: acc += p @ ckv  -> [rows, L]
+    _fold_page(s * scale, ckv, i, pos, m_scr, l_scr, acc_scr, **mask)
 
 
 def _mla_paged_decode_kernel(tables_ref, pos_ref, q_eff_ref, q_rope_ref,
@@ -296,6 +345,7 @@ def _mla_paged_decode_kernel(tables_ref, pos_ref, q_eff_ref, q_rope_ref,
     if quantized:
         cs_ref, rs_ref, ctx_ref, m_scr, l_scr, acc_scr = rest
     else:
+        cs_ref = rs_ref = None
         ctx_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
     i = pl.program_id(1)
@@ -308,31 +358,36 @@ def _mla_paged_decode_kernel(tables_ref, pos_ref, q_eff_ref, q_rope_ref,
 
     @pl.when(i * page_size <= pos)
     def _():
-        qe = q_eff_ref[0].astype(jnp.float32)                # [H, L]
-        qr = q_rope_ref[0].astype(jnp.float32)               # [H, R]
-        ckv = ckv_ref[0].astype(jnp.float32)                 # [ps, L]
-        kr = krope_ref[0].astype(jnp.float32)                # [ps, R]
-        if quantized:
-            # one scale per latent token slot (the latent vector is the
-            # quantization granule); dequantized ckv also feeds the latent
-            # accumulator below, so context picks up the scales too
-            ckv = ckv * cs_ref[0].astype(jnp.float32)[:, None]
-            kr = kr * rs_ref[0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(qe, ckv, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s + jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-        s = s * scale                                        # [H, ps]
-        valid = _page_mask(s, i, pos, page_size=page_size, window=0, ring=0)
-        # context accumulates in latent space: acc += p @ ckv  -> [H, L]
-        _online_softmax_update(jnp.where(valid, s, NEG_INF), ckv,
-                               m_scr, l_scr, acc_scr)
+        ckv, kr = _mla_page(ckv_ref, krope_ref, cs_ref, rs_ref)
+        _mla_attend_page(q_eff_ref[0].astype(jnp.float32),      # [H, L]
+                         q_rope_ref[0].astype(jnp.float32),     # [H, R]
+                         ckv, kr, i, pos, m_scr, l_scr, acc_scr,
+                         page_size=page_size, scale=scale)
 
     @pl.when(i == pl.num_programs(1) - 1)
     def _():
-        ctx_ref[0] = (acc_scr[...]
-                      / jnp.maximum(l_scr[...], 1e-20)[:, None]).astype(
-                          ctx_ref.dtype)
+        ctx_ref[0] = _normalized(l_scr, acc_scr).astype(ctx_ref.dtype)
+
+
+def _mla_specs(H, L, R, ps, index_map, quantized):
+    """q blocks [1, rows, L] / [1, rows, R], latent page blocks, and — when
+    int8 — [1, ps, 1] scale blocks of the [P, ps, 1] scale view."""
+    specs = [
+        pl.BlockSpec((1, H, L), lambda b, i, *_: (b, 0, 0)),
+        pl.BlockSpec((1, H, R), lambda b, i, *_: (b, 0, 0)),
+        pl.BlockSpec((1, ps, L), lambda *a: index_map(*a) + (0, 0)),
+        pl.BlockSpec((1, ps, R), lambda *a: index_map(*a) + (0, 0)),
+    ]
+    if quantized:
+        sc = pl.BlockSpec((1, ps, 1), lambda *a: index_map(*a) + (0, 0))
+        specs += [sc, sc]
+    return specs
+
+
+def scale_view(s):
+    """[P, ps] latent scales as [P, ps, 1]: a page's block then equals the
+    array's last two dims, which the (8, 128) tiling rule requires."""
+    return s.reshape(s.shape[:2] + (1,))
 
 
 def mla_paged_decode_fwd(q_eff, q_rope, ckv_pages, krope_pages, tables, pos,
@@ -350,33 +405,23 @@ def mla_paged_decode_fwd(q_eff, q_rope, ckv_pages, krope_pages, tables, pos,
     quantized = ckv_scale is not None
     kernel = functools.partial(_mla_paged_decode_kernel, page_size=ps,
                                scale=scale, quantized=quantized)
-    in_specs = [
-        pl.BlockSpec((1, H, L), lambda b, i, tr, pr: (b, 0, 0)),
-        pl.BlockSpec((1, H, R), lambda b, i, tr, pr: (b, 0, 0)),
-        pl.BlockSpec((1, ps, L), lambda b, i, tr, pr: (tr[b, i], 0, 0)),
-        pl.BlockSpec((1, ps, R), lambda b, i, tr, pr: (tr[b, i], 0, 0)),
-    ]
+    in_specs = _mla_specs(H, L, R, ps, lambda b, i, tr, pr: (tr[b, i],),
+                          quantized)
     operands = [tables, pos, q_eff, q_rope, ckv_pages, krope_pages]
     if quantized:
-        scale_spec = pl.BlockSpec((1, ps), lambda b, i, tr, pr: (tr[b, i], 0))
-        in_specs += [scale_spec, scale_spec]
-        operands += [ckv_scale, krope_scale]
+        operands += [scale_view(ckv_scale), scale_view(krope_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, n_pages),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, H, L), lambda b, i, tr, pr: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H, L), jnp.float32),
-        ],
+        scratch_shapes=_online_scratch((H,), L),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, L), q_eff.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -384,10 +429,12 @@ def mla_paged_decode_fwd(q_eff, q_rope, ckv_pages, krope_pages, tables, pos,
 
 def _mla_paged_verify_kernel(tables_ref, pos_ref, nq_ref, q_eff_ref,
                              q_rope_ref, ckv_ref, krope_ref, *rest,
-                             page_size: int, scale: float, quantized: bool):
+                             page_size: int, scale: float, quantized: bool,
+                             H: int):
     if quantized:
         cs_ref, rs_ref, ctx_ref, m_scr, l_scr, acc_scr = rest
     else:
+        cs_ref = rs_ref = None
         ctx_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
     i = pl.program_id(1)
@@ -401,32 +448,18 @@ def _mla_paged_verify_kernel(tables_ref, pos_ref, nq_ref, q_eff_ref,
 
     @pl.when(i * page_size <= pos + n_q - 1)
     def _():
-        qe = q_eff_ref[0].astype(jnp.float32)                # [Q, H, L]
-        Q, H, L = qe.shape
-        qe = qe.reshape(Q * H, L)
-        qr = q_rope_ref[0].astype(jnp.float32).reshape(Q * H, -1)
-        ckv = ckv_ref[0].astype(jnp.float32)                 # [ps, L]
-        kr = krope_ref[0].astype(jnp.float32)                # [ps, R]
-        if quantized:
-            ckv = ckv * cs_ref[0].astype(jnp.float32)[:, None]
-            kr = kr * rs_ref[0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(qe, ckv, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s + jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-        s = s * scale                                        # [Q*H, ps]
-        qi = jax.lax.broadcasted_iota(jnp.int32, (Q, H), 0).reshape(Q * H, 1)
-        valid = _page_mask(s, i, pos + qi, page_size=page_size, window=0,
-                           ring=0)
-        valid = valid & (qi < n_q)
-        _online_softmax_update(jnp.where(valid, s, NEG_INF), ckv,
-                               m_scr, l_scr, acc_scr)
+        ckv, kr = _mla_page(ckv_ref, krope_ref, cs_ref, rs_ref)
+        # row j*H + h is query j of head h, at absolute position pos + j
+        qi = jax.lax.broadcasted_iota(jnp.int32, (q_eff_ref.shape[1], 1),
+                                      0) // H
+        _mla_attend_page(q_eff_ref[0].astype(jnp.float32),      # [Q*H, L]
+                         q_rope_ref[0].astype(jnp.float32),     # [Q*H, R]
+                         ckv, kr, i, pos + qi, m_scr, l_scr, acc_scr,
+                         page_size=page_size, scale=scale, row_ok=qi < n_q)
 
     @pl.when(i == pl.num_programs(1) - 1)
     def _():
-        ctx_ref[0] = (acc_scr[...]
-                      / jnp.maximum(l_scr[...], 1e-20)[:, None]).reshape(
-                          ctx_ref.shape[1:]).astype(ctx_ref.dtype)
+        ctx_ref[0] = _normalized(l_scr, acc_scr).astype(ctx_ref.dtype)
 
 
 def mla_paged_verify_fwd(q_eff, q_rope, ckv_pages, krope_pages, tables, pos,
@@ -442,36 +475,28 @@ def mla_paged_verify_fwd(q_eff, q_rope, ckv_pages, krope_pages, tables, pos,
     n_pages = tables.shape[1]
     quantized = ckv_scale is not None
     kernel = functools.partial(_mla_paged_verify_kernel, page_size=ps,
-                               scale=scale, quantized=quantized)
-    in_specs = [
-        pl.BlockSpec((1, Q, H, L), lambda b, i, tr, pr, nr: (b, 0, 0, 0)),
-        pl.BlockSpec((1, Q, H, R), lambda b, i, tr, pr, nr: (b, 0, 0, 0)),
-        pl.BlockSpec((1, ps, L), lambda b, i, tr, pr, nr: (tr[b, i], 0, 0)),
-        pl.BlockSpec((1, ps, R), lambda b, i, tr, pr, nr: (tr[b, i], 0, 0)),
-    ]
-    operands = [tables, pos, n_q, q_eff, q_rope, ckv_pages, krope_pages]
+                               scale=scale, quantized=quantized, H=H)
+    in_specs = _mla_specs(Q * H, L, R, ps,
+                          lambda b, i, tr, pr, nr: (tr[b, i],), quantized)
+    # query rows flatten outside the kernel (no in-kernel shape cast)
+    operands = [tables, pos, n_q, q_eff.reshape(B, Q * H, L),
+                q_rope.reshape(B, Q * H, R), ckv_pages, krope_pages]
     if quantized:
-        scale_spec = pl.BlockSpec((1, ps),
-                                  lambda b, i, tr, pr, nr: (tr[b, i], 0))
-        in_specs += [scale_spec, scale_spec]
-        operands += [ckv_scale, krope_scale]
+        operands += [scale_view(ckv_scale), scale_view(krope_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, n_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Q, H, L),
-                               lambda b, i, tr, pr, nr: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Q * H,), jnp.float32),
-            pltpu.VMEM((Q * H,), jnp.float32),
-            pltpu.VMEM((Q * H, L), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, Q * H, L),
+                               lambda b, i, tr, pr, nr: (b, 0, 0)),
+        scratch_shapes=_online_scratch((Q * H,), L),
     )
-    return pl.pallas_call(
+    ctx = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Q, H, L), q_eff.dtype),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((B, Q * H, L), q_eff.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
+    return ctx.reshape(B, Q, H, L)

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import tpu_compiler_params
+from ..paged_attention.kernel import page_head, scale_view
 
 # the reference mask constant (models.attention.NEG_INF): finite, so a
 # fully-masked row softmaxes to the same uniform distribution the reference
@@ -52,71 +52,136 @@ from .. import tpu_compiler_params
 NEG_INF = -1e30
 
 
-def _store_scores(s_scr, seg, q_abs, s, valid):
-    s_scr[:, pl.ds(seg, s.shape[1])] = jnp.where(valid, s, NEG_INF)
+# The score scratch is *key-major*: [table tokens, query rows].  A page's
+# scores land at a dynamic sublane offset (a multiple of the page size),
+# which Mosaic accepts; the query-major layout would need a dynamic lane
+# offset that is not a multiple of 128, which it refuses.
+SCORE_VMEM_BYTES = 16 * 2**20     # budget of one grid step's score scratch
+VMEM_LIMIT_BYTES = 32 * 2**20     # scoped VMEM limit of the prefill kernels
+
+
+def score_bytes(n_tokens: int, rows: int) -> int:
+    """VMEM bytes of an fp32 [n_tokens, rows] score scratch (lanes pad to
+    128)."""
+    return n_tokens * (-(-rows // 128) * 128) * 4
+
+
+def fit_q_block(T: int, group: int, n_tokens: int, q_blk: int = 128) -> int:
+    """The query block for a ``T``-token chunk of ``group`` rows per token
+    against an ``n_tokens``-wide table: at most ``q_blk`` (and ``T`` rounded
+    up to 8), halved until the score scratch fits ``SCORE_VMEM_BYTES``.
+    Raises when even an 8-token block does not fit: the widest table a
+    kernel compiles for is ``SCORE_VMEM_BYTES / (4 * 128)`` = 32,768 tokens
+    for ``8 * group <= 128``."""
+    blk = min(q_blk, -(-T // 8) * 8)
+    while blk > 8 and score_bytes(n_tokens, blk * group) > SCORE_VMEM_BYTES:
+        blk = max(8, (blk // 2) // 8 * 8)
+    if score_bytes(n_tokens, blk * group) > SCORE_VMEM_BYTES:
+        raise ValueError(
+            f"prefill table of {n_tokens} tokens needs "
+            f"{score_bytes(n_tokens, blk * group)} B of score scratch, over "
+            f"the {SCORE_VMEM_BYTES} B budget")
+    return blk
+
+
+def _scores_t(q, k, scale, softcap=0.0):
+    """Key-major fp32 scores [ps, rows] = k @ q.T, scaled after the dot and
+    soft-capped after the scale (the reference order)."""
+    s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if softcap:
+        s = softcap * jnp.tanh(s / softcap)
+    return s
+
+
+def _store_scores(s_scr, seg, s, valid):
+    s_scr[pl.ds(pl.multiple_of(seg, s.shape[0]), s.shape[0]), :] = jnp.where(
+        valid, s, NEG_INF)
+
+
+def _mask_page(s_scr, seg, page_size):
+    """A page wholly past a block's last query: all-causal-masked, so its
+    scores are the NEG_INF fill the reference mask produces (no dot)."""
+    s_scr[pl.ds(pl.multiple_of(seg, page_size), page_size), :] = jnp.full(
+        (page_size, s_scr.shape[1]), NEG_INF, jnp.float32)
 
 
 def _softmax_rows(s_scr):
-    """One softmax over each row's full key set, at the true global max —
-    the same formulation (and degenerate all-masked behavior) as
+    """One softmax over each query row's full key set, at the true global
+    max — the same formulation (and degenerate all-masked behavior) as
     ``jax.nn.softmax`` in the reference chunked path."""
-    s_scr[...] = jax.nn.softmax(s_scr[...], axis=-1)
+    s_scr[...] = jax.nn.softmax(s_scr[...], axis=0)
 
 
 def _pv_accumulate(acc_scr, s_scr, seg, v, v_dtype):
     """Fold one page of the PV product: probabilities are rounded to the
     value dtype first (the reference's ``a.astype(v.dtype)``), accumulation
     stays fp32."""
-    p = s_scr[:, pl.ds(seg, v.shape[0])].astype(v_dtype).astype(jnp.float32)
+    p = s_scr[pl.ds(pl.multiple_of(seg, v.shape[0]), v.shape[0]), :]
+    p = p.astype(v_dtype).astype(jnp.float32)                # [ps, rows]
     acc_scr[...] += jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _kv_head(ref, kh, scale_ref=None):
+    """KV head ``kh`` (a grid index) of a whole-page block [1, ps, K, D] as
+    fp32 [ps, D]: a select over the page's static head slices — Mosaic
+    cannot prove a dynamic sublane index aligned to the packed tiling."""
+    x = page_head(ref, 0, scale_ref)
+    for h in range(1, ref.shape[2]):
+        x = jnp.where(kh == h, page_head(ref, h, scale_ref), x)
+    return x
+
+
+def _page_spec(ps, K, D, page_of):
+    """Whole-page [1, ps, K, D] block (all KV heads) of page ``page_of``;
+    the last two block dims equal the pool's, as Mosaic's tiling requires."""
+    return pl.BlockSpec((1, ps, K, D), lambda *a: (page_of(*a), 0, 0, 0))
+
+
+def _scale_spec(ps, K, page_of):
+    return pl.BlockSpec((1, ps, K), lambda *a: (page_of(*a), 0, 0))
 
 
 # ------------------------------------------------------------- vanilla GQA
 
 def _ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref, k_ref,
                            v_ref, *rest, page_size: int, n_pages: int,
-                           q_blk: int, scale: float, softcap: float, v_dtype,
-                           quantized: bool):
+                           q_blk: int, G: int, scale: float, softcap: float,
+                           v_dtype, quantized: bool):
     if quantized:
         ks_ref, vs_ref, o_ref, s_scr, acc_scr = rest
     else:
+        ks_ref = vs_ref = None
         o_ref, s_scr, acc_scr = rest
     b = pl.program_id(0)
+    kh = pl.program_id(1)
     qb = pl.program_id(2)
     i = pl.program_id(3)
     start = start_ref[b]
-    T, G, D = q_ref.shape[2], q_ref.shape[3], q_ref.shape[4]
-    rows = T * G
+    rows = q_ref.shape[2]                    # q_blk * G (token, group) rows
     j = jnp.where(i < n_pages, i, i - n_pages)
     # absolute query position of each (token, head-group) row
     q_abs = start + qb * q_blk \
-        + jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0) // G
+        + jax.lax.broadcasted_iota(jnp.int32, (page_size, rows), 1) // G
 
     @pl.when(i < n_pages)
     def _():
         k_abs = j * page_size \
-            + jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 1)
+            + jax.lax.broadcasted_iota(jnp.int32, (page_size, rows), 0)
         # a page wholly past this block's last query is all-causal-masked;
         # skip the dot, the NEG_INF fill is what the reference mask produces
         live_page = j * page_size <= start + qb * q_blk + q_blk - 1
 
         @pl.when(live_page)
         def _():
-            q = q_ref[0, 0].astype(jnp.float32).reshape(rows, D)
-            k = k_ref[0, :, 0].astype(jnp.float32)               # [ps, D]
-            if quantized:
-                k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            if softcap:
-                s = softcap * jnp.tanh(s / softcap)
-            _store_scores(s_scr, j * page_size, q_abs, s, k_abs <= q_abs)
+            s = _scores_t(q_ref[0, 0].astype(jnp.float32),      # [rows, D]
+                          _kv_head(k_ref, kh, ks_ref), scale, softcap)
+            _store_scores(s_scr, j * page_size, s, k_abs <= q_abs)
 
         @pl.when(jnp.logical_not(live_page))
         def _():
-            s_scr[:, pl.ds(j * page_size, page_size)] = jnp.full(
-                (rows, page_size), NEG_INF, jnp.float32)
+            _mask_page(s_scr, j * page_size, page_size)
 
     @pl.when(i == n_pages - 1)
     def _():
@@ -128,14 +193,12 @@ def _ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref, k_ref,
 
     @pl.when(i >= n_pages)
     def _():
-        v = v_ref[0, :, 0].astype(jnp.float32)                   # [ps, D]
-        if quantized:
-            v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        _pv_accumulate(acc_scr, s_scr, j * page_size, v, v_dtype)
+        _pv_accumulate(acc_scr, s_scr, j * page_size,
+                       _kv_head(v_ref, kh, vs_ref), v_dtype)
 
     @pl.when(i == 2 * n_pages - 1)
     def _():
-        o_ref[0, 0] = acc_scr[...].reshape(T, G, D).astype(o_ref.dtype)
+        o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
 
 
 def ragged_prefill_fwd(q, k_pages, v_pages, tables, start, n_live, *,
@@ -157,48 +220,45 @@ def ragged_prefill_fwd(q, k_pages, v_pages, tables, start, n_live, *,
     # runs keep fp32 probabilities exactly like the reference dequant path
     kernel = functools.partial(
         _ragged_prefill_kernel, page_size=ps, n_pages=n_pages, q_blk=q_blk,
-        scale=scale, softcap=softcap,
+        G=G, scale=scale, softcap=softcap,
         v_dtype=jnp.float32 if quantized else v_pages.dtype,
         quantized=quantized)
 
-    def _page_map(b, kh, qb, i, tr, st, nl):
-        return (tr[b, jnp.where(i < n_pages, i, i - n_pages)], 0, kh, 0)
+    def page_of(b, kh, qb, i, tr, st, nl):
+        return tr[b, jnp.where(i < n_pages, i, i - n_pages)]
 
-    def _scale_map(b, kh, qb, i, tr, st, nl):
-        return (tr[b, jnp.where(i < n_pages, i, i - n_pages)], 0, kh)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, q_blk, G, D),
-                     lambda b, kh, qb, i, tr, st, nl: (b, kh, qb, 0, 0)),
-        pl.BlockSpec((1, ps, 1, D), _page_map),
-        pl.BlockSpec((1, ps, 1, D), _page_map),
-    ]
-    operands = [tables, start, n_live, q, k_pages, v_pages]
+    # (token, group) rows flatten outside the kernel: an in-kernel
+    # [T, G, D] -> [T*G, D] reshape is a shape cast Mosaic refuses
+    q_spec = pl.BlockSpec((1, 1, q_blk * G, D),
+                          lambda b, kh, qb, i, tr, st, nl: (b, kh, qb, 0))
+    in_specs = [q_spec, _page_spec(ps, K, D, page_of),
+                _page_spec(ps, K, D, page_of)]
+    operands = [tables, start, n_live, q.reshape(B, K, T * G, D), k_pages,
+                v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, ps, 1), _scale_map),
-                     pl.BlockSpec((1, ps, 1), _scale_map)]
+        in_specs += [_scale_spec(ps, K, page_of), _scale_spec(ps, K, page_of)]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, K, n_qb, 2 * n_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, q_blk, G, D),
-            lambda b, kh, qb, i, tr, st, nl: (b, kh, qb, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((q_blk * G, n_pages * ps), jnp.float32),
+            pltpu.VMEM((n_pages * ps, q_blk * G), jnp.float32),
             pltpu.VMEM((q_blk * G, D), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, T, G, D), q.dtype),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((B, K, T * G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                 "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*operands)
+    return o.reshape(B, K, T, G, D)
 
 
 # ------------------------------------------------------ sliding-window ring
@@ -206,30 +266,31 @@ def ragged_prefill_fwd(q, k_pages, v_pages, tables, start, n_live, *,
 def _windowed_ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref,
                                     kn_ref, vn_ref, k_ref, v_ref, *rest,
                                     page_size: int, n_ring: int, n_fresh: int,
-                                    q_blk: int, window: int, scale: float,
-                                    softcap: float, v_dtype,
+                                    q_blk: int, G: int, window: int,
+                                    scale: float, softcap: float, v_dtype,
                                     quantized: bool):
     if quantized:
         ks_ref, vs_ref, o_ref, s_scr, acc_scr = rest
     else:
+        ks_ref = vs_ref = None
         o_ref, s_scr, acc_scr = rest
     b = pl.program_id(0)
+    kh = pl.program_id(1)
     qb = pl.program_id(2)
     i = pl.program_id(3)
     start = start_ref[b]
     n_live = n_live_ref[b]
-    T, G, D = q_ref.shape[2], q_ref.shape[3], q_ref.shape[4]
-    rows = T * G
+    rows = q_ref.shape[2]
     n_kv = n_ring + n_fresh
     j = jnp.where(i < n_kv, i, i - n_kv)
     ring_n = n_ring * page_size
     q_abs = start + qb * q_blk \
-        + jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0) // G
-    col = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 1)
+        + jax.lax.broadcasted_iota(jnp.int32, (page_size, rows), 1) // G
+    col = jax.lax.broadcasted_iota(jnp.int32, (page_size, rows), 0)
 
     @pl.when(i < n_kv)
     def _():
-        q = q_ref[0, 0].astype(jnp.float32).reshape(rows, D)
+        q = q_ref[0, 0].astype(jnp.float32)                      # [rows, D]
 
         @pl.when(j < n_ring)
         def _():
@@ -240,16 +301,10 @@ def _windowed_ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref,
             last = start - 1
             k_abs = last - ((last % ring_n - idx) % ring_n)
             valid = (k_abs >= 0) & (k_abs > q_abs - window)
-            k = k_ref[0, :, 0].astype(jnp.float32)
-            if quantized:
-                # only the resident ring pages are int8; the fresh chunk's
-                # K/V below ride in at model dtype
-                k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            if softcap:
-                s = softcap * jnp.tanh(s / softcap)
-            _store_scores(s_scr, j * page_size, q_abs, s, valid)
+            # only the resident ring pages are int8; the fresh chunk's K/V
+            # below ride in at model dtype
+            s = _scores_t(q, _kv_head(k_ref, kh, ks_ref), scale, softcap)
+            _store_scores(s_scr, j * page_size, s, valid)
 
         @pl.when(j >= n_ring)
         def _():
@@ -257,12 +312,8 @@ def _windowed_ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref,
             k_abs = start + jf * page_size + col
             valid = (k_abs <= q_abs) & (k_abs > q_abs - window) \
                 & (jf * page_size + col < n_live)
-            k = kn_ref[0, :, 0].astype(jnp.float32)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            if softcap:
-                s = softcap * jnp.tanh(s / softcap)
-            _store_scores(s_scr, j * page_size, q_abs, s, valid)
+            s = _scores_t(q, _kv_head(kn_ref, kh), scale, softcap)
+            _store_scores(s_scr, j * page_size, s, valid)
 
     @pl.when(i == n_kv - 1)
     def _():
@@ -274,16 +325,13 @@ def _windowed_ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref,
 
     @pl.when(i >= n_kv)
     def _():
-        vr = v_ref[0, :, 0].astype(jnp.float32)
-        if quantized:
-            vr = vr * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        vf = vn_ref[0, :, 0].astype(jnp.float32)
-        vsel = jnp.where(j < n_ring, vr, vf)
+        vsel = jnp.where(j < n_ring, _kv_head(v_ref, kh, vs_ref),
+                         _kv_head(vn_ref, kh))
         _pv_accumulate(acc_scr, s_scr, j * page_size, vsel, v_dtype)
 
     @pl.when(i == 2 * n_kv - 1)
     def _():
-        o_ref[0, 0] = acc_scr[...].reshape(T, G, D).astype(o_ref.dtype)
+        o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
 
 
 def windowed_ragged_prefill_fwd(q, k_new, v_new, k_pages, v_pages, tables,
@@ -308,57 +356,55 @@ def windowed_ragged_prefill_fwd(q, k_new, v_new, k_pages, v_pages, tables,
     quantized = k_scale is not None
     kernel = functools.partial(
         _windowed_ragged_prefill_kernel, page_size=ps, n_ring=n_ring,
-        n_fresh=n_fresh, q_blk=q_blk, window=window, scale=scale,
+        n_fresh=n_fresh, q_blk=q_blk, G=G, window=window, scale=scale,
         softcap=softcap,
         v_dtype=jnp.float32 if quantized else v_pages.dtype,
         quantized=quantized)
 
-    def _ring_map(b, kh, qb, i, tr, st, nl):
+    def ring_page(b, kh, qb, i, tr, st, nl):
         j = jnp.where(i < n_kv, i, i - n_kv)
-        return (tr[b, jnp.minimum(j, n_ring - 1)], 0, kh, 0)
+        return tr[b, jnp.minimum(j, n_ring - 1)]
 
-    def _ring_scale_map(b, kh, qb, i, tr, st, nl):
+    def fresh_map(b, kh, qb, i, tr, st, nl):
         j = jnp.where(i < n_kv, i, i - n_kv)
-        return (tr[b, jnp.minimum(j, n_ring - 1)], 0, kh)
+        return (b, jnp.clip(j - n_ring, 0, n_fresh - 1), 0, 0)
 
-    def _fresh_map(b, kh, qb, i, tr, st, nl):
-        j = jnp.where(i < n_kv, i, i - n_kv)
-        return (b, jnp.clip(j - n_ring, 0, n_fresh - 1), kh, 0)
-
+    q_spec = pl.BlockSpec((1, 1, q_blk * G, D),
+                          lambda b, kh, qb, i, tr, st, nl: (b, kh, qb, 0))
     in_specs = [
-        pl.BlockSpec((1, 1, q_blk, G, D),
-                     lambda b, kh, qb, i, tr, st, nl: (b, kh, qb, 0, 0)),
-        pl.BlockSpec((1, ps, 1, D), _fresh_map),
-        pl.BlockSpec((1, ps, 1, D), _fresh_map),
-        pl.BlockSpec((1, ps, 1, D), _ring_map),
-        pl.BlockSpec((1, ps, 1, D), _ring_map),
+        q_spec,
+        pl.BlockSpec((1, ps, K, D), fresh_map),
+        pl.BlockSpec((1, ps, K, D), fresh_map),
+        _page_spec(ps, K, D, ring_page),
+        _page_spec(ps, K, D, ring_page),
     ]
-    operands = [tables, start, n_live, q, k_new, v_new, k_pages, v_pages]
+    operands = [tables, start, n_live, q.reshape(B, K, T * G, D), k_new,
+                v_new, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, ps, 1), _ring_scale_map),
-                     pl.BlockSpec((1, ps, 1), _ring_scale_map)]
+        in_specs += [_scale_spec(ps, K, ring_page),
+                     _scale_spec(ps, K, ring_page)]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, K, n_qb, 2 * n_kv),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, q_blk, G, D),
-            lambda b, kh, qb, i, tr, st, nl: (b, kh, qb, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((q_blk * G, n_kv * ps), jnp.float32),
+            pltpu.VMEM((n_kv * ps, q_blk * G), jnp.float32),
             pltpu.VMEM((q_blk * G, D), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, T, G, D), q.dtype),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((B, K, T * G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                 "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*operands)
+    return o.reshape(B, K, T, G, D)
 
 
 # ------------------------------------------------------ MLA materialized-K
@@ -378,12 +424,12 @@ def _mla_ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref,
     T, E = q_ref.shape[2], q_ref.shape[3]
     j = jnp.where(i < n_pages, i, i - n_pages)
     q_abs = start + qb * q_blk \
-        + jax.lax.broadcasted_iota(jnp.int32, (T, page_size), 0)
+        + jax.lax.broadcasted_iota(jnp.int32, (page_size, T), 1)
 
     @pl.when(i < n_pages)
     def _():
         k_abs = j * page_size \
-            + jax.lax.broadcasted_iota(jnp.int32, (T, page_size), 1)
+            + jax.lax.broadcasted_iota(jnp.int32, (page_size, T), 0)
         live_page = j * page_size <= start + qb * q_blk + q_blk - 1
 
         @pl.when(live_page)
@@ -391,24 +437,21 @@ def _mla_ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref,
             ckv = ckv_ref[0].astype(jnp.float32)                 # [ps, L]
             kr = kr_ref[0].astype(jnp.float32)                   # [ps, R]
             if quantized:
-                ckv = ckv * cs_ref[0].astype(jnp.float32)[:, None]
-                kr = kr * rs_ref[0].astype(jnp.float32)[:, None]
-            wuk = wuk_ref[:, 0].astype(jnp.float32)              # [L, nope]
+                ckv = ckv * cs_ref[0].astype(jnp.float32)
+                kr = kr * rs_ref[0].astype(jnp.float32)
+            wuk = wuk_ref[0].astype(jnp.float32)                 # [L, nope]
             # materialize this page's per-head K, rounded to the cache dtype
             # exactly where the reference ``ckv @ wkv_b`` einsum rounds
             k_nope = jax.lax.dot_general(
                 ckv, wuk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32).astype(kv_dtype)
             k = jnp.concatenate([k_nope.astype(jnp.float32), kr], axis=-1)
-            q = q_ref[0, 0].astype(jnp.float32)                  # [T, E]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            _store_scores(s_scr, j * page_size, q_abs, s, k_abs <= q_abs)
+            s = _scores_t(q_ref[0, 0].astype(jnp.float32), k, scale)
+            _store_scores(s_scr, j * page_size, s, k_abs <= q_abs)
 
         @pl.when(jnp.logical_not(live_page))
         def _():
-            s_scr[:, pl.ds(j * page_size, page_size)] = jnp.full(
-                (T, page_size), NEG_INF, jnp.float32)
+            _mask_page(s_scr, j * page_size, page_size)
 
     @pl.when(i == n_pages - 1)
     def _():
@@ -422,8 +465,8 @@ def _mla_ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref,
     def _():
         ckv = ckv_ref[0].astype(jnp.float32)
         if quantized:
-            ckv = ckv * cs_ref[0].astype(jnp.float32)[:, None]
-        wuv = wuv_ref[:, 0].astype(jnp.float32)                  # [L, vd]
+            ckv = ckv * cs_ref[0].astype(jnp.float32)
+        wuv = wuv_ref[0].astype(jnp.float32)                     # [L, vd]
         v = jax.lax.dot_general(
             ckv, wuv, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32).astype(kv_dtype)
@@ -440,7 +483,8 @@ def mla_ragged_prefill_fwd(q, ckv_pages, krope_pages, w_uk, w_uv, tables,
                            ckv_scale=None, krope_scale=None,
                            interpret: bool = False):
     """q: [B, H, T, nope+rope] (rope part roped); ckv_pages: [P, ps, L];
-    krope_pages: [P, ps, R]; w_uk: [L, H, nope]; w_uv: [L, H, vd]; tables:
+    krope_pages: [P, ps, R]; w_uk: [H, L, nope]; w_uv: [H, L, vd] (head-
+    major, so a head's block spans the array's last two dims); tables:
     [B, n_pages].  Returns the attended values [B, H, T, vd].
     ``ckv_scale``/``krope_scale``: [P, ps] bf16 scales when the latent pages
     are int8 — the dequantized latent is fp32, so the in-kernel K/V
@@ -463,23 +507,23 @@ def mla_ragged_prefill_fwd(q, ckv_pages, krope_pages, w_uk, w_uv, tables,
         return (tr[b, jnp.where(i < n_pages, i, i - n_pages)], 0, 0)
 
     def _scale_map(b, h, qb, i, tr, st, nl):
-        return (tr[b, jnp.where(i < n_pages, i, i - n_pages)], 0)
+        return (tr[b, jnp.where(i < n_pages, i, i - n_pages)], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, q_blk, E),
                      lambda b, h, qb, i, tr, st, nl: (b, h, qb, 0)),
         pl.BlockSpec((1, ps, L), _page_map),
         pl.BlockSpec((1, ps, krope_pages.shape[2]), _page_map),
-        pl.BlockSpec((L, 1, w_uk.shape[2]),
-                     lambda b, h, qb, i, tr, st, nl: (0, h, 0)),
-        pl.BlockSpec((L, 1, vd),
-                     lambda b, h, qb, i, tr, st, nl: (0, h, 0)),
+        pl.BlockSpec((1, L, w_uk.shape[2]),
+                     lambda b, h, qb, i, tr, st, nl: (h, 0, 0)),
+        pl.BlockSpec((1, L, vd),
+                     lambda b, h, qb, i, tr, st, nl: (h, 0, 0)),
     ]
     operands = [tables, start, n_live, q, ckv_pages, krope_pages, w_uk, w_uv]
     if quantized:
-        in_specs += [pl.BlockSpec((1, ps), _scale_map),
-                     pl.BlockSpec((1, ps), _scale_map)]
-        operands += [ckv_scale, krope_scale]
+        in_specs += [pl.BlockSpec((1, ps, 1), _scale_map),
+                     pl.BlockSpec((1, ps, 1), _scale_map)]
+        operands += [scale_view(ckv_scale), scale_view(krope_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, H, n_qb, 2 * n_pages),
@@ -488,7 +532,7 @@ def mla_ragged_prefill_fwd(q, ckv_pages, krope_pages, w_uk, w_uv, tables,
             (1, 1, q_blk, vd),
             lambda b, h, qb, i, tr, st, nl: (b, h, qb, 0)),
         scratch_shapes=[
-            pltpu.VMEM((q_blk, n_pages * ps), jnp.float32),
+            pltpu.VMEM((n_pages * ps, q_blk), jnp.float32),
             pltpu.VMEM((q_blk, vd), jnp.float32),
         ],
     )
@@ -496,8 +540,9 @@ def mla_ragged_prefill_fwd(q, ckv_pages, krope_pages, w_uk, w_uv, tables,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, T, vd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                 "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*operands)

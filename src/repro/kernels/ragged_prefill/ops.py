@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import default_interpret
-from .kernel import (mla_ragged_prefill_fwd, ragged_prefill_fwd,
+from .kernel import (fit_q_block, mla_ragged_prefill_fwd, ragged_prefill_fwd,
                      windowed_ragged_prefill_fwd)
 
 
@@ -55,7 +55,10 @@ def ragged_prefill_attend(q, k_new, v_new, k_pages, v_pages, tables, start,
     assert H % K == 0, (H, K)
     scale = 1.0 / math.sqrt(D)
     qg = q.reshape(B, T, K, H // K, D).transpose(0, 2, 1, 3, 4)
-    blk = min(q_blk, ((T + 7) // 8) * 8)
+    n_keys = tables.shape[1] * k_pages.shape[1]
+    if window:
+        n_keys += k_new.shape[1]        # the fresh chunk's keys ride along
+    blk = fit_q_block(T, H // K, n_keys, q_blk)
     qg, T0 = _pad_q(qg, blk)
     tables = jnp.asarray(tables, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
@@ -95,9 +98,10 @@ def mla_ragged_prefill_attend(q, ckv_pages, krope_pages, wkv_b, tables, start,
     B, T, H, E = q.shape
     scale = 1.0 / math.sqrt(E)
     qg = q.transpose(0, 2, 1, 3)                       # [B, H, T, E]
-    blk = min(q_blk, ((T + 7) // 8) * 8)
+    blk = fit_q_block(T, 1, tables.shape[1] * ckv_pages.shape[1], q_blk)
     qg, T0 = _pad_q(qg, blk)
-    w_uk, w_uv = wkv_b[..., :nope], wkv_b[..., nope:]
+    w = wkv_b.transpose(1, 0, 2)                       # [H, L, nope+vd]
+    w_uk, w_uv = w[..., :nope], w[..., nope:]
     o = mla_ragged_prefill_fwd(
         qg, ckv_pages, krope_pages, w_uk, w_uv,
         jnp.asarray(tables, jnp.int32), jnp.asarray(start, jnp.int32),

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import tpu_compiler_params
 
 
 def _gemm_sigmoid_kernel(x_ref, w_ref, b_ref, o_ref, acc_scr):
@@ -33,7 +32,7 @@ def _gemm_sigmoid_kernel(x_ref, w_ref, b_ref, o_ref, acc_scr):
 
     @pl.when(ki == nk - 1)
     def _finish():
-        z = acc_scr[...] + b_ref[...].astype(jnp.float32)[None, :]
+        z = acc_scr[...] + b_ref[...].astype(jnp.float32)         # [1, bn]
         o_ref[...] = jax.nn.sigmoid(z).astype(o_ref.dtype)
 
 
@@ -63,13 +62,14 @@ def gemm_sigmoid_fwd(x: jax.Array, w: jax.Array, b: jax.Array, *,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda mi, ni, ki: (mi, ki)),
             pl.BlockSpec((block_k, block_n), lambda mi, ni, ki: (ki, ni)),
-            pl.BlockSpec((block_n,), lambda mi, ni, ki: (ni,)),
+            # 2-D bias block: a 1-D block's layout does not match XLA's
+            pl.BlockSpec((1, block_n), lambda mi, ni, ki: (0, ni)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, w, b)
+    )(x, w, b.reshape(1, Np))
     return out[:M, :N]

@@ -8,13 +8,16 @@ from __future__ import annotations
 
 import jax
 
-from ..models.shardings import make_mesh_compat
+
+def _make_mesh(shape, axes):
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 1):
@@ -25,7 +28,7 @@ def make_host_mesh(data: int = 1, model: int = 1, pod: int = 1):
     axes.append("data"); shape.append(data)
     if model > 1:
         axes.append("model"); shape.append(model)
-    return make_mesh_compat(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 # TPU v5e hardware constants (per chip)

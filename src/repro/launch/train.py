@@ -95,4 +95,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import zlib
 from typing import Any, Optional, Tuple
 
 import jax
@@ -36,7 +37,10 @@ def _is_def(x) -> bool:
 
 
 def _init_leaf(path, d: ParamDef, key) -> jax.Array:
-    leaf_key = jax.random.fold_in(key, hash(jax.tree_util.keystr(path)) % (2**31))
+    # a stable digest of the leaf's path (not the per-process salted
+    # ``hash``), so every process draws the same weights from one seed
+    path_id = zlib.crc32(jax.tree_util.keystr(path).encode()) % (2**31)
+    leaf_key = jax.random.fold_in(key, path_id)
     if d.init == "zeros":
         return jnp.zeros(d.shape, d.dtype)
     if d.init == "ones":

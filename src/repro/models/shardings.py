@@ -13,42 +13,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-# ------------------------------------------------------ jax version compat
-#
-# The repo targets the modern surface (``jax.shard_map`` with axis_names /
-# check_vma, ``jax.sharding.AxisType``); older installs (<= 0.4.x) only have
-# ``jax.experimental.shard_map.shard_map(..., check_rep=, auto=)`` and no
-# AxisType at all.  Everything below resolves to whichever exists so the rest
-# of the codebase can stay version-agnostic.
-
-def shard_map_compat(f, mesh: Mesh, *, in_specs, out_specs, axis_names=None,
-                     check_vma: bool = False):
-    """``jax.shard_map`` if present, else the jax.experimental equivalent.
-
-    ``axis_names`` is the set of mesh axes that go Manual; remaining axes stay
-    auto (old API expresses the same thing inverted, via ``auto=``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kwargs = {}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kwargs["auto"] = auto
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, **kwargs)
-
-
-def make_mesh_compat(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` with explicit Auto axis types when supported."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
 # logical axis -> preferred mesh axis (in priority order)
 LOGICAL_RULES: Dict[str, Tuple[str, ...]] = {
     "batch": ("dp",),            # dp is the compound data axis (pod+data)

@@ -8,10 +8,7 @@ from repro.launch import hlo_cost
 
 
 def _xla_flops(compiled) -> float:
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):     # older jax: one dict per device
-        ca = ca[0]
-    return ca["flops"]
+    return compiled.cost_analysis()["flops"]
 
 
 def _mlp_scan(unroll):
