@@ -142,9 +142,12 @@ def main(argv=None):
                     help="write the run metrics + full metrics-registry "
                          "snapshot as JSON")
     ap.add_argument("--jax-annotations", action="store_true",
-                    help="wrap jitted prefill/decode steps in jax.profiler "
-                         "TraceAnnotations (visible when a jax profiler "
-                         "trace is also being captured)")
+                    help="also emit the engine loop's phases (admit, "
+                         "dispatch: schedule/plan/upload/launch.<kind>, "
+                         "stage, collect: sync.<kind>/emit, results) as "
+                         "jax.profiler TraceAnnotations, visible on the "
+                         "device trace's clock when a jax profiler trace "
+                         "is also being captured")
     ap.add_argument("--inject", metavar="SPEC", default="",
                     help="deterministic fault plan, e.g. "
                          "'nan_logits:rid=2,at=3;step_error:rid=0,at=2'; "

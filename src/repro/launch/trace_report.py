@@ -11,6 +11,10 @@ and prints:
   (wall clock not covered by any step span), and the decode-stall share
   (non-decode steps that ran while decode-ready slots were parked behind
   them, i.e. step spans carrying ``decode_waiting=True``);
+* the engine loop's host phases (``Tracer.phase``), each under the phase
+  that contains it: ``admit``, ``dispatch`` (``schedule``, ``plan``,
+  ``upload``, ``launch.<kind>``), ``stage`` (``plan``, ``upload``),
+  ``collect`` (``sync.<kind>``, ``emit``) and ``results``;
 * a per-request table (TTFT, total latency, TPOT, tokens, prefill chunks,
   preemptions) read from each request's terminal ``finished`` instant;
 * a failure summary — terminal errors (quarantine, cancel, deadline) and
@@ -37,8 +41,11 @@ from ..serving.telemetry import ENGINE_PID, HOST_TID, REQUEST_PID, \
 # one launch) — it *serves* decode-ready slots, so the stall computation
 # below exempts it exactly like plain decode
 PHASES = ("prefill", "prefill_chunk", "restore", "decode", "verify")
-# overlapped host-pipeline phases (ENGINE_PID, tid=HOST_TID), Engine.pump()
-HOST_PHASES = ("dispatch", "stage", "collect")
+# engine-loop phases (ENGINE_PID, tid=HOST_TID) in display order: the top
+# level, then the order of the phases nested in them (``launch.<kind>`` and
+# ``sync.<kind>`` sort by their prefix)
+HOST_PHASES = ("admit", "dispatch", "stage", "collect", "results")
+NESTED_PHASES = ("schedule", "plan", "upload", "launch", "sync", "emit")
 
 
 def load(path: str) -> Dict[str, Any]:
@@ -55,8 +62,8 @@ def phase_breakdown(trace: Dict[str, Any]) -> Dict[str, Any]:
     that ran with decode-ready slots waiting."""
     spans = [e for e in trace.get("traceEvents", [])
              if e.get("ph") == "X" and e.get("pid") == ENGINE_PID
-             and e.get("tid", 0) == 0]    # step track only: the overlapped
-                                          # host pipeline reports separately
+             and e.get("tid", 0) == 0]    # step track only: the engine
+                                          # loop's phases report separately
     per = {p: 0.0 for p in PHASES}
     counts = {p: 0 for p in PHASES}
     stall = other = 0.0
@@ -82,19 +89,39 @@ def phase_breakdown(trace: Dict[str, Any]) -> Dict[str, Any]:
 
 
 def host_pipeline(trace: Dict[str, Any]) -> Dict[str, Any]:
-    """Overlapped host-pipeline sums (``Engine.pump()``): time in the
-    dispatch / stage / collect halves on the (ENGINE_PID, HOST_TID) track.
-    Empty dict when the run was synchronous (no host track emitted)."""
-    per = {p: 0.0 for p in HOST_PHASES}
-    counts = {p: 0 for p in HOST_PHASES}
-    for e in trace.get("traceEvents", []):
-        if e.get("ph") == "X" and e.get("pid") == ENGINE_PID \
-                and e.get("tid") == HOST_TID and e.get("name") in per:
-            per[e["name"]] += e.get("dur", 0.0) / 1e6
-            counts[e["name"]] += 1
-    if not any(counts.values()):
+    """Engine-loop phase sums on the (ENGINE_PID, HOST_TID) track: seconds
+    and span counts per phase path (``dispatch``, ``dispatch/plan``,
+    ``stage/plan``, ...), each phase filed under the phase containing it.
+    Empty dict when the trace has no engine-loop track."""
+    spans = sorted((e for e in trace.get("traceEvents", [])
+                    if e.get("ph") == "X" and e.get("pid") == ENGINE_PID
+                    and e.get("tid") == HOST_TID),
+                   key=lambda e: (e["ts"], -e.get("dur", 0.0)))
+    per: Dict[str, float] = {}
+    counts: Dict[str, int] = {}
+    stack: List[Any] = []                 # (end ts, path) of open phases
+    for e in spans:
+        start, end = e["ts"], e["ts"] + e.get("dur", 0.0)
+        while stack and stack[-1][0] <= start + 1.0:
+            stack.pop()
+        path = (stack[-1][1] + "/" if stack else "") + e["name"]
+        per[path] = per.get(path, 0.0) + e.get("dur", 0.0) / 1e6
+        counts[path] = counts.get(path, 0) + 1
+        stack.append((end, path))
+    if not per:
         return {}
     return {"per_phase_s": per, "counts": counts}
+
+
+def _phase_order(path: str):
+    """Sort key: parents before children, known phases in display order."""
+    key = []
+    for depth, name in enumerate(path.split("/")):
+        known = HOST_PHASES if depth == 0 else NESTED_PHASES
+        base = name.split(".", 1)[0]
+        key.append((known.index(base) if base in known else len(known),
+                    name))
+    return key
 
 
 def request_rows(trace: Dict[str, Any]) -> List[Dict[str, Any]]:
@@ -148,11 +175,13 @@ def report(trace: Dict[str, Any]) -> str:
 
     hp = host_pipeline(trace)
     if hp:
-        out.append("host pipeline (overlapped dispatch/stage/collect):")
-        for p in HOST_PHASES:
-            s = hp["per_phase_s"][p]
-            out.append(f"  {p:<14} {s*1e3:9.1f} ms  {s/wall*100:5.1f}%  "
-                       f"({hp['counts'][p]} spans)")
+        out.append("engine loop (host phases, nested under their parent):")
+        for path in sorted(hp["per_phase_s"], key=_phase_order):
+            s = hp["per_phase_s"][path]
+            depth = path.count("/")
+            label = "  " * depth + path.rsplit("/", 1)[-1]
+            out.append(f"  {label:<18} {s*1e3:9.1f} ms  "
+                       f"{s/wall*100:5.1f}%  ({hp['counts'][path]} spans)")
 
     rows = request_rows(trace)
     if rows:

@@ -142,49 +142,51 @@ def make_serve_step(cfg: ArchConfig, mesh: Optional[Mesh], kind: str,
        route through (``reference`` gather+attend | ``pallas`` fused decode
        kernel)."""
     model = build_model(cfg, attn_backend)
+    # each step is named after its kind, so the compiled module
+    # (``jit_decode_paged``, ...) says which step ran or recompiled
     if kind == "decode":
-        def step(params, cache, tokens):
+        def decode(params, cache, tokens):
             logits, cache = model.decode(params, cache, tokens, mesh)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return nxt, cache
-        return step
+        return decode
     if kind == "decode_paged":
-        def step(params, kv, state, meta, tokens):
+        def decode_paged(params, kv, state, meta, tokens):
             logits, kv, state = model.decode_paged(params, kv, state, meta,
                                                    tokens, mesh)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             ok = jnp.isfinite(logits).all(axis=-1)
             return nxt, ok, kv, state
-        return step
+        return decode_paged
     if kind == "verify_paged":
-        def step(params, kv, state, meta, tokens):
+        def verify_paged(params, kv, state, meta, tokens):
             logits, kv, state = model.verify_paged(params, kv, state, meta,
                                                    tokens, mesh)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             ok = jnp.isfinite(logits).all(axis=(-2, -1))
             return nxt, ok, kv, state
-        return step
+        return verify_paged
     if kind == "prefill_paged":
-        def step(params, kv, state, meta, tokens, extras):
+        def prefill_paged(params, kv, state, meta, tokens, extras):
             return model.prefill_paged(params, kv, state, meta, tokens,
                                        extras, mesh)
-        return step
+        return prefill_paged
     if kind == "prefill_paged_cont":
         # continuation chunks of a long prompt: pure page work — enc-dec
         # skips the encoder and reads its pinned cross K/V from the slots
-        def step(params, kv, state, meta, tokens, extras):
+        def prefill_paged_cont(params, kv, state, meta, tokens, extras):
             return model.prefill_paged(params, kv, state, meta, tokens,
                                        extras, mesh, continuation=True)
-        return step
+        return prefill_paged_cont
     if kind == "prefill_at":
-        def step(params, batch, last_idx):
+        def prefill_at(params, batch, last_idx):
             return model.prefill(params, batch, mesh, logits_idx=last_idx)
-        return step
+        return prefill_at
     assert kind == "prefill", kind
 
-    def step(params, batch):
+    def prefill(params, batch):
         return model.prefill(params, batch, mesh)
-    return step
+    return prefill
 
 
 def abstract_serve_args(cfg: ArchConfig, shape: ShapeConfig, mesh: Mesh):

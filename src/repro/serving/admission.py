@@ -40,7 +40,6 @@ from typing import Dict, List, Optional, Tuple
 class HealthState:
     """Serving lifecycle: starting → healthy → degraded → draining → drained."""
 
-    STATES = ("starting", "healthy", "degraded", "draining", "drained")
     _ALLOWED = {
         "starting": {"healthy", "degraded", "draining"},
         "healthy": {"degraded", "draining"},
@@ -49,17 +48,10 @@ class HealthState:
         "drained": set(),
     }
 
-    def __init__(self, metrics=None):
+    def __init__(self):
         self.state = "starting"
         self.reason = ""
         self.history: List[str] = ["starting"]
-        self._gauge = None
-        if metrics is not None:
-            self._gauge = metrics.gauge(
-                "server.health_state",
-                "Health state index (0=starting 1=healthy 2=degraded 3=draining 4=drained).",
-            )
-            self._gauge.set(0)
 
     def _to(self, new: str, reason: str = "") -> bool:
         if new == self.state:
@@ -69,8 +61,6 @@ class HealthState:
         self.state = new
         self.reason = reason
         self.history.append(new)
-        if self._gauge is not None:
-            self._gauge.set(self.STATES.index(new))
         return True
 
     def mark_healthy(self) -> bool:

@@ -62,9 +62,12 @@ boundary growth invalidates it; validation is an exact fingerprint match,
 so a used staged plan is bit-identical to a replan and tokens stay exact).
 ``run_offline(..., overlap=True)`` and the async streaming front-end
 (``serving.server``) drive ``pump()``; overlap hit rates are counted under
-``engine.overlap_*`` and the dispatch/stage/collect phases appear on a
-dedicated host-pipeline tracer track, visibly overlapping the step spans in
-Perfetto.
+``engine.overlap_*`` (``engine.overlap_skipped{reason}`` says why a step
+staged nothing).  Both loops time their phases with ``Tracer.phase``:
+``dispatch`` (``schedule``, ``plan``, ``upload``, ``launch.<kind>``),
+``stage`` and ``collect`` (``sync.<kind>``, ``emit``) appear on the tracer's
+engine-loop track, visibly overlapping the step spans in Perfetto, and in a
+captured JAX profile on the device trace's clock.
 
 Streaming hooks: ``on_token(rid, index, token, t)`` fires as each token is
 collected (a preemption replay re-fires earlier indexes; stream consumers
@@ -137,8 +140,8 @@ class _Pending:
     rows: Any                         # prefill row tuples / decode active list
     out_dev: Any                      # device logits / next-token array
     t0: float                         # dispatch start (step span start)
-    t_dispatched: float               # host-side dispatch end
     waiting: bool                     # decode-ready slots parked behind this
+    staged: bool = False              # decode launched from a staged plan
 
 
 @dataclasses.dataclass
@@ -215,6 +218,11 @@ def _synthetic_frontend(cfg: ArchConfig, scfg: ServeConfig, seed: int,
     return None
 
 
+def _upload(host: Dict[str, np.ndarray]) -> Dict[str, jax.Array]:
+    """A step's host-built inputs, on the device."""
+    return {k: jnp.asarray(v) for k, v in host.items()}
+
+
 def _pow2_pad(n: int, cap: int) -> int:
     b = 1
     while b < n:
@@ -243,7 +251,7 @@ class Engine:
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.tracer = tracer if tracer is not None else Tracer()
         self.pool = PagedKVPool(cfg, self.scfg, metrics=self.metrics)
-        self.states = StateSlotPool(cfg, self.scfg, metrics=self.metrics) \
+        self.states = StateSlotPool(cfg, self.scfg) \
             if self.spec.state_slots else None
         if self.scfg.prefix_cache and not self.spec.prefix_cacheable:
             print(f"[engine] WARNING: prefix cache disabled for {cfg.name}: "
@@ -266,7 +274,7 @@ class Engine:
         # deadline-aware admission control (serving/{faults,admission})
         self.injector = FaultInjector(faults, self.metrics) \
             if faults is not None else None
-        self.health = HealthState(self.metrics)
+        self.health = HealthState()
         self.admission = AdmissionController(
             self.scfg.max_slots, metrics=self.metrics, seed=seed) \
             if self.scfg.admission_control else None
@@ -283,8 +291,6 @@ class Engine:
             "engine.chunked_prefill_steps", "continuation-chunk calls")
         self._m_restores = self.metrics.counter(
             "engine.state_restores", "checkpoint-restore re-admissions")
-        self._m_cow = self.metrics.counter(
-            "engine.cow_forks", "copy-on-write page forks run")
         # prefill work accounting: padded counts what the device computed
         # (pow2 rows x bucket), actual counts real prompt tokens — the gap is
         # padding waste, the thing chunking + bucketing are trading against
@@ -294,18 +300,13 @@ class Engine:
             "engine.prefill_actual_tokens", "real prompt tokens prefilled")
         self._h_decode_step = self.metrics.histogram(
             "engine.decode_step_s", "fixed-shape decode step wall time")
-        # speculative-decoding accounting: drafts proposed vs accepted, plus
-        # the per-step acceptance-rate distribution (accepted / proposed for
-        # each slot-step with a non-empty draft)
+        # speculative-decoding accounting: drafts proposed vs accepted
         self._m_spec_proposed = self.metrics.counter(
             "engine.spec_proposed", "draft tokens proposed by the n-gram "
             "speculator")
         self._m_spec_accepted = self.metrics.counter(
             "engine.spec_accepted", "draft tokens accepted by the verify "
             "step (emitted without their own decode launch)")
-        self._h_accept = self.metrics.histogram(
-            "engine.spec_accept_rate", "per slot-step draft acceptance rate "
-            "(accepted / proposed, non-empty drafts only)")
         # decode-stall bookkeeping: wall time decode-ready slots spend parked
         # behind non-decode steps (the head-of-line cost chunking bounds)
         self._h_stall = self.metrics.histogram(
@@ -323,6 +324,9 @@ class Engine:
         self._m_overlap_dropped = self.metrics.counter(
             "engine.overlap_dropped", "staged plans invalidated by a "
             "retirement/EOS/admission/preemption before dispatch")
+        self._m_overlap_skipped = self.metrics.counter(
+            "engine.overlap_skipped", "steps after which no plan was "
+            "staged, by reason", labels=("reason",))
         # request-lifecycle admission guards
         self._inflight: set = set()   # rids queued, live, or awaiting collect
         self._m_reject_budget = self.metrics.counter(
@@ -492,11 +496,7 @@ class Engine:
             return True
         if pending is None:
             return False
-        self.tracer.host_span("dispatch", pending.t0, pending.t_dispatched,
-                              kind=pending.kind)
-        t_s0 = time.perf_counter()
-        if self._stage_next(pending):
-            self.tracer.host_span("stage", t_s0, time.perf_counter())
+        self._stage_next(pending)
         self._finish_step(pending, overlap=True)
         return True
 
@@ -608,12 +608,56 @@ class Engine:
         is asynchronous).  ``None`` on drain — trailing stall time
         accumulated behind non-decode steps is flushed there so it cannot
         leak into a later run on a reused engine."""
+        with self.tracer.phase("dispatch") as ph:
+            with self.tracer.phase("schedule"):
+                action = self._schedule()
+            if action is None:
+                ph.discard()                  # an idle poll: no span
+                self._drop_staged()
+                if self.injector is not None:
+                    self.injector.on_drain(self)
+                if self._stall_accum:
+                    self._h_stall.observe(self._stall_accum)
+                    self._stall_accum = 0.0
+                return None
+            waiting = bool(self.sched.decode_ready())
+            kind, payload = action
+            if kind != "decode":
+                self._drop_staged()
+            t0 = time.perf_counter()
+            staged = False
+            if kind == "prefill":
+                rows, out = self._launch_prefill(payload, t0)
+            elif kind == "prefill_chunk":
+                rows, out = self._launch_chunks(payload, t0)
+            elif kind == "restore":
+                self._run_restore(payload, t0)
+                rows, out = None, None
+            elif self.spec_k:
+                # speculation on: every decode-ready step runs as a small-q
+                # verify step (with an empty draft it degenerates to decode)
+                kind = "verify"
+                if self.injector is not None:
+                    self.injector.before_launch(self, "verify", payload)
+                rows, out = payload, self._launch_verify(payload)
+            else:
+                if self.injector is not None:
+                    self.injector.before_launch(self, "decode", payload)
+                out, staged = self._launch_decode(payload)
+                rows = payload
+            return _Pending(kind=kind, payload=payload, rows=rows,
+                            out_dev=out, t0=t0, waiting=waiting,
+                            staged=staged)
+
+    def _schedule(self) -> Optional[Tuple]:
+        """The fault injector's tick, the deadline sweep and the scheduler's
+        next action (None when there is no work)."""
         if self.injector is not None:
             self.injector.on_tick(self)
         if self.admission is not None:
             self._evict_deadlines()
         try:
-            action = self.sched.next_action()
+            return self.sched.next_action()
         except RuntimeError:
             # injected pool pressure can manufacture a scheduler deadlock the
             # real pool would never see; give the hostage pages back and
@@ -621,58 +665,25 @@ class Engine:
             if self.injector is None \
                     or not self.injector.release_pressure(self):
                 raise
-            action = self.sched.next_action()
-        if action is None:
-            self._drop_staged()
-            if self.injector is not None:
-                self.injector.on_drain(self)
-            if self._stall_accum:
-                self._h_stall.observe(self._stall_accum)
-                self._stall_accum = 0.0
-            return None
-        waiting = bool(self.sched.decode_ready())
-        kind, payload = action
-        if kind != "decode":
-            self._drop_staged()
-        t0 = time.perf_counter()
-        if kind == "prefill":
-            rows, out = self._launch_prefill(payload, t0)
-        elif kind == "prefill_chunk":
-            rows, out = self._launch_chunks(payload, t0)
-        elif kind == "restore":
-            self._run_restore(payload, t0)
-            rows, out = None, None
-        elif self.spec_k:
-            # speculation on: every decode-ready step runs as a small-q
-            # verify step (with an empty draft it degenerates to decode)
-            kind = "verify"
-            if self.injector is not None:
-                self.injector.before_launch(self, "verify", payload)
-            rows, out = payload, self._launch_verify(payload)
-        else:
-            if self.injector is not None:
-                self.injector.before_launch(self, "decode", payload)
-            rows, out = payload, self._launch_decode(payload)
-        return _Pending(kind=kind, payload=payload, rows=rows, out_dev=out,
-                        t0=t0, t_dispatched=time.perf_counter(),
-                        waiting=waiting)
+            return self.sched.next_action()
 
     def _finish_step(self, pending: _Pending, overlap: bool = False) -> None:
         """Block on the pending step's device output and run the host-side
-        bookkeeping: token appends, retirement, step span, stall account."""
-        t_c0 = time.perf_counter()
-        if pending.kind == "decode":
-            self._collect_decode(pending)
-        elif pending.kind == "verify":
-            self._collect_verify(pending)
-        elif pending.kind in ("prefill", "prefill_chunk"):
-            self._collect_prefill(pending)
-        t1 = time.perf_counter()
-        n_rows = 1 if pending.kind == "restore" else len(pending.payload)
-        self.tracer.step_span(pending.kind, pending.t0, t1, rows=n_rows,
-                              decode_waiting=pending.waiting)
-        if overlap:
-            self.tracer.host_span("collect", t_c0, t1, kind=pending.kind)
+        bookkeeping: token appends, retirement, step span, stall account.
+        ``step()`` and ``pump()`` (``overlap``) collect alike."""
+        with self.tracer.phase("collect", kind=pending.kind):
+            if pending.kind == "decode":
+                self._collect_decode(pending)
+            elif pending.kind == "verify":
+                self._collect_verify(pending)
+            elif pending.kind in ("prefill", "prefill_chunk"):
+                self._collect_prefill(pending)
+            t1 = time.perf_counter()
+            n_rows = 1 if pending.kind == "restore" else len(pending.payload)
+            extra = {"staged": pending.staged} \
+                if pending.kind == "decode" else {}
+            self.tracer.step_span(pending.kind, pending.t0, t1, rows=n_rows,
+                                  decode_waiting=pending.waiting, **extra)
         if pending.kind in ("decode", "verify"):
             # verify steps *serve* decode-ready slots: both flush the stall
             self._h_stall.observe(self._stall_accum)
@@ -785,34 +796,51 @@ class Engine:
         pending step is a decode, nothing is queued, no slot is mid-prefill,
         no slot retires on budget at this step's collect (an EOS retirement
         is caught by the dispatch fingerprint instead), and no slot crosses
-        a page boundary at its next position.  True when a plan was staged."""
-        if pending.kind != "decode" or self.sched.queue \
-                or self.sched.prefilling_slots():
-            return False
-        active = list(pending.rows)
+        a page boundary at its next position.  True when a plan was staged;
+        otherwise ``engine.overlap_skipped`` counts the reason."""
+        with self.tracer.phase("stage"):
+            reason = self._stage_blocker(pending)
+            if reason is not None:
+                self._m_overlap_skipped.labels(reason=reason).inc()
+                return False
+            active = list(pending.rows)
+            self._staged = _StagedDecode(
+                active=tuple(active),
+                fp=tuple((i, self.sched.slots[i].req.rid,
+                          self.sched.slots[i].pos + 1,
+                          len(self.sched.slots[i].pages), 0)
+                         for i in active),
+                meta=self._decode_plan(active, pos_offset=1))
+            self._m_overlap_staged.inc()
+            return True
+
+    def _stage_blocker(self, pending: _Pending) -> Optional[str]:
+        """Why the step after ``pending`` cannot be staged (None if it can):
+        ``not_decode``, ``queued``, ``prefilling``, ``retiring`` or
+        ``page_growth``."""
+        if pending.kind != "decode":
+            return "not_decode"
+        if self.sched.queue:
+            return "queued"
+        if self.sched.prefilling_slots():
+            return "prefilling"
         ps = self.scfg.page_size
         cap = self.pool.table_width
-        for i in active:
+        for i in pending.rows:
             slot = self.sched.slots[i]
             if len(slot.req.generated) + 1 >= slot.req.max_new:
-                return False          # retires when this step collects
+                return "retiring"     # retires when this step collects
             p1 = slot.pos + 1
             if self.pool.spec.paged and len(slot.pages) < cap \
                     and p1 % ps == 0 and p1 // ps >= len(slot.pages):
-                return False          # next decode needs page growth
-        self._staged = _StagedDecode(
-            active=tuple(active),
-            fp=tuple((i, self.sched.slots[i].req.rid,
-                      self.sched.slots[i].pos + 1,
-                      len(self.sched.slots[i].pages), 0) for i in active),
-            meta=self._decode_plan(active, pos_offset=1))
-        self._m_overlap_staged.inc()
-        return True
+                return "page_growth"  # next decode needs page growth
+        return None
 
     # -------------------------------------------------------------- prefill
 
-    def _extras(self, rids: List[int], B: int) -> Dict[str, Any]:
-        """Frontend inputs for a padded prefill batch ({} for text-only)."""
+    def _extras(self, rids: List[int], B: int) -> Dict[str, np.ndarray]:
+        """Frontend inputs for a padded prefill batch, on the host ({} for
+        text-only)."""
         cfg = self.cfg
         if not (cfg.enc_dec or cfg.n_image_tokens):
             return {}
@@ -823,7 +851,7 @@ class Engine:
         for i, r in enumerate(rows):
             out[i] = r
         key = "frames" if cfg.enc_dec else "image_embeds"
-        return {key: jnp.asarray(out)}
+        return {key: out}
 
     def _prefill_launch(self, rows: List[Tuple[int, Any, int, int]],
                         continuation: bool = False):
@@ -837,6 +865,28 @@ class Engine:
         pinned cross cache instead of re-encoding).  Returns the per-row
         last-real-token logits *still on device* — the collect half blocks
         on them with ``np.asarray``."""
+        with self.tracer.phase("plan"):
+            meta, toks, extras = self._prefill_plan(rows, continuation)
+        with self.tracer.phase("upload"):
+            meta, toks, extras = _upload(meta), jnp.asarray(toks), \
+                _upload(extras)
+        state = self.states.state if self.states is not None else {}
+        step = self._prefill_cont if continuation and self.cfg.enc_dec \
+            else self._prefill
+        kind = "prefill_chunk" if continuation else "prefill"
+        with self.tracer.phase("launch." + kind):
+            logits, self.pool.kv, state = step(
+                self.params, self.pool.kv, state, meta, toks, extras)
+        if self.states is not None:
+            self.states.state = state
+        self._m_padded.inc(toks.size)
+        self._m_actual.inc(sum(c for _, _, _, c in rows))
+        return logits
+
+    def _prefill_plan(self, rows: List[Tuple[int, Any, int, int]],
+                      continuation: bool):
+        """Host-side (numpy) inputs of one batched chunk-prefill call:
+        ``prefill_meta``, the bucketed tokens and the frontend extras."""
         bucket = self.scfg.bucket_of(max(c for _, _, _, c in rows))
         B = _pow2_pad(len(rows), self.scfg.max_slots)
         toks = np.zeros((B, bucket), np.int32)
@@ -865,23 +915,11 @@ class Engine:
             while W < need:
                 W *= 2
             width = max(min(W, tables.shape[1]), 1)
-        meta = {k: jnp.asarray(v) for k, v in prefill_meta(
-            self.cfg, ps, tables[:, :width], slots, start, n_tail,
-            bucket).items()}
-        state = self.states.state if self.states is not None else {}
+        meta = prefill_meta(self.cfg, ps, tables[:, :width], slots, start,
+                            n_tail, bucket)
         extras = {} if continuation \
             else self._extras([req.rid for _, req, _, _ in rows], B)
-        step = self._prefill_cont if continuation and self.cfg.enc_dec \
-            else self._prefill
-        with self.tracer.annotate("prefill_step"):
-            logits, self.pool.kv, state = step(
-                self.params, self.pool.kv, state, meta, jnp.asarray(toks),
-                extras)
-        if self.states is not None:
-            self.states.state = state
-        self._m_padded.inc(B * bucket)
-        self._m_actual.inc(sum(c for _, _, _, c in rows))
-        return logits
+        return meta, toks, extras
 
     def _after_chunk(self, slot_idx: int, req, n_done: int, n_chunk: int,
                      logits_row: Optional[np.ndarray], now: float,
@@ -919,7 +957,6 @@ class Engine:
                 self.pool.kv = self._copy(self.pool.kv,
                                           jnp.asarray(adm.cow_src, jnp.int32),
                                           jnp.asarray(adm.cow_dst, jnp.int32))
-                self._m_cow.inc()
         rows = [(adm.slot_idx, adm.req, adm.n_matched, adm.n_chunk)
                 for adm in adms]
         out = self._prefill_launch(rows)
@@ -945,23 +982,26 @@ class Engine:
         """Collect half of a prefill/chunk step: block on the device logits,
         then advance every row's cursor (first tokens, cache publishes,
         retirement)."""
-        logits = np.asarray(pending.out_dev)     # blocks: device step done
+        with self.tracer.phase("sync." + pending.kind):
+            logits = np.asarray(pending.out_dev)  # blocks: device step done
         now = time.perf_counter()
-        for r, (slot_idx, req, n_done, n_chunk) in enumerate(pending.rows):
-            slot = self.sched.slots[slot_idx]
-            if slot is None or slot.req is not req:
-                continue              # cancelled/quarantined under our feet
-            self.tracer.on_chunk(req.rid, pending.t0, now,
-                                 n_done=n_done, n_chunk=n_chunk)
-            if not np.isfinite(logits[r]).all():
-                # checked *before* _after_chunk so a poisoned prompt never
-                # publishes its pages to the radix cache
-                self._quarantine_slot(slot_idx, "nan_logits", now)
-                continue
-            pages = (pending.payload[r].pages if pending.kind == "prefill"
-                     else slot.pages)
-            self._after_chunk(slot_idx, req, n_done, n_chunk, logits[r],
-                              now, pages)
+        with self.tracer.phase("emit"):
+            for r, (slot_idx, req, n_done, n_chunk) in enumerate(
+                    pending.rows):
+                slot = self.sched.slots[slot_idx]
+                if slot is None or slot.req is not req:
+                    continue          # cancelled/quarantined under our feet
+                self.tracer.on_chunk(req.rid, pending.t0, now,
+                                     n_done=n_done, n_chunk=n_chunk)
+                if not np.isfinite(logits[r]).all():
+                    # checked *before* _after_chunk so a poisoned prompt
+                    # never publishes its pages to the radix cache
+                    self._quarantine_slot(slot_idx, "nan_logits", now)
+                    continue
+                pages = (pending.payload[r].pages
+                         if pending.kind == "prefill" else slot.pages)
+                self._after_chunk(slot_idx, req, n_done, n_chunk, logits[r],
+                                  now, pages)
 
     def _run_restore(self, adm: Admission, t0: float) -> None:
         """Re-admit a checkpointed (preempted) request: write its state
@@ -980,8 +1020,17 @@ class Engine:
                      pos_offset: int = 0) -> Dict[str, Any]:
         """Flat per-step decode metadata, derived once on the host (numpy)
         instead of re-derived by every layer's block inside the scanned
-        decode step.  ``pos_offset=1`` builds the *next* step's plan while
-        this step's collect hasn't advanced the cursors yet (staging)."""
+        decode step, and uploaded.  ``pos_offset=1`` builds the *next*
+        step's plan while this step's collect hasn't advanced the cursors
+        yet (staging)."""
+        with self.tracer.phase("plan"):
+            meta = self._decode_meta(active, pos_offset)
+        with self.tracer.phase("upload"):
+            return _upload(meta)
+
+    def _decode_meta(self, active: List[int],
+                     pos_offset: int = 0) -> Dict[str, np.ndarray]:
+        """``decode_meta`` of the active rows, on the host."""
         B = self.scfg.max_slots
         maxp = max(self.pool.table_width, 1)
         pos = np.zeros((B,), np.int32)
@@ -990,39 +1039,44 @@ class Engine:
             slot = self.sched.slots[i]
             pos[i] = slot.pos + pos_offset
             tables[i] = slot.table
-        return {k: jnp.asarray(v) for k, v in decode_meta(
-            self.cfg, self.scfg.page_size, tables, pos).items()}
+        return decode_meta(self.cfg, self.scfg.page_size, tables, pos)
 
     def _launch_decode(self, active: List[int]):
         """Launch one fixed-shape decode step, reusing a staged plan when
         its fingerprint still matches reality (a used plan is bit-identical
         to a replan — same positions, tables, pages — so tokens are exact).
-        Returns (device next-token array, launch time) without blocking."""
+        Returns ((device next-token array, finite flags, launch time),
+        whether the staged plan was used) without blocking."""
         B = self.scfg.max_slots
-        tokens = np.zeros((B,), np.int32)
-        for i in active:
-            tokens[i] = self.sched.slots[i].req.generated[-1]
-        meta = None
-        if self._staged is not None:
-            st, self._staged = self._staged, None
-            fp = tuple(
-                (i, self.sched.slots[i].req.rid, self.sched.slots[i].pos,
-                 len(self.sched.slots[i].pages), 0) for i in active)
-            if tuple(active) == st.active and fp == st.fp:
-                meta = st.meta
-                self._m_overlap_used.inc()
-            else:
-                self._m_overlap_dropped.inc()
-        if meta is None:
-            meta = self._decode_plan(active)
+        with self.tracer.phase("plan"):
+            tokens = np.zeros((B,), np.int32)
+            for i in active:
+                tokens[i] = self.sched.slots[i].req.generated[-1]
+            meta = None
+            if self._staged is not None:
+                st, self._staged = self._staged, None
+                fp = tuple(
+                    (i, self.sched.slots[i].req.rid, self.sched.slots[i].pos,
+                     len(self.sched.slots[i].pages), 0) for i in active)
+                if tuple(active) == st.active and fp == st.fp:
+                    meta = st.meta
+                    self._m_overlap_used.inc()
+                else:
+                    self._m_overlap_dropped.inc()
+            staged = meta is not None
+            plan = None if staged else self._decode_meta(active)
+        with self.tracer.phase("upload"):
+            if plan is not None:
+                meta = _upload(plan)
+            tokens = jnp.asarray(tokens)
         state = self.states.state if self.states is not None else {}
         t_launch = time.perf_counter()
-        with self.tracer.annotate("decode_step"):
+        with self.tracer.phase("launch.decode"):
             nxt, ok, self.pool.kv, state = self._decode(
-                self.params, self.pool.kv, state, meta, jnp.asarray(tokens))
+                self.params, self.pool.kv, state, meta, tokens)
         if self.states is not None:
             self.states.state = state
-        return nxt, ok, t_launch
+        return (nxt, ok, t_launch), staged
 
     def _collect_decode(self, pending: _Pending) -> None:
         """Collect half of a decode step: block on the device tokens, then
@@ -1030,35 +1084,38 @@ class Engine:
         whose finite flag came back False is quarantined instead of emitting
         its garbage argmax — its survivors' rows are untouched."""
         nxt_dev, ok_dev, t_launch = pending.out_dev
-        nxt = np.asarray(nxt_dev)                # blocks: device step done
-        ok = np.asarray(ok_dev)
+        with self.tracer.phase("sync.decode"):
+            nxt = np.asarray(nxt_dev)            # blocks: device step done
+            ok = np.asarray(ok_dev)
         now = time.perf_counter()
         self._h_decode_step.observe(now - t_launch)
         if self.admission is not None:
             self.admission.observe_step(now - t_launch)
-        for i in pending.rows:
-            slot = self.sched.slots[i]
-            if slot is None:
-                continue              # quarantined earlier in this collect
-            if not ok[i]:
-                self._quarantine_slot(i, "nan_logits", now)
-                continue
-            slot.pos += 1
-            tok = int(nxt[i])
-            slot.req.generated.append(tok)
-            self._emit_token(slot.req.rid, len(slot.req.generated) - 1,
-                             tok, now)
-            self._maybe_retire(i, now)
+        with self.tracer.phase("emit"):
+            for i in pending.rows:
+                slot = self.sched.slots[i]
+                if slot is None:
+                    continue          # quarantined earlier in this collect
+                if not ok[i]:
+                    self._quarantine_slot(i, "nan_logits", now)
+                    continue
+                slot.pos += 1
+                tok = int(nxt[i])
+                slot.req.generated.append(tok)
+                self._emit_token(slot.req.rid, len(slot.req.generated) - 1,
+                                 tok, now)
+                self._maybe_retire(i, now)
 
     # ------------------------------------------------------------- speculate
 
-    def _verify_plan(self, active: List[int],
-                     drafts: Dict[int, List[int]]) -> Dict[str, Any]:
-        """Fixed-shape verify-step metadata: like ``_decode_plan`` but with
-        per-row live query counts (1 + draft length) and per-query write
-        targets for all Q = spec_k + 1 positions.  Idle rows keep pos=0,
-        n_q=1 and a NULL_PAGE table, so their single query writes to the
-        reserved sink page exactly as an idle decode row does."""
+    def _verify_meta(self, active: List[int],
+                     drafts: Dict[int, List[int]]) -> Dict[str, np.ndarray]:
+        """Fixed-shape verify-step metadata, on the host: like
+        ``_decode_meta`` but with per-row live query counts (1 + draft
+        length) and per-query write targets for all Q = spec_k + 1
+        positions.  Idle rows keep pos=0, n_q=1 and a NULL_PAGE table, so
+        their single query writes to the reserved sink page exactly as an
+        idle decode row does."""
         B = self.scfg.max_slots
         Q = self.spec_k + 1
         maxp = max(self.pool.table_width, 1)
@@ -1070,8 +1127,8 @@ class Engine:
             pos[i] = slot.pos
             n_q[i] = 1 + len(drafts[i])
             tables[i] = slot.table
-        return {k: jnp.asarray(v) for k, v in verify_meta(
-            self.cfg, self.scfg.page_size, tables, pos, n_q, Q).items()}
+        return verify_meta(self.cfg, self.scfg.page_size, tables, pos, n_q,
+                           Q)
 
     def _launch_verify(self, active: List[int]):
         """Launch one fixed-shape speculative verify step: draft up to
@@ -1084,31 +1141,34 @@ class Engine:
         Returns (device [B, Q] next-token array, launch time, drafts)."""
         B = self.scfg.max_slots
         Q = self.spec_k + 1
-        tokens = np.zeros((B, Q), np.int32)
-        drafts: Dict[int, List[int]] = {}
-        prefix = self.pool.spec.prefix_tokens
-        for i in active:
-            req = self.sched.slots[i].req
-            # a draft token beyond the remaining budget could never be
-            # emitted (the bonus token fills the last budget slot), and its
-            # K/V write must stay under the max_len page horizon
-            kmax = min(self.spec_k,
-                       req.max_new - len(req.generated) - 1,
-                       prefix + self.scfg.max_len - 1
-                       - self.sched.slots[i].pos)
-            draft = self.proposer.propose(
-                req.prompt + req.generated)[:max(kmax, 0)]
-            drafts[i] = draft
-            tokens[i, 0] = req.generated[-1]
-            tokens[i, 1:1 + len(draft)] = draft
-            if draft:
-                self._m_spec_proposed.inc(len(draft))
-        meta = self._verify_plan(active, drafts)
+        with self.tracer.phase("plan"):
+            tokens = np.zeros((B, Q), np.int32)
+            drafts: Dict[int, List[int]] = {}
+            prefix = self.pool.spec.prefix_tokens
+            for i in active:
+                req = self.sched.slots[i].req
+                # a draft token beyond the remaining budget could never be
+                # emitted (the bonus token fills the last budget slot), and
+                # its K/V write must stay under the max_len page horizon
+                kmax = min(self.spec_k,
+                           req.max_new - len(req.generated) - 1,
+                           prefix + self.scfg.max_len - 1
+                           - self.sched.slots[i].pos)
+                draft = self.proposer.propose(
+                    req.prompt + req.generated)[:max(kmax, 0)]
+                drafts[i] = draft
+                tokens[i, 0] = req.generated[-1]
+                tokens[i, 1:1 + len(draft)] = draft
+                if draft:
+                    self._m_spec_proposed.inc(len(draft))
+            meta = self._verify_meta(active, drafts)
+        with self.tracer.phase("upload"):
+            meta, tokens = _upload(meta), jnp.asarray(tokens)
         state = self.states.state if self.states is not None else {}
         t_launch = time.perf_counter()
-        with self.tracer.annotate("verify_step"):
+        with self.tracer.phase("launch.verify"):
             nxt, ok, self.pool.kv, state = self._verify(
-                self.params, self.pool.kv, state, meta, jnp.asarray(tokens))
+                self.params, self.pool.kv, state, meta, tokens)
         if self.states is not None:
             self.states.state = state
         return nxt, ok, t_launch, drafts
@@ -1121,38 +1181,41 @@ class Engine:
         mid-emit stops the emission there (trailing accepted tokens are
         discarded exactly as decode would never have produced them)."""
         nxt_dev, ok_dev, t_launch, drafts = pending.out_dev
-        nxt = np.asarray(nxt_dev)                # blocks: device step done
-        ok = np.asarray(ok_dev)
+        with self.tracer.phase("sync.verify"):
+            nxt = np.asarray(nxt_dev)            # blocks: device step done
+            ok = np.asarray(ok_dev)
         now = time.perf_counter()
         self._h_decode_step.observe(now - t_launch)
         if self.admission is not None:
             self.admission.observe_step(now - t_launch)
-        for i in pending.rows:
-            slot = self.sched.slots[i]
-            if slot is None:
-                continue              # quarantined earlier in this collect
-            if not ok[i]:
-                self._quarantine_slot(i, "nan_logits", now)
-                continue
-            req = slot.req
-            draft = drafts[i]
-            a = accept_length(draft, nxt[i, :len(draft)]) if draft else 0
-            if draft:
-                self._m_spec_accepted.inc(a)
-                self._h_accept.observe(a / len(draft))
-            for j in range(a + 1):
-                tok = int(nxt[i, j])
-                slot.pos += 1
-                req.generated.append(tok)
-                self._emit_token(req.rid, len(req.generated) - 1, tok, now)
-                done = len(req.generated) >= req.max_new
-                if self.scfg.eos_id >= 0 and tok == self.scfg.eos_id:
-                    done = True
-                if done:
-                    req.t_finish = now
-                    self.sched.retire(i)
-                    self.tracer.on_finished(req.rid, now, len(req.generated))
-                    break
+        with self.tracer.phase("emit"):
+            for i in pending.rows:
+                slot = self.sched.slots[i]
+                if slot is None:
+                    continue          # quarantined earlier in this collect
+                if not ok[i]:
+                    self._quarantine_slot(i, "nan_logits", now)
+                    continue
+                req = slot.req
+                draft = drafts[i]
+                a = accept_length(draft, nxt[i, :len(draft)]) if draft else 0
+                if draft:
+                    self._m_spec_accepted.inc(a)
+                for j in range(a + 1):
+                    tok = int(nxt[i, j])
+                    slot.pos += 1
+                    req.generated.append(tok)
+                    self._emit_token(req.rid, len(req.generated) - 1, tok,
+                                     now)
+                    done = len(req.generated) >= req.max_new
+                    if self.scfg.eos_id >= 0 and tok == self.scfg.eos_id:
+                        done = True
+                    if done:
+                        req.t_finish = now
+                        self.sched.retire(i)
+                        self.tracer.on_finished(req.rid, now,
+                                                len(req.generated))
+                        break
 
     def _emit_token(self, rid: int, index: int, tok: int, now: float) -> None:
         """Fire the streaming hook and the injector's token seam (the

@@ -122,11 +122,8 @@ class PagedKVPool:
             "pool.pages_scrubbed", "pages zero-scrubbed during quarantine")
         self._m_live = self.metrics.gauge(
             "pool.pages_live", "pages currently allocated (refcount > 0)")
-        self._m_free = self.metrics.gauge(
-            "pool.free_pages", "free-list depth")
         self._m_refs = self.metrics.gauge(
             "pool.ref_total", "sum of refcounts over live pages")
-        self._m_free.set(len(self._free))
 
     # ------------------------------------------------------------ accounting
 
@@ -214,7 +211,6 @@ class PagedKVPool:
 
     def _sync_gauges(self) -> None:
         self._m_live.set(len(self._ref))
-        self._m_free.set(len(self._free))
 
     def note_scrubbed(self, n: int) -> None:
         """Record ``n`` pages zero-scrubbed by the engine's quarantine path."""
@@ -249,8 +245,7 @@ class StateSlotPool:
     which rows are live; ``checkpoint``/``restore`` implement the
     preemption half of the slot lifetime contract (see module docstring)."""
 
-    def __init__(self, cfg: ArchConfig, scfg: ServeConfig,
-                 metrics: Optional[MetricsRegistry] = None):
+    def __init__(self, cfg: ArchConfig, scfg: ServeConfig):
         self.cfg = cfg
         self.scfg = scfg
         model = build_model(cfg)
@@ -259,15 +254,6 @@ class StateSlotPool:
         self.state: Any = init_tree(defs, jax.random.PRNGKey(0))
         self.n_slots = scfg.max_slots
         self._claimed: Set[int] = set()
-        self.metrics = metrics if metrics is not None else MetricsRegistry()
-        self._m_resident = self.metrics.gauge(
-            "states.slots_claimed", "state slots held by live requests")
-        self._m_claims = self.metrics.counter(
-            "states.claims", "state-slot claims (admissions)")
-        self._m_ckpt = self.metrics.counter(
-            "states.checkpoints", "slot snapshots taken on preemption")
-        self._m_restore = self.metrics.counter(
-            "states.restores", "checkpointed snapshots written back")
 
     # ------------------------------------------------------------ accounting
 
@@ -283,27 +269,22 @@ class StateSlotPool:
         assert 0 <= slot < self.n_slots, slot
         assert slot not in self._claimed, f"double claim of state slot {slot}"
         self._claimed.add(slot)
-        self._m_claims.inc()
-        self._m_resident.set(len(self._claimed))
 
     def release(self, slot: int) -> None:
         assert slot in self._claimed, f"release of unclaimed state slot {slot}"
         self._claimed.remove(slot)
-        self._m_resident.set(len(self._claimed))
 
     # ------------------------------------------------- checkpoint / restore
 
     def checkpoint(self, slot: int) -> Any:
         """Snapshot one slot's state to host memory (preemption)."""
         assert slot in self._claimed, f"checkpoint of unclaimed slot {slot}"
-        self._m_ckpt.inc()
         return jax.tree.map(lambda a: np.asarray(a[:, slot]), self.state)
 
     def restore(self, slot: int, saved: Any) -> None:
         """Write a checkpointed snapshot back into (a possibly different)
         claimed slot."""
         assert slot in self._claimed, f"restore into unclaimed slot {slot}"
-        self._m_restore.inc()
         self.state = jax.tree.map(
             lambda a, s: a.at[:, slot].set(jnp.asarray(s, a.dtype)),
             self.state, saved)

@@ -93,8 +93,6 @@ class RadixCache:
         # telemetry: token-level hit accounting upholds the invariant
         # hit_tokens + miss_tokens == lookup_tokens for every match() call
         self.metrics = metrics if metrics is not None else MetricsRegistry()
-        self._m_lookups = self.metrics.counter(
-            "radix.lookups", "match() calls (one per admission attempt)")
         self._m_lookup_tok = self.metrics.counter(
             "radix.lookup_tokens", "matchable prompt tokens offered")
         self._m_hit_tok = self.metrics.counter(
@@ -104,16 +102,9 @@ class RadixCache:
             "radix.partial_hit_tokens", "hit tokens needing a COW fork")
         self._m_miss_tok = self.metrics.counter(
             "radix.miss_tokens", "prompt tokens the tree could not serve")
-        self._m_inserted = self.metrics.counter(
-            "radix.inserted_pages", "pages newly published to the tree")
-        self._m_evictions = self.metrics.counter(
-            "radix.evictions", "tree references dropped under pressure")
         self._m_nodes = self.metrics.gauge(
             "radix.cached_pages", "pages currently cached (tree nodes)")
-        self._m_locked = self.metrics.gauge(
-            "radix.locked_nodes", "nodes pinned by live requests")
         self._n_nodes = 0
-        self._n_locked = 0
 
     # -------------------------------------------------------------- querying
 
@@ -150,7 +141,6 @@ class RadixCache:
             nodes.append(best)
         n_matched = n + cow_len
         matchable = min(len(tokens), max_match)
-        self._m_lookups.inc()
         self._m_lookup_tok.inc(matchable)
         self._m_hit_tok.inc(n_matched)
         self._m_partial_tok.inc(cow_len)
@@ -185,25 +175,18 @@ class RadixCache:
                 new += 1
             child.last_access = tick
             node = child
-        self._m_inserted.inc(new)
         self._n_nodes += new
         self._m_nodes.set(self._n_nodes)
         return new
 
     def lock(self, nodes: Sequence[RadixNode]) -> None:
         for nd in nodes:
-            if nd.lock == 0:
-                self._n_locked += 1
             nd.lock += 1
-        self._m_locked.set(self._n_locked)
 
     def unlock(self, nodes: Sequence[RadixNode]) -> None:
         for nd in nodes:
             assert nd.lock > 0, "unlock of an unlocked radix node"
             nd.lock -= 1
-            if nd.lock == 0:
-                self._n_locked -= 1
-        self._m_locked.set(self._n_locked)
 
     def evict(self, n_pages: int) -> int:
         """Detach up to ``n_pages`` LRU unlocked leaves, dropping the tree's
@@ -226,7 +209,6 @@ class RadixCache:
             del parent.children[victim.key]
             self.pool.release([victim.page])
             self.evictions += 1
-            self._m_evictions.inc()
             self._n_nodes -= 1
             self._m_nodes.set(self._n_nodes)
             freed += 1

@@ -158,8 +158,6 @@ class Scheduler:
         self._m_preempt = self.metrics.counter(
             "sched.preemptions", "slots evicted under pressure, by kind",
             labels=("kind",))               # checkpoint | replay
-        self._m_chunks = self.metrics.counter(
-            "sched.chunk_continuations", "continuation chunks scheduled")
         # chunked prefill applies to families whose prompt KV is
         # token-addressable pages at text positions: recurrent state must be
         # carried through a whole prompt in one call, and the vlm image
@@ -296,7 +294,6 @@ class Scheduler:
                 return ("prefill", adms)
         chunks = self._chunk_batch()
         if chunks:
-            self._m_chunks.inc(len(chunks))
             return ("prefill_chunk", chunks)
         return None
 

@@ -22,6 +22,11 @@ between that world (an asyncio event loop speaking HTTP/SSE, see
       queues downstream of the worker are unbounded; a single slow *client*
       buffers there without stalling the engine for everyone else.
 
+    The engine thread's own work between steps is timed by the engine's
+    tracer as the ``admit`` (submit queue) and ``results`` (finished
+    requests) phases, beside the engine's ``dispatch`` / ``stage`` /
+    ``collect``.
+
     The detokenize worker turns token ids into text fragments off the hot
     loop and hands finished events into each request's ``asyncio.Queue`` via
     ``loop.call_soon_threadsafe`` — the only thread-crossing primitive used.
@@ -216,32 +221,24 @@ class ServingLoop:
     def _engine_main(self) -> None:
         drive = self.engine.pump if self.overlap else self.engine.step
         health = self.engine.health
+        tracer = self.engine.tracer
         health.mark_healthy()
         try:
             while not self._stop.is_set():
                 self._t_progress = time.monotonic()
-                busy = False
-                while True:
-                    try:
-                        msg = self._submit.get_nowait()
-                    except queue.Empty:
-                        break
-                    busy = True
-                    if msg[0] == "submit":
-                        _, rid, prompt, max_new, dl, ttft_dl = msg
-                        try:
-                            self.engine.add_request(
-                                prompt, max_new, rid=rid, deadline_s=dl,
-                                ttft_deadline_s=ttft_dl)
-                        except ValueError as e:   # rid collision (loop bug)
-                            self._events.put(("error", rid, str(e)))
-                    else:
-                        self.engine.cancel(msg[1])
+                busy = not self._submit.empty()
+                if busy:
+                    with tracer.phase("admit"):
+                        self._admit()
                 if drive():
                     busy = True
-                for res in self.engine.collect():
-                    busy = True
-                    self._events.put(("done", res.rid, res))
+                with tracer.phase("results") as ph:
+                    done = self.engine.collect()
+                    for res in done:
+                        self._events.put(("done", res.rid, res))
+                    if not done:
+                        ph.discard()
+                busy = busy or bool(done)
                 if not busy:
                     if (health.draining and not self.engine.sched.has_work()
                             and self._submit.empty()):
@@ -254,6 +251,24 @@ class ServingLoop:
                 self._events.put(("error", rid, self._fatal))
         finally:
             self._events.put(None)          # detok worker shutdown sentinel
+
+    def _admit(self) -> None:
+        """Hand every queued submit/cancel message to the engine."""
+        while True:
+            try:
+                msg = self._submit.get_nowait()
+            except queue.Empty:
+                return
+            if msg[0] == "submit":
+                _, rid, prompt, max_new, dl, ttft_dl = msg
+                try:
+                    self.engine.add_request(
+                        prompt, max_new, rid=rid, deadline_s=dl,
+                        ttft_deadline_s=ttft_dl)
+                except ValueError as e:       # rid collision (loop bug)
+                    self._events.put(("error", rid, str(e)))
+            else:
+                self.engine.cancel(msg[1])
 
     # ----------------------------------------------------- watchdog thread
 

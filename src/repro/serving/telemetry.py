@@ -18,23 +18,35 @@ measurement layer every serving component reports into:
 ``Tracer``
     Request-lifecycle + engine-phase tracing in Chrome trace-event JSON
     (the ``{"traceEvents": [...]}`` format Perfetto / ``chrome://tracing``
-    load directly).  Two tracks:
+    load directly).  Three tracks:
 
-    * **engine** (pid 1) — one complete ("X") event per ``Engine.step``:
-      ``prefill`` / ``prefill_chunk`` / ``restore`` / ``decode``, with args
-      recording the rows served and whether decode-ready slots sat parked
-      behind the step (``decode_waiting`` — stall attribution).
+    * **engine steps** (pid 1, tid 0) — one complete ("X") event per engine
+      step: ``prefill`` / ``prefill_chunk`` / ``restore`` / ``decode`` /
+      ``verify``, with args recording the rows served, whether decode-ready
+      slots sat parked behind the step (``decode_waiting`` — stall
+      attribution) and, on decode steps, whether the launch used a plan
+      staged during the previous step (``staged``).
+    * **engine loop** (pid 1, tid 1) — the host phases of every step,
+      nested: ``admit`` (the serving loop's submit queue), ``dispatch``
+      (``schedule``, ``plan``, ``upload``, ``launch.<kind>``), ``stage``
+      (``plan``, ``upload`` of the next decode step), ``collect``
+      (``sync.<kind>``, the blocking read of the step's outputs, then
+      ``emit``: cursors, streaming hooks, retirement) and ``results``
+      (finished requests handed to the serving loop).
     * **requests** (pid 2, tid = rid) — per-request spans
       ``queued → prefill_chunk[i]... → decode`` plus ``preempted`` /
       ``restored`` instants and a terminal ``finished`` instant whose args
       carry the request's summary (ttft, tpot, chunk count, preemptions).
 
-    The tracer also keeps a per-rid lifecycle record (arrival, admission,
-    first token, finish, chunk count, preemptions) that the engine reads
-    back into each ``RequestResult`` — per-request timing comes from one
-    place.  ``annotate(name)`` optionally wraps the jitted steps in
-    ``jax.profiler.TraceAnnotation`` so these host spans line up with
-    device timelines when a jax profiler trace is being captured.
+    ``phase(name)`` is the one span mechanism of the engine loop.  With
+    ``jax_annotations=True`` every phase also opens a
+    ``jax.profiler.TraceAnnotation`` of the same name, so a concurrently
+    captured profile carries the loop's phases on the device trace's own
+    clock.  The tracer also keeps a per-rid lifecycle record (arrival,
+    admission, first token, finish, chunk count, preemptions) that the
+    engine reads back into each ``RequestResult`` — per-request timing
+    comes from one place.  At most ``max_events`` events are kept; later
+    ones are counted as ``dropped_events``.
 
 ``shared_metrics``
     The one end-of-run metrics schema both engines emit
@@ -54,7 +66,6 @@ single emitted token.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
 import time
@@ -236,10 +247,9 @@ class MetricsRegistry:
 # Chrome trace-event track layout (pid/tid are just track ids to Perfetto)
 ENGINE_PID = 1
 REQUEST_PID = 2
-HOST_TID = 1       # engine-process track for the overlapped host pipeline:
-                   # dispatch / stage / collect spans emitted by Engine.pump()
-                   # sit beside the step track (tid 0) so the overlap is
-                   # visible in Perfetto
+HOST_TID = 1       # engine-process track for the engine loop's phases
+                   # (Tracer.phase), beside the step track (tid 0) so the
+                   # overlap of host work and device steps is visible
 
 
 @dataclasses.dataclass
@@ -256,19 +266,82 @@ class RequestRecord:
     terminal: bool = False
 
 
+class _NoPhase:
+    """The phase handed out when both sinks are off: no clock read, no
+    allocation, no event."""
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoPhase":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+    def discard(self) -> None:
+        pass
+
+
+_NO_PHASE = _NoPhase()
+
+
+class _Phase:
+    """One engine-loop phase: an ``X`` event on the engine-loop track when
+    the tracer is enabled, and a ``jax.profiler.TraceAnnotation`` of the
+    same name when it annotates."""
+    __slots__ = ("tracer", "name", "args", "t0", "mark", "ann")
+
+    def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
+        self.tracer, self.name, self.args = tracer, name, args
+        self.ann = None
+
+    def __enter__(self) -> "_Phase":
+        tr = self.tracer
+        if tr.jax_annotations:
+            import jax
+            self.ann = jax.profiler.TraceAnnotation(self.name)
+            self.ann.__enter__()
+        self.mark = len(tr.events) if tr.enabled else None
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        if self.mark is not None:
+            self.tracer.span(ENGINE_PID, HOST_TID, self.name, self.t0, t1,
+                             **self.args)
+
+    def discard(self) -> None:
+        """Leave no event for this phase or the phases nested in it (an
+        idle poll of the loop); events on other tracks since it opened
+        (a deadline eviction's terminal instant, a request's spans) stay,
+        and so does the profiler's annotation."""
+        if self.mark is not None:
+            events = self.tracer.events
+            events[self.mark:] = [
+                e for e in events[self.mark:]
+                if e["pid"] != ENGINE_PID or e["tid"] != HOST_TID]
+            self.mark = None
+
+
 class Tracer:
     """Request-lifecycle + engine-phase tracer (Chrome trace-event JSON).
 
     All methods are host-side list/dict appends on a perf_counter clock;
     ``enabled=False`` turns every hook into a cheap early return (used by
     standalone Scheduler construction in tests).  ``jax_annotations=True``
-    makes ``annotate(name)`` wrap jitted step dispatches in
-    ``jax.profiler.TraceAnnotation`` so a concurrently captured device
-    profile carries the same phase names."""
+    makes every ``phase`` also a ``jax.profiler.TraceAnnotation``, so a
+    concurrently captured device profile carries the same phase names.
+    Events past ``max_events`` are not kept; ``dropped_events`` counts
+    them, so a long-running server's tracer stays bounded."""
 
-    def __init__(self, enabled: bool = True, jax_annotations: bool = False):
+    def __init__(self, enabled: bool = True, jax_annotations: bool = False,
+                 max_events: int = 1_000_000):
         self.enabled = enabled
         self.jax_annotations = jax_annotations
+        self.max_events = max_events
+        self.dropped_events = 0
         self.t0 = time.perf_counter()       # trace epoch (ts are relative)
         self.events: List[Dict[str, Any]] = []
         self.requests: Dict[int, RequestRecord] = {}
@@ -282,12 +355,18 @@ class Tracer:
     def _ts(self, t: float) -> float:
         return (t - self.t0) * 1e6          # seconds -> trace microseconds
 
+    def _append(self, event: Dict[str, Any]) -> None:
+        if len(self.events) < self.max_events:
+            self.events.append(event)
+        else:
+            self.dropped_events += 1
+
     def span(self, pid: int, tid: int, name: str, t_start: float,
              t_end: float, **args) -> None:
         """One complete ("X") event covering [t_start, t_end] (abs seconds)."""
         if not self.enabled:
             return
-        self.events.append({
+        self._append({
             "name": name, "ph": "X", "pid": pid, "tid": tid,
             "cat": "engine" if pid == ENGINE_PID else "request",
             "ts": self._ts(t_start),
@@ -298,35 +377,30 @@ class Tracer:
                 **args) -> None:
         if not self.enabled:
             return
-        self.events.append({
+        self._append({
             "name": name, "ph": "i", "s": "t", "pid": pid, "tid": tid,
             "cat": "engine" if pid == ENGINE_PID else "request",
             "ts": self._ts(t), "args": args})
 
-    def annotate(self, name: str):
-        """Context manager for one jitted step dispatch: a
-        ``jax.profiler.TraceAnnotation`` when enabled, else a no-op."""
-        if self.enabled and self.jax_annotations:
-            import jax
-            return jax.profiler.TraceAnnotation(name)
-        return contextlib.nullcontext()
-
     # -------------------------------------------------------- engine phases
+
+    def phase(self, name: str, **args):
+        """Context manager timing one engine-loop phase (see the module
+        docstring for the names).  Its ``discard()`` drops the phase and
+        the phases nested in it from the Chrome trace."""
+        if not (self.enabled or self.jax_annotations):
+            return _NO_PHASE
+        return _Phase(self, name, args)
 
     def step_span(self, name: str, t_start: float, t_end: float,
                   **args) -> None:
-        """One engine step (prefill / prefill_chunk / restore / decode)."""
+        """One engine step (prefill / prefill_chunk / restore / decode /
+        verify) on the step track."""
         if not self.enabled:
             return
         args.setdefault("step", self._steps)
         self._steps += 1
         self.span(ENGINE_PID, 0, name, t_start, t_end, **args)
-
-    def host_span(self, name: str, t_start: float, t_end: float,
-                  **args) -> None:
-        """One host-pipeline phase (dispatch / stage / collect) of an
-        overlapped ``Engine.pump()`` step, on its own engine-process track."""
-        self.span(ENGINE_PID, HOST_TID, name, t_start, t_end, **args)
 
     # ---------------------------------------------------- request lifecycle
 
@@ -440,11 +514,12 @@ class Tracer:
                for e in self.events):
             meta.append(
                 {"ph": "M", "pid": ENGINE_PID, "tid": HOST_TID,
-                 "name": "thread_name", "args": {"name": "host pipeline"}})
+                 "name": "thread_name", "args": {"name": "engine loop"}})
         meta += [{"ph": "M", "pid": REQUEST_PID, "tid": rid,
                   "name": "thread_name", "args": {"name": f"request {rid}"}}
                  for rid in sorted(self.requests)]
-        return {"traceEvents": meta + self.events, "displayTimeUnit": "ms"}
+        return {"traceEvents": meta + self.events, "displayTimeUnit": "ms",
+                "otherData": {"dropped_events": self.dropped_events}}
 
     def save(self, path: str) -> None:
         with open(path, "w") as f:

@@ -131,8 +131,8 @@ def test_overlap_run_offline_token_exact_and_staging_used():
     dropped = eng.metrics.value("engine.overlap_dropped")
     assert staged > 0 and used > 0             # the pipeline actually staged
     assert used + dropped == staged            # every plan is accounted for
-    # host-pipeline spans made it into the trace (dispatch every step,
-    # stage only on staged steps)
+    # engine-loop phases made it into the trace (dispatch, stage and
+    # collect on every step)
     trace = eng.tracer.to_dict()
     from repro.serving.telemetry import ENGINE_PID, HOST_TID
     host = [e for e in trace["traceEvents"]

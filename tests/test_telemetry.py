@@ -201,10 +201,12 @@ def test_trace_well_formed_and_request_fields():
         assert by_rid[r.rid]["ttft_s"] == pytest.approx(r.ttft_s)
         assert by_rid[r.rid]["n_tokens"] == len(r.tokens)
 
-    # every engine step produced exactly one engine-track span
+    # every engine step produced exactly one span on the step track (tid 0;
+    # the engine loop's phases have a track of their own)
     # (chunked_prefill_steps is a subset of prefill_steps, not additive)
     steps = [e for e in trace["traceEvents"]
-             if e.get("ph") == "X" and e.get("pid") == ENGINE_PID]
+             if e.get("ph") == "X" and e.get("pid") == ENGINE_PID
+             and e.get("tid") == 0]
     assert len(steps) == metrics["prefill_steps"] \
         + metrics["decode_steps"] + metrics["state_restores"]
     assert metrics["chunked_prefill_steps"] > 0         # 40 toks / 16 budget
@@ -227,7 +229,8 @@ def test_trace_phase_sums_cover_wall_clock():
 
 
 def test_tracing_is_token_invariant():
-    """Telemetry on (default) vs tracer disabled: identical tokens."""
+    """Telemetry on (default), with profiler annotations, and tracer
+    disabled: identical tokens."""
     cfg = _cfg()
     scfg = ServeConfig(page_size=8, max_slots=2, max_len=48)
     params = init_params(cfg, jax.random.PRNGKey(2))
@@ -237,6 +240,12 @@ def test_tracing_is_token_invariant():
     off, _ = off_eng.run_offline(prompts, 5)
     assert [r.tokens for r in on] == [r.tokens for r in off]
     assert off_eng.tracer.events == []                  # truly off
+    # the phases as profiler annotations too, on both drive loops
+    for overlap in (False, True):
+        ann, _ = Engine(cfg, scfg, params,
+                        tracer=Tracer(jax_annotations=True)).run_offline(
+            prompts, 5, overlap=overlap)
+        assert [r.tokens for r in ann] == [r.tokens for r in on]
     ref, _ = generate_static(cfg, params, prompts, 5, scfg, batch_size=1)
     assert [r.tokens for r in on] == ref
 
