@@ -2,45 +2,69 @@
 
 One decode step reads every live token of a request's KV straight out of the
 paged pool: the per-request page table rides in as a *scalar-prefetch*
-operand, so the K/V BlockSpec index maps resolve ``tables[b, i]`` before the
-body runs and the pipeline DMAs exactly the physical pages the request owns —
-the ``pool[tables]`` gather that the XLA reference path materializes in HBM
+operand, so the K/V BlockSpec index maps resolve physical page ids before
+the body runs and the pipeline DMAs exactly the pages the request owns — the
+``pool[tables]`` gather that the XLA reference path materializes in HBM
 never exists here.  This is the TPU-native shape of vLLM/SGLang
 PagedAttention: walk the page table, attend in place.
 
 Two kernel bodies cover every paged decode family in ``models.cache_spec``:
 
-* ``_paged_decode_kernel`` — vanilla GQA (mask ``idx <= pos``) and
-  sliding-window page *rings* (``window > 0``: absolute positions are
-  recovered from the ring layout and masked to the window, exactly the
-  reference ring rule).  Grid ``(B, n_pages)``; the inner dimension sweeps
-  the request's pages with online-softmax state (running max ``m``,
-  normalizer ``l``, accumulator ``acc``) in fp32 VMEM scratch, one set per
-  KV head.  Each grid step fetches a whole page (every KV head) and each
-  head's ``G = H // K`` query group attends its slice — GQA never
-  replicates KV, and a page crosses HBM once per step.
+* ``_walk_kernel`` — vanilla GQA (mask ``idx <= pos``) and sliding-window
+  page *rings* (``window > 0``: absolute positions are recovered from the
+  ring layout and masked to the window, exactly the reference ring rule).
+  It walks each row's live pages only, a block of pages a step (below),
+  with online-softmax state (running max ``m``, normalizer ``l``,
+  accumulator ``acc``) in fp32 VMEM scratch, one set per KV head.  Each
+  page block spans every KV head and each head's ``G = H // K`` query group
+  attends its slice — GQA never replicates KV, and a page crosses HBM once
+  per call.
 * ``_mla_paged_decode_kernel`` — DeepSeek-style absorbed-latent decode.
   Scores are ``q_eff·ckv + q_rope·krope`` against the rank-``L`` latent pages
   (one shared "KV head"); the context accumulator stays in latent space
   (``acc += p·ckv``) so the kernel's output is the ``[H, L]`` context that the
   caller up-projects with ``w_uv`` — per-head K/V are never materialized.
+  Its grid is ``(B, n_pages)``, one page a step; pages whose first token
+  lies past ``pos`` skip their work via ``pl.when``.
 
-Pages whose first token already lies past ``pos`` are skipped via ``pl.when``
-(a null-page read would be masked anyway, but skipping saves the DMA wait);
-fully-masked pages are absorbed by the -inf-guarded online-softmax update.
+The decode walk (GQA decode and verify).  A row's *live extent* is its first
+``n_live = min(last // ps + 1, n_pages)`` table columns (``live_pages``),
+``last`` being the position of its last query: ``pos`` for decode,
+``pos + n_q - 1`` for verify.  The count serves the page ring too: a column
+past ``last // ps`` has never been written, and every written one can hold
+in-window tokens, so the ring rule masks inside the extent as before.  The
+extent splits into blocks of ``ppb`` pages (``pages_per_block``: 128
+tokens, fewer for a narrow table or the VMEM budget), and the grid has one
+step per live block of every row, rows in order — its length is a dynamic
+grid bound that ``walk_schedule`` computes on the device from the
+positions, with each step's row and block and each page slot's physical
+page.  Each pool (K, V, and the int8 scales) is passed once per page slot
+with a whole-page block whose index map reads that page, so the pipeline
+fetches the next block's pages while this one computes; a slot past the
+row's extent keeps the page it held a step before, and costs no DMA.  Each
+KV head's scores are one ``[rows, ppb * ps]`` dot over the block's pages in
+table order, folded into the online softmax once per block, slots past the
+extent masked ``-inf``.  An idle row (null table, ``pos`` 0) costs one
+step, whose one live page is the null page it attends.  Pages are fetched
+by BlockSpecs, not by ``make_async_copy`` from an HBM ref: Mosaic pads an
+HBM ref's two minor dims to its tiling and refuses a slice of a pool whose
+head dim is under 128 lanes (qwen2's 64), or of the ``[P, ps, K]`` int8
+scales.
 
-Each decode body has a small-q *verify* twin (``_paged_verify_kernel`` /
-``_mla_paged_verify_kernel``) for speculative decoding: the q block carries
-``Q = 1 + K`` query tokens per row (last emitted token + draft), a third
-scalar-prefetch operand ``n_q`` gives each row's live query count, and the
-mask becomes per-query causal — query ``j`` sits at absolute position
-``pos + j``, so flattened row ``j*G + g`` runs exactly the decode body's ops
-at that position and ``Q == 1`` reproduces the decode kernel bit-for-bit.
-Dead rows (``j >= n_q``) stay fully masked and finish as exact zeros.
+Each decode body has a small-q *verify* twin (``_walk_kernel`` with
+``verify`` / ``_mla_paged_verify_kernel``) for speculative decoding: the q
+block carries ``Q = 1 + K`` query tokens per row (last emitted token +
+draft), a further scalar-prefetch operand ``n_q`` gives each row's live
+query count, and the mask becomes per-query causal — query ``j`` sits at
+absolute position ``pos + j``, so flattened row ``j*G + g`` runs exactly the
+decode body's ops at that position and ``Q == 1`` reproduces the decode
+kernel bit-for-bit.  Dead rows (``j >= n_q``) stay fully masked and finish
+as exact zeros.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -50,10 +74,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float("-inf")
 
+# The decode walk's block: about this many tokens per step, with the
+# double-buffered K/V (and scale) blocks held to this much VMEM (v5e's
+# default scoped limit is 16 MiB; the rest is left to the body's values).
+BLOCK_TOKENS = 128
+BLOCK_VMEM_BYTES = 8 << 20
+
 
 def _online_softmax_update(s, v, m_ref, l_ref, acc_ref):
-    """Fold one masked score block ``s`` ([rows, ps]) and its values ``v``
-    ([ps, d]) into the running (m, l, acc) scratch state.  ``m``/``l`` are
+    """Fold one masked score block ``s`` ([rows, n]) and its values ``v``
+    ([n, d]) into the running (m, l, acc) scratch state.  ``m``/``l`` are
     [rows, 1] columns: kept 2-D so no lane-to-sublane shape cast is needed
     to broadcast them against ``s`` and ``acc``."""
     m_prev = m_ref[...]
@@ -80,12 +110,13 @@ def _init(m_scr, l_scr, acc_scr):
     acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
 
-def _page_mask(s, page_idx, pos, *, page_size, window, ring):
-    """Validity of the ``page_size`` token slots of page ``page_idx`` against
-    absolute position ``pos`` — the decode masking contract (see
-    kernels/README.md): causal ``idx <= pos`` when ``window == 0``, else the
-    ring rule recovering each slot's absolute position from the ring layout."""
-    idx = page_idx * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+def _slot_mask(s, first, pos, *, window, ring):
+    """Validity of the token slots ``first, first + 1, ...`` (the columns of
+    ``s``, in table order) against absolute position ``pos`` — the decode
+    masking contract (see kernels/README.md): causal ``idx <= pos`` when
+    ``window == 0``, else the ring rule recovering each slot's absolute
+    position from the ring layout."""
+    idx = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     if window == 0:
         return idx <= pos
     slot = pos % ring
@@ -108,78 +139,182 @@ def page_head(ref, kh, scale_ref=None):
     return x
 
 
-def _fold_page(s, v, i, pos, m_ref, l_ref, acc_ref, *, page_size,
-               window=0, ring=0, row_ok=None):
-    """Mask page ``i``'s scores ``s`` [rows, ps] at per-row positions
-    ``pos`` (and live rows ``row_ok``) and fold them with values ``v`` into
-    the online-softmax state."""
-    valid = _page_mask(s, i, pos, page_size=page_size, window=window,
-                       ring=ring)
+def _fold_page(s, v, first, pos, m_ref, l_ref, acc_ref, *, window=0,
+               ring=0, row_ok=None):
+    """Mask the scores ``s`` [rows, n] of token slots ``first ..`` at
+    per-row positions ``pos`` (and live rows ``row_ok``) and fold them with
+    values ``v`` into the online-softmax state."""
+    valid = _slot_mask(s, first, pos, window=window, ring=ring)
     if row_ok is not None:
         valid = valid & row_ok
     _online_softmax_update(jnp.where(valid, s, NEG_INF), v, m_ref, l_ref,
                            acc_ref)
 
 
-def _attend_page(q, k, v, i, pos, m_ref, l_ref, acc_ref, *, scale, softcap,
-                 **mask):
-    """Scores of query rows ``q`` [rows, D] against one page's keys ``k``,
-    folded into the online-softmax state (``mask``: ``_fold_page``'s)."""
-    # scale after the dot, the reference ordering, so the two backends'
-    # fp32 scores round identically
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if softcap:
-        s = softcap * jnp.tanh(s / softcap)
-    _fold_page(s, v, i, pos, m_ref, l_ref, acc_ref, **mask)
+def live_pages(last, page_size, n_pages):
+    """Table columns a row walks: those up to the page of its last query
+    position ``last``, at most the table width and at least one (an idle
+    row attends its null page)."""
+    return jnp.clip(last // page_size + 1, 1, n_pages)
 
 
-def _paged_decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                         page_size: int, scale: float, softcap: float,
-                         window: int, ring: int, quantized: bool):
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_scr, l_scr, acc_scr = rest
-    b = pl.program_id(0)
-    i = pl.program_id(1)
+def _vmem_bytes(shape, dtype):
+    """VMEM bytes of an array tiled on its last two dims: (8, 128) 32-bit
+    tiles, packed to 16 / 32 sublanes for 16 / 8-bit dtypes."""
+    size = jnp.dtype(dtype).itemsize
+    *lead, rows, cols = shape
+    sub = 8 * (4 // size)
+    return (math.prod(lead) * -(-rows // sub) * sub * -(-cols // 128) * 128
+            * size)
 
-    @pl.when(i == 0)
+
+def pages_per_block(page_size, K, D, dtype, n_pages, scale_dtype=None):
+    """Pages per step of the decode walk: ``BLOCK_TOKENS`` worth, at most
+    the table width, halved until the double-buffered page blocks of K and
+    V (and their int8 scales) fit ``BLOCK_VMEM_BYTES``."""
+    def fits(ppb):
+        n = 4 * ppb * _vmem_bytes((page_size, K, D), dtype)
+        if scale_dtype is not None:
+            n += 4 * ppb * _vmem_bytes((page_size, K), scale_dtype)
+        return n <= BLOCK_VMEM_BYTES
+    ppb = max(1, min(n_pages, BLOCK_TOKENS // page_size))
+    while ppb > 1 and not fits(ppb):
+        ppb //= 2
+    return ppb
+
+
+def walk_schedule(tables, last, page_size, ppb):
+    """The decode walk's steps, from the page table and each row's last
+    query position ``last`` [B]: ``(sched, pages, n_steps)``.  Rows come in
+    order, each with ``ceil(live_pages / ppb)`` blocks of ``ppb`` pages
+    (``n_blk`` blocks span the table); step ``s < n_steps`` attends block
+    ``sched[s] % n_blk`` of row ``sched[s] // n_blk``, and its page slot
+    ``j`` holds physical page ``pages[sched[s] * ppb + j]``.  A slot past
+    the row's extent keeps the page it held one step before — the previous
+    block's, so the pipeline fetches nothing for it — or, in a row's first
+    block, the null page 0.  Vector ops only (a gather on the TPU goes
+    index by index): ``sched`` has room for every block of every row, and
+    entries past ``n_steps`` are never read."""
+    B, n_pages = tables.shape
+    n_blk = -(-n_pages // ppb)
+    live = live_pages(last, page_size, n_pages)                       # [B]
+    blocks = -(-live // ppb)
+    ends = jnp.cumsum(blocks)
+    s = jnp.arange(B * n_blk, dtype=jnp.int32)[:, None]
+    # step s lies in the first row that ends after it; each row before
+    # that one skips its n_blk - blocks dead blocks
+    sched = s[:, 0] + jnp.sum(jnp.where(ends <= s, n_blk - blocks, 0),
+                              axis=1)
+    padded = jnp.pad(tables, ((0, 0), (0, n_blk * ppb - n_pages)))
+    prev = jnp.pad(padded[:, :-ppb], ((0, 0), (ppb, 0)))
+    cols = jnp.arange(n_blk * ppb, dtype=jnp.int32)
+    pages = jnp.where(cols < live[:, None], padded, prev)
+    return sched, pages.reshape(-1), ends[-1]
+
+
+def _walk_kernel(sched_ref, pages_ref, pos_ref, *refs, page_size: int,
+                 n_pages: int, ppb: int, scale: float, softcap: float,
+                 window: int, quantized: bool, G: int, verify: bool):
+    """Body shared by GQA decode and verify (``verify``: a fourth scalar
+    operand ``n_q``): one step per live block of ``ppb`` pages (module
+    docstring, "The decode walk")."""
+    del pages_ref          # read by the index maps only
+    nq_ref, refs = (refs[0], refs[1:]) if verify else (None, refs)
+    q_ref, refs = refs[0], refs[1:]
+    n_ops = 4 if quantized else 2
+    pages = [refs[a * ppb:(a + 1) * ppb] for a in range(n_ops)]
+    o_ref, m_scr, l_scr, acc_scr = refs[n_ops * ppb:]
+    k_refs, v_refs = pages[:2]
+    ks_refs, vs_refs = pages[2:] if quantized else ([None] * ppb,) * 2
+    ps, n_blk = page_size, -(-n_pages // ppb)
+    s = pl.program_id(0)
+    r, blk = sched_ref[s] // n_blk, sched_ref[s] % n_blk
+    last = pos_ref[r] if nq_ref is None else pos_ref[r] + nq_ref[r] - 1
+    live = live_pages(last, ps, n_pages)
+
+    @pl.when(blk == 0)
     def _():
         _init(m_scr, l_scr, acc_scr)
 
-    pos = pos_ref[b]
-    # vanilla: pages strictly past pos hold no valid token yet; ring: every
-    # resident page can hold in-window tokens, sweep them all
-    live = (i * page_size <= pos) if window == 0 else (i * page_size < ring)
+    pos = pos_ref[r]
+    if nq_ref is None:
+        row_ok = None
+    else:
+        # row j*G + g is query j of head group g, at absolute position
+        # pos + j — the decode mask evaluated per row
+        qi = jax.lax.broadcasted_iota(jnp.int32, (q_ref.shape[2], 1), 0) // G
+        pos, row_ok = pos + qi, qi < nq_ref[r]
+    first = blk * ppb * ps
+    for kh in range(q_ref.shape[1]):       # each KV head of the block
+        # the block's pages, in table order: slot t is token first + t
+        k = jnp.concatenate([page_head(k_refs[j], kh, ks_refs[j])
+                             for j in range(ppb)], axis=0)        # [T, D]
+        v = jnp.concatenate([page_head(v_refs[j], kh, vs_refs[j])
+                             for j in range(ppb)], axis=0)
+        # scale after the dot, the reference ordering, so the two backends'
+        # fp32 scores round identically
+        sc = jax.lax.dot_general(q_ref[0, kh].astype(jnp.float32), k,
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * scale
+        if softcap:
+            sc = softcap * jnp.tanh(sc / softcap)
+        # slots past the extent hold a carried or null page: masked
+        ok = first + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1) \
+            < live * ps
+        _fold_page(sc, v, first, pos, m_scr.at[kh], l_scr.at[kh],
+                   acc_scr.at[kh], window=window, ring=n_pages * ps,
+                   row_ok=ok if row_ok is None else ok & row_ok)
 
-    @pl.when(live)
-    def _():
-        for kh in range(q_ref.shape[1]):       # each KV head of the page
-            _attend_page(q_ref[0, kh].astype(jnp.float32),     # [G, D]
-                         page_head(k_ref, kh, ks_ref),
-                         page_head(v_ref, kh, vs_ref), i, pos,
-                         m_scr.at[kh], l_scr.at[kh], acc_scr.at[kh],
-                         page_size=page_size, scale=scale, softcap=softcap,
-                         window=window, ring=ring)
-
-    @pl.when(i == pl.num_programs(1) - 1)
+    @pl.when(blk == (live + ppb - 1) // ppb - 1)
     def _():
         for kh in range(q_ref.shape[1]):
             o_ref[0, kh] = _normalized(l_scr.at[kh], acc_scr.at[kh]).astype(
                 o_ref.dtype)
 
 
-def _kv_specs(ps, K, D, index_map, quantized):
-    """Whole-page K/V blocks [1, ps, K, D] (plus [1, ps, K] scale blocks when
-    int8): the last two block dims equal the pool's, as Mosaic requires."""
-    page = pl.BlockSpec((1, ps, K, D), lambda *a: index_map(*a) + (0, 0, 0))
-    specs = [page, page]
-    if quantized:
-        sc = pl.BlockSpec((1, ps, K), lambda *a: index_map(*a) + (0, 0))
-        specs += [sc, sc]
-    return specs
+def _walk_call(q, k_pages, v_pages, tables, pos, n_q, k_scale, v_scale, *,
+               G, interpret, **kw):
+    """``pallas_call`` of the decode walk: q [B, K, rows, D] (rows = G for
+    decode, Q*G for verify with ``n_q``); each pool (and int8 scale) is
+    passed once per page slot of a block, its index map reading the slot's
+    page from the schedule."""
+    B, K, rows, D = q.shape
+    ps, n_pages = k_pages.shape[1], tables.shape[1]
+    quantized = k_scale is not None
+    ppb = pages_per_block(ps, K, D, k_pages.dtype, n_pages,
+                          k_scale.dtype if quantized else None)
+    n_blk = -(-n_pages // ppb)
+    sched, pages, n_steps = walk_schedule(
+        tables, pos if n_q is None else pos + n_q - 1, ps, ppb)
+    prefetch = [sched, pages, pos] + ([] if n_q is None else [n_q])
+    pools = [k_pages, v_pages] + ([k_scale, v_scale] if quantized else [])
+
+    def page_spec(pool, j):
+        block = (1,) + pool.shape[1:]
+        return pl.BlockSpec(block, lambda s, sched_ref, pages_ref, *_: (
+            pages_ref[sched_ref[s] * ppb + j],) + (0,) * (len(block) - 1))
+
+    q_spec = pl.BlockSpec((1, K, rows, D), lambda s, sched_ref, *_: (
+        sched_ref[s] // n_blk, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(n_steps,),
+        in_specs=[q_spec] + [page_spec(p, j) for p in pools
+                             for j in range(ppb)],
+        out_specs=q_spec,
+        scratch_shapes=_online_scratch((K, rows), D),
+    )
+    return pl.pallas_call(
+        functools.partial(_walk_kernel, page_size=ps, n_pages=n_pages,
+                          ppb=ppb, quantized=quantized, G=G,
+                          verify=n_q is not None, **kw),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        # a row's blocks run in order, carrying the online-softmax state
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*prefetch, q, *[p for p in pools for _ in range(ppb)])
 
 
 def _online_scratch(rows, D):
@@ -198,79 +333,12 @@ def paged_decode_fwd(q, k_pages, v_pages, tables, pos, *, scale: float,
     [B, K, G, D].  ``window > 0`` treats the table as a page ring of
     ``n_pages * ps`` token slots.  ``k_scale``/``v_scale``: [P, ps, K] bf16
     per-token-per-head absmax scales when the pool is int8-quantized — the
-    kernel dequantizes in-register after the page DMA.  Grid ``(B,
-    n_pages)``: each page is fetched once and every KV head attends it."""
-    B, K, G, D = q.shape
-    ps = k_pages.shape[1]
-    n_pages = tables.shape[1]
-    quantized = k_scale is not None
-    kernel = functools.partial(
-        _paged_decode_kernel, page_size=ps, scale=scale, softcap=softcap,
-        window=window, ring=n_pages * ps, quantized=quantized)
-    in_specs = [pl.BlockSpec((1, K, G, D), lambda b, i, tr, pr: (b, 0, 0, 0))]
-    in_specs += _kv_specs(ps, K, D, lambda b, i, tr, pr: (tr[b, i],),
-                          quantized)
-    operands = [tables, pos, q, k_pages, v_pages]
-    if quantized:
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, n_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, K, G, D),
-                               lambda b, i, tr, pr: (b, 0, 0, 0)),
-        scratch_shapes=_online_scratch((K, G), D),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(*operands)
-
-
-def _paged_verify_kernel(tables_ref, pos_ref, nq_ref, q_ref, k_ref, v_ref,
-                         *rest, page_size: int, scale: float, softcap: float,
-                         window: int, ring: int, quantized: bool, G: int):
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_scr, l_scr, acc_scr = rest
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _():
-        _init(m_scr, l_scr, acc_scr)
-
-    pos = pos_ref[b]
-    n_q = nq_ref[b]
-    # vanilla: pages strictly past the last live query's position hold no
-    # attendable token; ring: every resident page can hold in-window tokens
-    live = (i * page_size <= pos + n_q - 1) if window == 0 \
-        else (i * page_size < ring)
-
-    @pl.when(live)
-    def _():
-        # row j*G + g is query j of head group g, at absolute position
-        # pos + j — the decode mask evaluated per row
-        qi = jax.lax.broadcasted_iota(jnp.int32, (q_ref.shape[2], 1), 0) // G
-        for kh in range(q_ref.shape[1]):
-            _attend_page(q_ref[0, kh].astype(jnp.float32),    # [Q*G, D]
-                         page_head(k_ref, kh, ks_ref),
-                         page_head(v_ref, kh, vs_ref), i, pos + qi,
-                         m_scr.at[kh], l_scr.at[kh], acc_scr.at[kh],
-                         page_size=page_size, scale=scale, softcap=softcap,
-                         window=window, ring=ring, row_ok=qi < n_q)
-
-    @pl.when(i == pl.num_programs(1) - 1)
-    def _():
-        for kh in range(q_ref.shape[1]):
-            o_ref[0, kh] = _normalized(l_scr.at[kh], acc_scr.at[kh]).astype(
-                o_ref.dtype)
+    kernel dequantizes in-register after the page DMA.  One grid step per
+    live block of ``pages_per_block`` pages; each page is fetched once and
+    every KV head attends it."""
+    return _walk_call(q, k_pages, v_pages, tables, pos, None, k_scale,
+                      v_scale, G=q.shape[2], scale=scale, softcap=softcap,
+                      window=window, interpret=interpret)
 
 
 def paged_verify_fwd(q, k_pages, v_pages, tables, pos, n_q, *, scale: float,
@@ -279,40 +347,16 @@ def paged_verify_fwd(q, k_pages, v_pages, tables, pos, n_q, *, scale: float,
     """Small-q speculative verify: q [B, K, Q, G, D] — per row the last
     emitted token plus its draft, padded to Q; pos [B] base positions; n_q
     [B] live query counts (1 + draft length).  Same page-table / ring /
-    int8-scale contract as ``paged_decode_fwd``; pages are swept once per
-    row with all Q queries' masks evaluated against them.  Returns
+    int8-scale contract and the same walk as ``paged_decode_fwd``, the
+    extent reaching the last live query's page; each block is fetched once
+    per row with all Q queries' masks evaluated against it.  Returns
     [B, K, Q, G, D]; dead query rows (j >= n_q) are exact zeros."""
     B, K, Q, G, D = q.shape
-    ps = k_pages.shape[1]
-    n_pages = tables.shape[1]
-    quantized = k_scale is not None
-    kernel = functools.partial(
-        _paged_verify_kernel, page_size=ps, scale=scale, softcap=softcap,
-        window=window, ring=n_pages * ps, quantized=quantized, G=G)
     # the (Q, G) query rows flatten outside the kernel: an in-kernel
     # [Q, G, D] -> [Q*G, D] reshape is a shape cast Mosaic refuses for G % 8
-    q_spec = pl.BlockSpec((1, K, Q * G, D),
-                          lambda b, i, tr, pr, nr: (b, 0, 0, 0))
-    in_specs = [q_spec] + _kv_specs(
-        ps, K, D, lambda b, i, tr, pr, nr: (tr[b, i],), quantized)
-    operands = [tables, pos, n_q, q.reshape(B, K, Q * G, D), k_pages, v_pages]
-    if quantized:
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, n_pages),
-        in_specs=in_specs,
-        out_specs=q_spec,
-        scratch_shapes=_online_scratch((K, Q * G), D),
-    )
-    o = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, Q * G, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(*operands)
+    o = _walk_call(q.reshape(B, K, Q * G, D), k_pages, v_pages, tables, pos,
+                   n_q, k_scale, v_scale, G=G, scale=scale, softcap=softcap,
+                   window=window, interpret=interpret)
     return o.reshape(B, K, Q, G, D)
 
 
@@ -329,14 +373,14 @@ def _mla_page(ckv_ref, krope_ref, cs_ref, rs_ref):
     return ckv, kr
 
 
-def _mla_attend_page(qe, qr, ckv, kr, i, pos, m_scr, l_scr, acc_scr, *,
+def _mla_attend_page(qe, qr, ckv, kr, first, pos, m_scr, l_scr, acc_scr, *,
                      scale, **mask):
     s = jax.lax.dot_general(qe, ckv, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     s = s + jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
     # context accumulates in latent space: acc += p @ ckv  -> [rows, L]
-    _fold_page(s * scale, ckv, i, pos, m_scr, l_scr, acc_scr, **mask)
+    _fold_page(s * scale, ckv, first, pos, m_scr, l_scr, acc_scr, **mask)
 
 
 def _mla_paged_decode_kernel(tables_ref, pos_ref, q_eff_ref, q_rope_ref,
@@ -361,8 +405,8 @@ def _mla_paged_decode_kernel(tables_ref, pos_ref, q_eff_ref, q_rope_ref,
         ckv, kr = _mla_page(ckv_ref, krope_ref, cs_ref, rs_ref)
         _mla_attend_page(q_eff_ref[0].astype(jnp.float32),      # [H, L]
                          q_rope_ref[0].astype(jnp.float32),     # [H, R]
-                         ckv, kr, i, pos, m_scr, l_scr, acc_scr,
-                         page_size=page_size, scale=scale)
+                         ckv, kr, i * page_size, pos, m_scr, l_scr,
+                         acc_scr, scale=scale)
 
     @pl.when(i == pl.num_programs(1) - 1)
     def _():
@@ -454,8 +498,8 @@ def _mla_paged_verify_kernel(tables_ref, pos_ref, nq_ref, q_eff_ref,
                                       0) // H
         _mla_attend_page(q_eff_ref[0].astype(jnp.float32),      # [Q*H, L]
                          q_rope_ref[0].astype(jnp.float32),     # [Q*H, R]
-                         ckv, kr, i, pos + qi, m_scr, l_scr, acc_scr,
-                         page_size=page_size, scale=scale, row_ok=qi < n_q)
+                         ckv, kr, i * page_size, pos + qi, m_scr, l_scr,
+                         acc_scr, scale=scale, row_ok=qi < n_q)
 
     @pl.when(i == pl.num_programs(1) - 1)
     def _():

@@ -300,6 +300,14 @@ class Engine:
             "engine.prefill_actual_tokens", "real prompt tokens prefilled")
         self._h_decode_step = self.metrics.histogram(
             "engine.decode_step_s", "fixed-shape decode step wall time")
+        # decode-plan page accounting: walked is each row's live extent
+        # (the table columns the paged decode kernel visits), table is
+        # rows x table width (what a sweep of the whole table visits)
+        pages = self.metrics.counter(
+            "engine.decode_pages", "page-table columns of the decode plans "
+            "built, by kind (walked | table)", labels=("kind",))
+        self._m_pages_walked = pages.labels(kind="walked")
+        self._m_pages_table = pages.labels(kind="table")
         # speculative-decoding accounting: drafts proposed vs accepted
         self._m_spec_proposed = self.metrics.counter(
             "engine.spec_proposed", "draft tokens proposed by the n-gram "
@@ -1039,7 +1047,12 @@ class Engine:
             slot = self.sched.slots[i]
             pos[i] = slot.pos + pos_offset
             tables[i] = slot.table
-        return decode_meta(self.cfg, self.scfg.page_size, tables, pos)
+        # kernels/paged_attention ``live_pages``: through pos's page, at
+        # least the one (null) page an idle row attends
+        ps = self.scfg.page_size
+        self._m_pages_walked.inc(int(np.clip(pos // ps + 1, 1, maxp).sum()))
+        self._m_pages_table.inc(B * maxp)
+        return decode_meta(self.cfg, ps, tables, pos)
 
     def _launch_decode(self, active: List[int]):
         """Launch one fixed-shape decode step, reusing a staged plan when

@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ServeConfig, get_arch, reduced
+from repro.models.attention import quantize_int8
 from repro.models.attn_backend import (available_backends, decode_meta,
                                        get_backend, resolve_backend)
 
@@ -52,27 +53,79 @@ CORE_CASES = [
     (2, 6, 1, 64, 16, 3, 0),         # MQA
     (3, 4, 2, 32, 8, 5, 20),         # sliding-window ring, window < ring
     (2, 4, 2, 16, 4, 4, 16),         # window == ring (every slot in window)
+    # tables of several page blocks (the pallas walk takes 128 tokens a
+    # step), not a multiple of the block, so rows end mid-block; with more
+    # than three rows the last is idle (null table, pos 0) and ring rows
+    # draw positions over three turns of the ring
+    (5, 4, 2, 32, 16, 20, 0),        # 2.5 blocks of 8 pages
+    (4, 6, 1, 16, 8, 37, 0),         # MQA, 2.3 blocks of 16 pages
+    (5, 4, 2, 16, 16, 19, 200),      # ring of 2.4 blocks, window < ring
+    (4, 8, 4, 16, 8, 21, 168),       # ring of 1.3 blocks, window == ring
 ]
 
 
 @pytest.mark.parametrize("B,H,K,D,ps,maxp,window", CORE_CASES)
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, "int8"])
 def test_decode_attend_matches_reference(B, H, K, D, ps, maxp, window, dtype):
     rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.randn(B, H, D), dtype)
-    kp, vp = _pool(rng, 4 * maxp, ps, K, D, dtype)
-    tables = _tables(rng, B, maxp, 4 * maxp)
+    P = max(4, B + 1) * maxp
+    scales = {}
+    if dtype == "int8":
+        q = jnp.asarray(rng.randn(B, H, D), jnp.float32)
+        kp, ks = quantize_int8(jnp.asarray(rng.randn(P, ps, K, D),
+                                           jnp.float32))
+        vp, vs = quantize_int8(jnp.asarray(rng.randn(P, ps, K, D),
+                                           jnp.float32))
+        scales = dict(k_scale=ks, v_scale=vs)
+    else:
+        q = jnp.asarray(rng.randn(B, H, D), dtype)
+        kp, vp = _pool(rng, P, ps, K, D, dtype)
+    tables = _tables(rng, B, maxp, P)
     # positions straddle page boundaries; row 0 pins the pos == 0 edge
-    pos = jnp.asarray(np.concatenate(
-        [[0], rng.randint(1, maxp * ps, size=B - 1)]), jnp.int32)
+    hi = maxp * ps * (3 if window and B > 3 else 1)
+    pos = np.concatenate([[0], rng.randint(1, hi, size=B - 1)])
+    if B > 3:
+        tables, pos[-1] = tables.at[-1].set(0), 0
+    pos = jnp.asarray(pos, jnp.int32)
     scale = 1.0 / math.sqrt(D)
     ref = get_backend("reference").decode_attend(
-        q, kp, vp, tables, pos, scale=scale, window=window)
+        q, kp, vp, tables, pos, scale=scale, window=window, **scales)
     out = get_backend("pallas").decode_attend(
-        q, kp, vp, tables, pos, scale=scale, window=window)
+        q, kp, vp, tables, pos, scale=scale, window=window, **scales)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol, rtol=tol)
+
+
+def test_walk_schedule_visits_live_blocks_and_carries_dead_slots():
+    """The decode walk's schedule: one step per live block of each row, in
+    order; a slot past its row's extent holds the page it held one step
+    before (the pipeline then fetches nothing), or the null page in a
+    row's first block."""
+    from repro.kernels.paged_attention.kernel import walk_schedule
+    ps, ppb, width = 4, 8, 21                 # 21 pages: blocks of 8, 8, 5
+    tables = np.arange(1, 5 * width + 1, dtype=np.int32).reshape(5, width)
+    tables[3] = 0                             # an idle row
+    pos = np.array([0, 30, 83, 0, 50], np.int32)
+    sched, pages, n_steps = walk_schedule(jnp.asarray(tables),
+                                          jnp.asarray(pos), ps, ppb)
+    live = np.minimum(pos // ps + 1, width)   # 1, 8, 21, 1, 13 pages
+    blocks = -(-live // ppb)
+    assert int(n_steps) == blocks.sum() == 8
+    n_blk = -(-width // ppb)
+    steps = [(int(x) // n_blk, int(x) % n_blk)
+             for x in np.asarray(sched)[:int(n_steps)]]
+    assert steps == [(r, b) for r in range(5) for b in range(blocks[r])]
+    pages = np.asarray(pages).reshape(-1, ppb)
+    held = np.zeros(ppb, np.int32)
+    for r, b in steps:
+        slots = pages[r * n_blk + b]
+        for j in range(ppb):
+            col = b * ppb + j
+            want = tables[r, col] if col < live[r] else \
+                (held[j] if b else 0)
+            assert slots[j] == want, (r, b, j)
+        held = slots
 
 
 def test_decode_attend_softcap():
@@ -227,6 +280,37 @@ def test_engine_pallas_exact_token_match(arch):
     assert pal_m["attn_backend"] == "pallas"
     assert pal_m["decode_steps"] > 0 and pal_m["decode_step_ms_p50"] > 0
     assert [r.tokens for r in ref] == [p.tokens for p in pal]
+
+
+def test_engine_decode_pages_counter(monkeypatch):
+    """``engine.decode_pages``: ``walked`` sums each decode plan's live
+    extents (every row through its position's page, an idle row its one
+    null page), ``table`` the rows times the table width."""
+    from repro.serving import Engine
+    from repro.serving import engine as engine_mod
+
+    plans = []
+
+    def recording(cfg, page_size, tables, pos):
+        plans.append((page_size, tables.shape, pos.copy()))
+        return decode_meta(cfg, page_size, tables, pos)
+
+    monkeypatch.setattr(engine_mod, "decode_meta", recording)
+    cfg = dataclasses.replace(reduced(get_arch("qwen2-0.5b")), remat="none")
+    rng = np.random.RandomState(6)
+    prompts = [rng.randint(1, cfg.vocab, size=n).tolist()
+               for n in (5, 17, 30)]
+    eng = Engine(cfg, ServeConfig(page_size=8, max_slots=4, max_len=48),
+                 seed=0)
+    _, metrics = eng.run_offline(prompts, [6, 9, 4])
+    walked = sum(min(p // ps + 1, width) for ps, (_, width), pos in plans
+                 for p in pos.tolist())
+    table = sum(rows * width for _, (rows, width), _ in plans)
+    counters = eng.metrics.snapshot()["counters"]
+    assert metrics["decode_steps"] > 0 and plans
+    assert counters["engine.decode_pages{kind=walked}"] == walked
+    assert counters["engine.decode_pages{kind=table}"] == table
+    assert 0 < walked < table
 
 
 def test_engine_pallas_with_prefix_cache():

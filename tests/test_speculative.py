@@ -72,18 +72,29 @@ VERIFY_CASES = [
     (2, 4, 2, 32, 8, 5, 0, 30.0),      # logit softcap
     (3, 4, 2, 32, 8, 5, 20, 0.0),      # sliding-window ring
     (2, 4, 2, 32, 8, 4, 12, 0.0),      # tighter ring, window < page span
+    # tables of several page blocks (the pallas walk takes 128 tokens a
+    # step), not a multiple of the block, so rows end mid-block; with more
+    # than three rows the last is idle (null table, pos 0, one query) and
+    # ring rows draw positions over three turns of the ring
+    (5, 4, 2, 32, 16, 20, 0, 0.0),     # 2.5 blocks of 8 pages
+    (4, 4, 2, 16, 8, 37, 0, 30.0),     # 2.3 blocks of 16 pages, softcap
+    (5, 4, 2, 16, 16, 19, 200, 0.0),   # ring of 2.4 blocks
 ]
 
 
-def _verify_inputs(rng, B, H, K, D, ps, maxp, Q):
+def _verify_inputs(rng, B, H, K, D, ps, maxp, Q, window=0):
+    P = max(4, B + 1) * maxp
     q = jnp.asarray(rng.randn(B, Q, H, D), jnp.float32)
-    kp = jnp.asarray(rng.randn(4 * maxp, ps, K, D), jnp.float32)
-    vp = jnp.asarray(rng.randn(4 * maxp, ps, K, D), jnp.float32)
-    tables = _tables(rng, B, maxp, 4 * maxp)
+    kp = jnp.asarray(rng.randn(P, ps, K, D), jnp.float32)
+    vp = jnp.asarray(rng.randn(P, ps, K, D), jnp.float32)
+    tables = _tables(rng, B, maxp, P)
     # row 0 keeps the degenerate fresh-sequence case (pos=0, single query);
     # the rest sit anywhere the Q-token window still fits the table span
-    pos = np.concatenate([[0], rng.randint(1, maxp * ps - Q, size=B - 1)])
+    hi = maxp * ps * (3 if window and B > 3 else 1) - Q
+    pos = np.concatenate([[0], rng.randint(1, hi, size=B - 1)])
     n_q = np.concatenate([[1], rng.randint(1, Q + 1, size=B - 1)])
+    if B > 3:
+        tables, pos[-1], n_q[-1] = tables.at[-1].set(0), 0, 1
     return q, kp, vp, tables, jnp.asarray(pos, jnp.int32), \
         jnp.asarray(n_q, jnp.int32)
 
@@ -94,7 +105,7 @@ def test_verify_attend_matches_reference(B, H, K, D, ps, maxp, window,
                                          softcap, Q):
     rng = np.random.RandomState(B * 100 + ps + Q)
     q, kp, vp, tables, pos, n_q = _verify_inputs(rng, B, H, K, D, ps,
-                                                 maxp, Q)
+                                                 maxp, Q, window)
     scale = 1.0 / math.sqrt(D)
     ref = get_backend("reference").verify_attend(
         q, kp, vp, tables, pos, n_q, scale=scale, softcap=softcap,
@@ -185,21 +196,22 @@ def test_int8_mla_verify_attend_matches_reference():
 # -------------------------------------------------------- q_len=1 degeneracy
 
 QLEN1_CASES = [
-    # (window, softcap, int8)
-    (0, 0.0, False),
-    (0, 30.0, False),
-    (20, 0.0, False),
-    (0, 0.0, True),
+    # (window, softcap, int8, maxp); 8-token pages, 16 a block
+    pytest.param(0, 0.0, False, 5, id="0-0.0-False"),
+    pytest.param(0, 30.0, False, 5, id="0-30.0-False"),
+    pytest.param(20, 0.0, False, 5, id="20-0.0-False"),
+    pytest.param(0, 0.0, True, 5, id="0-0.0-True"),
+    pytest.param(0, 0.0, True, 37, id="0-0.0-True-multiblock"),
 ]
 
 
-@pytest.mark.parametrize("window,softcap,int8", QLEN1_CASES)
-def test_verify_qlen1_reproduces_decode_bitexact(window, softcap, int8):
+@pytest.mark.parametrize("window,softcap,int8,maxp", QLEN1_CASES)
+def test_verify_qlen1_reproduces_decode_bitexact(window, softcap, int8, maxp):
     """A verify step with an empty draft must BE a decode step: same pool,
     same masks, same launch math — Pallas vs Pallas is checked bit-exact,
     reference vs reference to fp32 ulp (its two paths order the einsums
     differently)."""
-    B, H, K, D, ps, maxp = 3, 4, 2, 32, 8, 5
+    B, H, K, D, ps = 3, 4, 2, 32, 8
     rng = np.random.RandomState(40 + window + int(softcap) + int8)
     q = jnp.asarray(rng.randn(B, H, D), jnp.float32)
     if int8:

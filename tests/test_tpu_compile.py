@@ -101,6 +101,23 @@ def test_decode_qwen2(one_chip, kv):
              ((B,), I32), scale=1 / math.sqrt(D), **scales)
 
 
+# the benchmark's decode programs (bench/configs): qwen2-0.5b's 64 slots
+# and minitron-4b's 4 (H=24, K=8, D=128), each with 256-page tables over a
+# pool of slots x 256 + 1 pages — the page blocks' VMEM at real widths
+BENCH_DECODE = [
+    pytest.param(dict(H=14, K=2, D=64, B=64, P=16385), id="qwen2-0.5b"),
+    pytest.param(dict(H=24, K=8, D=128, B=4, P=1025), id="minitron-4b"),
+]
+
+
+@pytest.mark.parametrize("shape", BENCH_DECODE)
+def test_decode_benchmark_shapes(one_chip, shape):
+    H, K, D, rows, P = (shape[k] for k in ("H", "K", "D", "B", "P"))
+    _compile(one_chip, paged_attention_decode, ((rows, H, D), BF16),
+             _pool(P, K, D, BF16), _pool(P, K, D, BF16), ((rows, 256), I32),
+             ((rows,), I32), scale=1 / math.sqrt(D))
+
+
 def test_decode_windowed_starcoder2(one_chip):
     H, K, D, n = SC2["H"], SC2["K"], SC2["D"], SC2["n_pages"]
     P = _n_phys(n)
