@@ -34,6 +34,14 @@ class ArchConfig:
 
     # --- attention ---
     rope_theta: float = 10000.0
+    rope_scaling: str = ""           # "" (plain theta powers) | "yarn"
+    rope_factor: float = 1.0         # yarn: context extension factor
+    rope_original_max_pos: int = 0   # yarn: pre-extension context length
+    yarn_beta_fast: float = 32.0     # yarn: rotations bounding the ramp
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0         # yarn: cos/sin scale is mscale /
+    yarn_mscale_all_dim: float = 0.0  # mscale_all_dim; softmax scale
+                                     # gains mscale(mscale_all_dim)^2
     qkv_bias: bool = False
     attn_logit_softcap: float = 0.0
     sliding_window: int = 0          # 0 = full attention
@@ -45,8 +53,14 @@ class ArchConfig:
     d_ff_expert: int = 0
     first_k_dense: int = 0           # leading dense layers (deepseek style)
     d_ff_dense: int = 0              # d_ff of those dense layers
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # training path only: serving is dropless
     router_aux_coef: float = 0.001
+    n_group: int = 1                 # group-limited routing: the top
+    topk_group: int = 1              # topk_group of n_group expert groups
+    norm_topk_prob: bool = True      # renormalize the top-k gate weights
+    routed_scaling_factor: float = 1.0  # unnormalized gate weights x this
+    n_experts_held: int = 0          # experts this chip holds (0: all); the
+    first_expert_held: int = 0       # router still scores all n_experts
 
     # --- MLA (deepseek) ---
     use_mla: bool = False
@@ -82,6 +96,7 @@ class ArchConfig:
 
     # --- misc ---
     norm: str = "rmsnorm"            # rmsnorm | layernorm
+    norm_eps: float = 1e-5
     tie_embeddings: bool = False
     dtype: str = BF16
     remat: str = "full"              # full | dots | none
@@ -116,6 +131,11 @@ class ArchConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights live here (the chip's share)."""
+        return self.n_experts_held or self.n_experts
 
     @property
     def is_attention_free(self) -> bool:
@@ -190,7 +210,7 @@ class ArchConfig:
         if self.is_moe:
             n_dense = self.first_k_dense
             n_moe = self.n_layers - n_dense
-            e = self.top_k if active_only else self.n_experts
+            e = self.top_k if active_only else self.experts_held
             moe_ff = e * mlp_params(self.d_ff_expert)
             moe_ff += self.n_shared_experts * mlp_params(self.d_ff_expert)
             router = self.d_model * self.n_experts
@@ -359,7 +379,13 @@ def reduced(cfg: ArchConfig, **overrides) -> ArchConfig:
     if cfg.is_moe:
         small.update(n_experts=4, top_k=2, d_ff_expert=64,
                      n_shared_experts=min(cfg.n_shared_experts, 1),
-                     first_k_dense=min(cfg.first_k_dense, 1), d_ff_dense=256)
+                     first_k_dense=min(cfg.first_k_dense, 1), d_ff_dense=256,
+                     n_experts_held=0, first_expert_held=0)
+        if cfg.n_group > 1:
+            small.update(n_group=2, topk_group=1)
+    if cfg.rope_scaling == "yarn":
+        # yarn's ramp binds within CPU-size prompts
+        small.update(rope_original_max_pos=64)
     if cfg.use_mla:
         small.update(kv_lora_rank=32, q_lora_rank=64, rope_head_dim=16,
                      nope_head_dim=32, v_head_dim=32, head_dim=0)

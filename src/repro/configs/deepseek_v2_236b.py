@@ -14,7 +14,15 @@ CONFIG = ArchConfig(
     act="silu",
     qkv_bias=False,
     rope_theta=1e4,
+    rope_scaling="yarn",
+    rope_factor=40.0,
+    rope_original_max_pos=4096,
+    yarn_beta_fast=32.0,
+    yarn_beta_slow=1.0,
+    yarn_mscale=0.707,
+    yarn_mscale_all_dim=0.707,
     norm="rmsnorm",
+    norm_eps=1e-6,
     # MoE
     n_experts=160,
     n_shared_experts=2,
@@ -23,6 +31,10 @@ CONFIG = ArchConfig(
     first_k_dense=1,
     d_ff_dense=12288,
     capacity_factor=1.25,
+    n_group=8,                # group_limited_greedy: top-3 of 8 groups
+    topk_group=3,
+    norm_topk_prob=False,     # softmax scores, unnormalized, x 16
+    routed_scaling_factor=16.0,
     # MLA
     use_mla=True,
     kv_lora_rank=512,

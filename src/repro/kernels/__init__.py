@@ -4,8 +4,8 @@
 """Shared Pallas-TPU helper: the interpret-mode rule.
 
 Every kernel package in this tree (``flash_attention``, ``rbm_cd``,
-``paged_attention``, ``ragged_prefill``) follows the same shape: ``kernel.py`` holds the
-``pallas_call`` body, ``ops.py`` the jit'd public wrapper.  The wrappers
+``paged_attention``, ``ragged_prefill``, ``grouped_matmul``) follows the same shape: ``kernel.py`` holds the
+``pallas_call`` body (``grouped_matmul``'s is Megablox's ``gmm``), ``ops.py`` the jit'd public wrapper.  The wrappers
 share one backend rule, hosted here: on CPU (this container, CI) the kernel
 body executes in Pallas interpret mode — bit-accurate to the TPU lowering's
 semantics — and on TPU the same call lowers to Mosaic.

@@ -19,22 +19,22 @@ Two kernel bodies cover every paged decode family in ``models.cache_spec``:
   page block spans every KV head and each head's ``G = H // K`` query group
   attends its slice — GQA never replicates KV, and a page crosses HBM once
   per call.
-* ``_mla_paged_decode_kernel`` — DeepSeek-style absorbed-latent decode.
-  Scores are ``q_eff·ckv + q_rope·krope`` against the rank-``L`` latent pages
-  (one shared "KV head"); the context accumulator stays in latent space
-  (``acc += p·ckv``) so the kernel's output is the ``[H, L]`` context that the
-  caller up-projects with ``w_uv`` — per-head K/V are never materialized.
-  Its grid is ``(B, n_pages)``, one page a step; pages whose first token
-  lies past ``pos`` skip their work via ``pl.when``.
+* ``_mla_walk_kernel`` — DeepSeek-style absorbed-latent decode on the same
+  walk.  Scores are ``q_eff·ckv + q_rope·krope`` against the rank-``L``
+  latent pages (one shared "KV head"); the context accumulator stays in
+  latent space (``acc += p·ckv``) so the kernel's output is the ``[H, L]``
+  context that the caller up-projects with ``w_uv`` — per-head K/V are
+  never materialized.
 
-The decode walk (GQA decode and verify).  A row's *live extent* is its first
+The decode walk (GQA and MLA, decode and verify).  A row's *live extent* is its first
 ``n_live = min(last // ps + 1, n_pages)`` table columns (``live_pages``),
 ``last`` being the position of its last query: ``pos`` for decode,
 ``pos + n_q - 1`` for verify.  The count serves the page ring too: a column
 past ``last // ps`` has never been written, and every written one can hold
 in-window tokens, so the ring rule masks inside the extent as before.  The
 extent splits into blocks of ``ppb`` pages (``pages_per_block``: 128
-tokens, fewer for a narrow table or the VMEM budget), and the grid has one
+tokens, fewer for a narrow table or the VMEM budget of the family's page
+blocks), and the grid has one
 step per live block of every row, rows in order — its length is a dynamic
 grid bound that ``walk_schedule`` computes on the device from the
 positions, with each step's row and block and each page slot's physical
@@ -51,8 +51,8 @@ HBM ref's two minor dims to its tiling and refuses a slice of a pool whose
 head dim is under 128 lanes (qwen2's 64), or of the ``[P, ps, K]`` int8
 scales.
 
-Each decode body has a small-q *verify* twin (``_walk_kernel`` with
-``verify`` / ``_mla_paged_verify_kernel``) for speculative decoding: the q
+Each decode body has a small-q *verify* twin (``_walk_kernel`` /
+``_mla_walk_kernel`` with ``verify``) for speculative decoding: the q
 block carries ``Q = 1 + K`` query tokens per row (last emitted token +
 draft), a further scalar-prefetch operand ``n_q`` gives each row's live
 query count, and the mask becomes per-query causal — query ``j`` sits at
@@ -168,15 +168,17 @@ def _vmem_bytes(shape, dtype):
             * size)
 
 
-def pages_per_block(page_size, K, D, dtype, n_pages, scale_dtype=None):
-    """Pages per step of the decode walk: ``BLOCK_TOKENS`` worth, at most
-    the table width, halved until the double-buffered page blocks of K and
-    V (and their int8 scales) fit ``BLOCK_VMEM_BYTES``."""
+def pages_per_block(page_size, n_pages, page_blocks):
+    """Pages per step of a decode walk: ``BLOCK_TOKENS`` worth, at most the
+    table width, halved until the double-buffered page blocks fit
+    ``BLOCK_VMEM_BYTES``.  ``page_blocks`` lists the (shape, dtype) of one
+    page's block of every pool the walk fetches: K and V (and their int8
+    scales) for GQA, the latent ``ckv`` and ``krope`` (and their scales)
+    for MLA — one rule for every family."""
+    per_page = 2 * sum(_vmem_bytes(shape, dt) for shape, dt in page_blocks)
+
     def fits(ppb):
-        n = 4 * ppb * _vmem_bytes((page_size, K, D), dtype)
-        if scale_dtype is not None:
-            n += 4 * ppb * _vmem_bytes((page_size, K), scale_dtype)
-        return n <= BLOCK_VMEM_BYTES
+        return ppb * per_page <= BLOCK_VMEM_BYTES
     ppb = max(1, min(n_pages, BLOCK_TOKENS // page_size))
     while ppb > 1 and not fits(ppb):
         ppb //= 2
@@ -281,8 +283,10 @@ def _walk_call(q, k_pages, v_pages, tables, pos, n_q, k_scale, v_scale, *,
     B, K, rows, D = q.shape
     ps, n_pages = k_pages.shape[1], tables.shape[1]
     quantized = k_scale is not None
-    ppb = pages_per_block(ps, K, D, k_pages.dtype, n_pages,
-                          k_scale.dtype if quantized else None)
+    blocks = [((ps, K, D), k_pages.dtype)] * 2
+    if quantized:
+        blocks += [((ps, K), k_scale.dtype)] * 2
+    ppb = pages_per_block(ps, n_pages, blocks)
     n_blk = -(-n_pages // ppb)
     sched, pages, n_steps = walk_schedule(
         tables, pos if n_q is None else pos + n_q - 1, ps, ppb)
@@ -373,65 +377,123 @@ def _mla_page(ckv_ref, krope_ref, cs_ref, rs_ref):
     return ckv, kr
 
 
-def _mla_attend_page(qe, qr, ckv, kr, first, pos, m_scr, l_scr, acc_scr, *,
-                     scale, **mask):
-    s = jax.lax.dot_general(qe, ckv, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s + jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    # context accumulates in latent space: acc += p @ ckv  -> [rows, L]
-    _fold_page(s * scale, ckv, first, pos, m_scr, l_scr, acc_scr, **mask)
+def _mla_walk_kernel(sched_ref, pages_ref, pos_ref, *refs, page_size: int,
+                     n_pages: int, ppb: int, scale: float, quantized: bool,
+                     H: int, verify: bool):
+    """Absorbed-latent decode and verify (``verify``: a fourth scalar
+    operand ``n_q``) on the decode walk: one step per live block of ``ppb``
+    latent pages.  Scores ``q_eff·ckv + q_rope·krope`` of every query row
+    against the block's tokens in table order, scaled after the dots, folded
+    into the online softmax with the latent ``ckv`` as values."""
+    del pages_ref          # read by the index maps only
+    nq_ref, refs = (refs[0], refs[1:]) if verify else (None, refs)
+    qe_ref, qr_ref, refs = refs[0], refs[1], refs[2:]
+    n_ops = 4 if quantized else 2
+    pages = [refs[a * ppb:(a + 1) * ppb] for a in range(n_ops)]
+    o_ref, m_scr, l_scr, acc_scr = refs[n_ops * ppb:]
+    ckv_refs, kr_refs = pages[:2]
+    cs_refs, rs_refs = pages[2:] if quantized else ([None] * ppb,) * 2
+    ps, n_blk = page_size, -(-n_pages // ppb)
+    s = pl.program_id(0)
+    r, blk = sched_ref[s] // n_blk, sched_ref[s] % n_blk
+    last = pos_ref[r] if nq_ref is None else pos_ref[r] + nq_ref[r] - 1
+    live = live_pages(last, ps, n_pages)
 
-
-def _mla_paged_decode_kernel(tables_ref, pos_ref, q_eff_ref, q_rope_ref,
-                             ckv_ref, krope_ref, *rest, page_size: int,
-                             scale: float, quantized: bool):
-    if quantized:
-        cs_ref, rs_ref, ctx_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        cs_ref = rs_ref = None
-        ctx_ref, m_scr, l_scr, acc_scr = rest
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
+    @pl.when(blk == 0)
     def _():
         _init(m_scr, l_scr, acc_scr)
 
-    pos = pos_ref[b]
+    pos = pos_ref[r]
+    row_ok = None
+    if nq_ref is not None:
+        # row j*H + h is query j of head h, at absolute position pos + j
+        qi = jax.lax.broadcasted_iota(jnp.int32, (qe_ref.shape[1], 1), 0) // H
+        pos, row_ok = pos + qi, qi < nq_ref[r]
+    block = [_mla_page(ckv_refs[j], kr_refs[j], cs_refs[j], rs_refs[j])
+             for j in range(ppb)]
+    ckv = jnp.concatenate([c for c, _ in block], axis=0)          # [T, L]
+    kr = jnp.concatenate([k for _, k in block], axis=0)           # [T, R]
+    qe = qe_ref[0].astype(jnp.float32)                            # [rows, L]
+    sc = jax.lax.dot_general(qe, ckv, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    sc = sc + jax.lax.dot_general(qr_ref[0].astype(jnp.float32), kr,
+                                  (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+    first = blk * ppb * ps
+    # slots past the extent hold a carried or null page: masked
+    ok = first + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1) < live * ps
+    # context accumulates in latent space: acc += p @ ckv  -> [rows, L]
+    _fold_page(sc * scale, ckv, first, pos, m_scr, l_scr, acc_scr,
+               row_ok=ok if row_ok is None else ok & row_ok)
 
-    @pl.when(i * page_size <= pos)
+    @pl.when(blk == (live + ppb - 1) // ppb - 1)
     def _():
-        ckv, kr = _mla_page(ckv_ref, krope_ref, cs_ref, rs_ref)
-        _mla_attend_page(q_eff_ref[0].astype(jnp.float32),      # [H, L]
-                         q_rope_ref[0].astype(jnp.float32),     # [H, R]
-                         ckv, kr, i * page_size, pos, m_scr, l_scr,
-                         acc_scr, scale=scale)
-
-    @pl.when(i == pl.num_programs(1) - 1)
-    def _():
-        ctx_ref[0] = _normalized(l_scr, acc_scr).astype(ctx_ref.dtype)
-
-
-def _mla_specs(H, L, R, ps, index_map, quantized):
-    """q blocks [1, rows, L] / [1, rows, R], latent page blocks, and — when
-    int8 — [1, ps, 1] scale blocks of the [P, ps, 1] scale view."""
-    specs = [
-        pl.BlockSpec((1, H, L), lambda b, i, *_: (b, 0, 0)),
-        pl.BlockSpec((1, H, R), lambda b, i, *_: (b, 0, 0)),
-        pl.BlockSpec((1, ps, L), lambda *a: index_map(*a) + (0, 0)),
-        pl.BlockSpec((1, ps, R), lambda *a: index_map(*a) + (0, 0)),
-    ]
-    if quantized:
-        sc = pl.BlockSpec((1, ps, 1), lambda *a: index_map(*a) + (0, 0))
-        specs += [sc, sc]
-    return specs
+        o_ref[0] = _normalized(l_scr, acc_scr).astype(o_ref.dtype)
 
 
 def scale_view(s):
     """[P, ps] latent scales as [P, ps, 1]: a page's block then equals the
     array's last two dims, which the (8, 128) tiling rule requires."""
     return s.reshape(s.shape[:2] + (1,))
+
+
+def mla_pages_per_block(ckv_pages, krope_pages, n_pages, quantized):
+    """``pages_per_block`` with the latent page shapes."""
+    ps = ckv_pages.shape[1]
+    blocks = [((ps, ckv_pages.shape[2]), ckv_pages.dtype),
+              ((ps, krope_pages.shape[2]), krope_pages.dtype)]
+    if quantized:
+        blocks += [((ps, 1), jnp.bfloat16)] * 2
+    return pages_per_block(ps, n_pages, blocks)
+
+
+def _mla_walk_call(q_eff, q_rope, ckv_pages, krope_pages, tables, pos, n_q,
+                   ckv_scale, krope_scale, *, H, scale, interpret):
+    """``pallas_call`` of the latent walk: q_eff [B, rows, L], q_rope
+    [B, rows, R] (rows = H for decode, Q*H for verify with ``n_q``); each
+    latent pool (and int8 scale view) is passed once per page slot of a
+    block, its index map reading the slot's page from the schedule."""
+    B, rows, L = q_eff.shape
+    R = q_rope.shape[-1]
+    ps, n_pages = ckv_pages.shape[1], tables.shape[1]
+    quantized = ckv_scale is not None
+    ppb = mla_pages_per_block(ckv_pages, krope_pages, n_pages, quantized)
+    n_blk = -(-n_pages // ppb)
+    sched, pages, n_steps = walk_schedule(
+        tables, pos if n_q is None else pos + n_q - 1, ps, ppb)
+    prefetch = [sched, pages, pos] + ([] if n_q is None else [n_q])
+    pools = [ckv_pages, krope_pages]
+    if quantized:
+        pools += [scale_view(ckv_scale), scale_view(krope_scale)]
+
+    def page_spec(pool, j):
+        return pl.BlockSpec((1,) + pool.shape[1:],
+                            lambda s, sched_ref, pages_ref, *_: (
+                                pages_ref[sched_ref[s] * ppb + j], 0, 0))
+
+    def row_spec(width):
+        return pl.BlockSpec((1, rows, width), lambda s, sched_ref, *_: (
+            sched_ref[s] // n_blk, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(n_steps,),
+        in_specs=[row_spec(L), row_spec(R)] + [page_spec(p, j) for p in pools
+                                               for j in range(ppb)],
+        out_specs=row_spec(L),
+        scratch_shapes=_online_scratch((rows,), L),
+    )
+    return pl.pallas_call(
+        functools.partial(_mla_walk_kernel, page_size=ps, n_pages=n_pages,
+                          ppb=ppb, scale=scale, quantized=quantized, H=H,
+                          verify=n_q is not None),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q_eff.shape, q_eff.dtype),
+        # a row's blocks run in order, carrying the online-softmax state
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*prefetch, q_eff, q_rope, *[p for p in pools for _ in range(ppb)])
 
 
 def mla_paged_decode_fwd(q_eff, q_rope, ckv_pages, krope_pages, tables, pos,
@@ -441,69 +503,11 @@ def mla_paged_decode_fwd(q_eff, q_rope, ckv_pages, krope_pages, tables, pos,
     ckv_pages: [P, ps, L]; krope_pages: [P, ps, R]; tables: [B, n_pages];
     pos: [B].  Returns the latent context [B, H, L].  ``ckv_scale``/
     ``krope_scale``: [P, ps] bf16 per-token absmax scales when the latent
-    pages are int8-quantized."""
-    B, H, L = q_eff.shape
-    R = q_rope.shape[-1]
-    ps = ckv_pages.shape[1]
-    n_pages = tables.shape[1]
-    quantized = ckv_scale is not None
-    kernel = functools.partial(_mla_paged_decode_kernel, page_size=ps,
-                               scale=scale, quantized=quantized)
-    in_specs = _mla_specs(H, L, R, ps, lambda b, i, tr, pr: (tr[b, i],),
-                          quantized)
-    operands = [tables, pos, q_eff, q_rope, ckv_pages, krope_pages]
-    if quantized:
-        operands += [scale_view(ckv_scale), scale_view(krope_scale)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, n_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, L), lambda b, i, tr, pr: (b, 0, 0)),
-        scratch_shapes=_online_scratch((H,), L),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, L), q_eff.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(*operands)
-
-
-def _mla_paged_verify_kernel(tables_ref, pos_ref, nq_ref, q_eff_ref,
-                             q_rope_ref, ckv_ref, krope_ref, *rest,
-                             page_size: int, scale: float, quantized: bool,
-                             H: int):
-    if quantized:
-        cs_ref, rs_ref, ctx_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        cs_ref = rs_ref = None
-        ctx_ref, m_scr, l_scr, acc_scr = rest
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _():
-        _init(m_scr, l_scr, acc_scr)
-
-    pos = pos_ref[b]
-    n_q = nq_ref[b]
-
-    @pl.when(i * page_size <= pos + n_q - 1)
-    def _():
-        ckv, kr = _mla_page(ckv_ref, krope_ref, cs_ref, rs_ref)
-        # row j*H + h is query j of head h, at absolute position pos + j
-        qi = jax.lax.broadcasted_iota(jnp.int32, (q_eff_ref.shape[1], 1),
-                                      0) // H
-        _mla_attend_page(q_eff_ref[0].astype(jnp.float32),      # [Q*H, L]
-                         q_rope_ref[0].astype(jnp.float32),     # [Q*H, R]
-                         ckv, kr, i * page_size, pos + qi, m_scr, l_scr,
-                         acc_scr, scale=scale, row_ok=qi < n_q)
-
-    @pl.when(i == pl.num_programs(1) - 1)
-    def _():
-        ctx_ref[0] = _normalized(l_scr, acc_scr).astype(ctx_ref.dtype)
+    pages are int8-quantized.  One grid step per live block of
+    ``pages_per_block`` latent pages (the GQA walk's schedule)."""
+    return _mla_walk_call(q_eff, q_rope, ckv_pages, krope_pages, tables, pos,
+                          None, ckv_scale, krope_scale, H=q_eff.shape[1],
+                          scale=scale, interpret=interpret)
 
 
 def mla_paged_verify_fwd(q_eff, q_rope, ckv_pages, krope_pages, tables, pos,
@@ -511,36 +515,14 @@ def mla_paged_verify_fwd(q_eff, q_rope, ckv_pages, krope_pages, tables, pos,
                          krope_scale=None, interpret: bool = False):
     """Small-q absorbed-latent verify: q_eff [B, Q, H, L] / q_rope
     [B, Q, H, R] against the latent pages, with pos/n_q as in
-    ``paged_verify_fwd``.  Returns the latent context [B, Q, H, L]; dead
-    query rows are exact zeros."""
+    ``paged_verify_fwd`` and the same walk as ``mla_paged_decode_fwd``, the
+    extent reaching the last live query's page.  Returns the latent context
+    [B, Q, H, L]; dead query rows are exact zeros."""
     B, Q, H, L = q_eff.shape
     R = q_rope.shape[-1]
-    ps = ckv_pages.shape[1]
-    n_pages = tables.shape[1]
-    quantized = ckv_scale is not None
-    kernel = functools.partial(_mla_paged_verify_kernel, page_size=ps,
-                               scale=scale, quantized=quantized, H=H)
-    in_specs = _mla_specs(Q * H, L, R, ps,
-                          lambda b, i, tr, pr, nr: (tr[b, i],), quantized)
     # query rows flatten outside the kernel (no in-kernel shape cast)
-    operands = [tables, pos, n_q, q_eff.reshape(B, Q * H, L),
-                q_rope.reshape(B, Q * H, R), ckv_pages, krope_pages]
-    if quantized:
-        operands += [scale_view(ckv_scale), scale_view(krope_scale)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, n_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Q * H, L),
-                               lambda b, i, tr, pr, nr: (b, 0, 0)),
-        scratch_shapes=_online_scratch((Q * H,), L),
-    )
-    ctx = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Q * H, L), q_eff.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(*operands)
+    ctx = _mla_walk_call(q_eff.reshape(B, Q * H, L),
+                         q_rope.reshape(B, Q * H, R), ckv_pages, krope_pages,
+                         tables, pos, n_q, ckv_scale, krope_scale, H=H,
+                         scale=scale, interpret=interpret)
     return ctx.reshape(B, Q, H, L)

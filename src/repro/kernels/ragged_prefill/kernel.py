@@ -21,17 +21,21 @@ Three kernel bodies cover every paged prefill family in
   the absolute position recovered from the ring layout relative to
   ``start - 1``, and the chunk's fresh K/V ride in as extra key blocks with
   the causal+window rule.
-* ``_mla_ragged_prefill_kernel`` — MLA materialized-K.  Per latent page, the
-  per-head K (``ckv @ w_uk`` ++ roped ``krope``) and V (``ckv @ w_uv``) are
-  materialized *inside the kernel* — rounded to the cache dtype at exactly
-  the point the reference einsum rounds — so the [B, S, H, *] K/V tensors
-  the reference path builds in HBM never exist.
+* ``_mla_prefill_walk_kernel`` — MLA materialized-K on the decode walk
+  (``paged_attention.kernel.walk_schedule``): each query block of a chunk
+  row walks the row's latent pages a block of pages at a time, for a group
+  of heads.  Per block, the per-head K (``ckv @ w_uk`` ++ roped ``krope``)
+  and V (``ckv @ w_uv``) are materialized *inside the kernel* — rounded to
+  the cache dtype at exactly the point the reference einsum rounds — so the
+  [B, S, H, *] K/V tensors the reference path builds in HBM never exist.
 
 Numerics match the reference chunked path's rounding points exactly: fp32
 scores (scale after the dot, softcap after scale), one softmax at the true
 global max over the row's full key set (a two-phase page sweep — scores
 first, probability-weighted values second — rather than an online softmax,
-so the probabilities round at the same max as the reference), probabilities
+so the probabilities round at the same max as the reference; the MLA walk
+takes the max and normalizer in a first pass over the pages and the
+probabilities in a second), probabilities
 rounded to the value dtype before the PV product, fp32 PV accumulation, one
 cast at the block output.
 """
@@ -44,7 +48,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..paged_attention.kernel import page_head, scale_view
+from ..paged_attention.kernel import (_init, _mla_page, live_pages,
+                                      mla_pages_per_block, page_head,
+                                      scale_view, walk_schedule)
 
 # the reference mask constant (models.attention.NEG_INF): finite, so a
 # fully-masked row softmaxes to the same uniform distribution the reference
@@ -409,140 +415,172 @@ def windowed_ragged_prefill_fwd(q, k_new, v_new, k_pages, v_pages, tables,
 
 # ------------------------------------------------------ MLA materialized-K
 
-def _mla_ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref,
-                               ckv_ref, kr_ref, wuk_ref, wuv_ref, *rest,
-                               page_size: int, n_pages: int, q_blk: int,
-                               scale: float, kv_dtype, quantized: bool):
-    if quantized:
-        cs_ref, rs_ref, o_ref, s_scr, acc_scr = rest
-    else:
-        o_ref, s_scr, acc_scr = rest
-    b = pl.program_id(0)
-    qb = pl.program_id(2)
-    i = pl.program_id(3)
-    start = start_ref[b]
-    T, E = q_ref.shape[2], q_ref.shape[3]
-    j = jnp.where(i < n_pages, i, i - n_pages)
-    q_abs = start + qb * q_blk \
-        + jax.lax.broadcasted_iota(jnp.int32, (page_size, T), 1)
+def _mla_prefill_walk_kernel(sched_ref, pages_ref, base_ref, q_ref, wuk_ref,
+                             wuv_ref, *refs, page_size: int, n_pages: int,
+                             ppb: int, scale: float, kv_dtype,
+                             quantized: bool):
+    """One step of the MLA prefill walk, for the grid step's group of
+    heads: one block of ``ppb`` latent pages against one query block
+    (queries at ``base + j``), in one of its two passes.  Each head's K
+    (``ckv @ w_uk`` ++ ``krope``) is materialized from the block, rounded
+    to the cache dtype where the reference einsum rounds.  The first pass
+    folds the masked scores into each query row's running max and
+    normalizer; the second recomputes them, turns them into probabilities
+    at the row's true max, rounds those to the value dtype (the reference's
+    ``softmax(s).astype(v.dtype)``) and accumulates them against the
+    materialized V (``ckv @ w_uv``) in fp32."""
+    del pages_ref          # read by the index maps only
+    n_ops = 4 if quantized else 2
+    pages = [refs[a * ppb:(a + 1) * ppb] for a in range(n_ops)]
+    o_ref, m_scr, l_scr, acc_scr = refs[n_ops * ppb:]
+    ckv_refs, kr_refs = pages[:2]
+    cs_refs, rs_refs = pages[2:] if quantized else ([None] * ppb,) * 2
+    ps, n_blk = page_size, -(-n_pages // ppb)
+    step = pl.program_id(1)
+    vrow, blk = sched_ref[step] // n_blk, sched_ref[step] % n_blk
+    second = vrow % 2 == 1
+    G, T = q_ref.shape[1], q_ref.shape[2]
+    base = base_ref[vrow // 2]
+    live = live_pages(base + T - 1, ps, n_pages)
 
-    @pl.when(i < n_pages)
+    @pl.when((blk == 0) & jnp.logical_not(second))
     def _():
-        k_abs = j * page_size \
-            + jax.lax.broadcasted_iota(jnp.int32, (page_size, T), 0)
-        live_page = j * page_size <= start + qb * q_blk + q_blk - 1
+        _init(m_scr, l_scr, acc_scr)
 
-        @pl.when(live_page)
-        def _():
-            ckv = ckv_ref[0].astype(jnp.float32)                 # [ps, L]
-            kr = kr_ref[0].astype(jnp.float32)                   # [ps, R]
-            if quantized:
-                ckv = ckv * cs_ref[0].astype(jnp.float32)
-                kr = kr * rs_ref[0].astype(jnp.float32)
-            wuk = wuk_ref[0].astype(jnp.float32)                 # [L, nope]
-            # materialize this page's per-head K, rounded to the cache dtype
-            # exactly where the reference ``ckv @ wkv_b`` einsum rounds
-            k_nope = jax.lax.dot_general(
-                ckv, wuk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32).astype(kv_dtype)
-            k = jnp.concatenate([k_nope.astype(jnp.float32), kr], axis=-1)
-            s = _scores_t(q_ref[0, 0].astype(jnp.float32), k, scale)
-            _store_scores(s_scr, j * page_size, s, k_abs <= q_abs)
+    block = [_mla_page(ckv_refs[j], kr_refs[j], cs_refs[j], rs_refs[j])
+             for j in range(ppb)]
+    ckv = jnp.concatenate([c for c, _ in block], axis=0).astype(kv_dtype)
+    kr = jnp.concatenate([k for _, k in block], axis=0).astype(kv_dtype)
+    idx = blk * ppb * ps + jax.lax.broadcasted_iota(
+        jnp.int32, (T, ckv.shape[0]), 1)
+    # causal, and slots past the extent hold a carried or null page
+    valid = (idx <= base + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)) \
+        & (idx < live * ps)
 
-        @pl.when(jnp.logical_not(live_page))
-        def _():
-            _mask_page(s_scr, j * page_size, page_size)
-
-    @pl.when(i == n_pages - 1)
-    def _():
-        _softmax_rows(s_scr)
-
-    @pl.when(i == n_pages)
-    def _():
-        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
-
-    @pl.when(i >= n_pages)
-    def _():
-        ckv = ckv_ref[0].astype(jnp.float32)
-        if quantized:
-            ckv = ckv * cs_ref[0].astype(jnp.float32)
-        wuv = wuv_ref[0].astype(jnp.float32)                     # [L, vd]
-        v = jax.lax.dot_general(
-            ckv, wuv, (((1,), (0,)), ((), ())),
+    def scores(h):
+        k_nope = jax.lax.dot_general(
+            ckv, wuk_ref[h].astype(kv_dtype), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32).astype(kv_dtype)
-        _pv_accumulate(acc_scr, s_scr, j * page_size,
-                       v.astype(jnp.float32), kv_dtype)
+        k = jnp.concatenate([k_nope, kr], axis=-1)                # [Tk, E]
+        return jax.lax.dot_general(
+            q_ref[0, h].astype(kv_dtype), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
 
-    @pl.when(i == 2 * n_pages - 1)
+    @pl.when(jnp.logical_not(second))
     def _():
-        o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
+        for h in range(G):
+            sc = jnp.where(valid, scores(h), NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+            l_scr[h] = l_scr[h] * jnp.exp(m_prev - m_new) + jnp.sum(
+                jnp.where(valid, jnp.exp(sc - m_new), 0.0), axis=1,
+                keepdims=True)
+            m_scr[h] = m_new
+
+    @pl.when(second)
+    def _():
+        for h in range(G):
+            p = jnp.where(valid, jnp.exp(scores(h) - m_scr[h]), 0.0) \
+                / l_scr[h]
+            vh = jax.lax.dot_general(
+                ckv, wuv_ref[h].astype(kv_dtype), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(kv_dtype)
+            acc_scr[h] += jax.lax.dot_general(
+                p.astype(kv_dtype), vh, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    @pl.when(second & (blk == (live + ppb - 1) // ppb - 1))
+    def _():
+        o_ref[0] = acc_scr[...].astype(o_ref.dtype)
+
+
+def mla_head_group(H: int) -> int:
+    """Heads per grid step of the MLA prefill walk: at most 8, dividing H
+    (the group's ``w_uk`` / ``w_uv`` blocks stay in VMEM for its walk)."""
+    g = min(H, 8)
+    while H % g:
+        g -= 1
+    return g
 
 
 def mla_ragged_prefill_fwd(q, ckv_pages, krope_pages, w_uk, w_uv, tables,
-                           start, n_live, *, scale: float, q_blk: int = 128,
+                           start, n_live, *, scale: float, q_blk: int = 512,
                            ckv_scale=None, krope_scale=None,
                            interpret: bool = False):
-    """q: [B, H, T, nope+rope] (rope part roped); ckv_pages: [P, ps, L];
-    krope_pages: [P, ps, R]; w_uk: [H, L, nope]; w_uv: [H, L, vd] (head-
-    major, so a head's block spans the array's last two dims); tables:
-    [B, n_pages].  Returns the attended values [B, H, T, vd].
-    ``ckv_scale``/``krope_scale``: [P, ps] bf16 scales when the latent pages
-    are int8 — the dequantized latent is fp32, so the in-kernel K/V
-    materialization stays fp32 (``kv_dtype``) exactly like the reference
-    dequant einsum."""
+    """q: [B, H, T, nope+rope] (rope part roped; T a ``q_blk`` multiple);
+    ckv_pages: [P, ps, L]; krope_pages: [P, ps, R]; w_uk: [H, L, nope];
+    w_uv: [H, L, vd] (head-major, so a head group's block spans the array's
+    last two dims); tables: [B, n_pages].  Returns the attended values
+    [B, H, T, vd].  ``ckv_scale``/``krope_scale``: [P, ps] bf16 scales when
+    the latent pages are int8 — the dequantized latent is fp32, so the
+    in-kernel K/V materialization stays fp32 (``kv_dtype``) exactly like
+    the reference dequant einsum.
+
+    The decode walk (``paged_attention.kernel.walk_schedule``) over virtual
+    rows: each chunk row's ``T / q_blk`` query blocks, block ``i`` at base
+    position ``start + i * q_blk``, each walked twice in a row (the
+    kernel's two passes) through its last query's page, ``pages_per_block``
+    pages a step, with the grid's outer axis over groups of
+    ``mla_head_group`` heads.  ``n_live`` does not mask: padding queries
+    attend causally like the reference's, and their rows are sliced off by
+    the caller."""
+    del n_live
     B, H, T, E = q.shape
-    L = ckv_pages.shape[2]
-    vd = w_uv.shape[2]
-    ps = ckv_pages.shape[1]
-    n_pages = tables.shape[1]
+    L, vd = ckv_pages.shape[2], w_uv.shape[2]
+    ps, n_pages = ckv_pages.shape[1], tables.shape[1]
     n_qb = T // q_blk
     quantized = ckv_scale is not None
-    kernel = functools.partial(
-        _mla_ragged_prefill_kernel, page_size=ps, n_pages=n_pages,
-        q_blk=q_blk, scale=scale,
-        kv_dtype=jnp.float32 if quantized else ckv_pages.dtype,
-        quantized=quantized)
-
-    def _page_map(b, h, qb, i, tr, st, nl):
-        return (tr[b, jnp.where(i < n_pages, i, i - n_pages)], 0, 0)
-
-    def _scale_map(b, h, qb, i, tr, st, nl):
-        return (tr[b, jnp.where(i < n_pages, i, i - n_pages)], 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, q_blk, E),
-                     lambda b, h, qb, i, tr, st, nl: (b, h, qb, 0)),
-        pl.BlockSpec((1, ps, L), _page_map),
-        pl.BlockSpec((1, ps, krope_pages.shape[2]), _page_map),
-        pl.BlockSpec((1, L, w_uk.shape[2]),
-                     lambda b, h, qb, i, tr, st, nl: (h, 0, 0)),
-        pl.BlockSpec((1, L, vd),
-                     lambda b, h, qb, i, tr, st, nl: (h, 0, 0)),
-    ]
-    operands = [tables, start, n_live, q, ckv_pages, krope_pages, w_uk, w_uv]
+    G = mla_head_group(H)
+    ppb = mla_pages_per_block(ckv_pages, krope_pages, n_pages, quantized)
+    n_blk = -(-n_pages // ppb)
+    V = B * n_qb
+    base = (start[:, None] + q_blk * jnp.arange(n_qb, dtype=jnp.int32)
+            ).reshape(V)
+    # each query block is walked twice in a row (the two passes)
+    sched, pages, n_steps = walk_schedule(
+        jnp.repeat(tables, 2 * n_qb, axis=0),
+        jnp.repeat(base, 2) + q_blk - 1, ps, ppb)
+    qv = q.reshape(B, H, n_qb, q_blk, E).transpose(0, 2, 1, 3, 4).reshape(
+        V, H, q_blk, E)
+    pools = [ckv_pages, krope_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, ps, 1), _scale_map),
-                     pl.BlockSpec((1, ps, 1), _scale_map)]
-        operands += [scale_view(ckv_scale), scale_view(krope_scale)]
+        pools += [scale_view(ckv_scale), scale_view(krope_scale)]
+
+    def page_spec(pool, j):
+        return pl.BlockSpec((1,) + pool.shape[1:],
+                            lambda g, s, sched_ref, pages_ref, _: (
+                                pages_ref[sched_ref[s] * ppb + j], 0, 0))
+
+    def row_spec(width):
+        return pl.BlockSpec((1, G, q_blk, width), lambda g, s, sched_ref, *_: (
+            sched_ref[s] // n_blk // 2, g, 0, 0))
+
+    def head_spec(width):
+        return pl.BlockSpec((G, L, width), lambda g, s, *_: (g, 0, 0))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, H, n_qb, 2 * n_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, q_blk, vd),
-            lambda b, h, qb, i, tr, st, nl: (b, h, qb, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((n_pages * ps, q_blk), jnp.float32),
-            pltpu.VMEM((q_blk, vd), jnp.float32),
-        ],
+        grid=(H // G, n_steps),
+        in_specs=[row_spec(E), head_spec(w_uk.shape[2]), head_spec(vd)]
+        + [page_spec(p, j) for p in pools for j in range(ppb)],
+        out_specs=row_spec(vd),
+        scratch_shapes=[pltpu.VMEM((G, q_blk, 1), jnp.float32),
+                        pltpu.VMEM((G, q_blk, 1), jnp.float32),
+                        pltpu.VMEM((G, q_blk, vd), jnp.float32)],
     )
-    return pl.pallas_call(
-        kernel,
+    o = pl.pallas_call(
+        functools.partial(
+            _mla_prefill_walk_kernel, page_size=ps, n_pages=n_pages, ppb=ppb,
+            scale=scale, quantized=quantized,
+            kv_dtype=jnp.float32 if quantized else ckv_pages.dtype),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, T, vd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((V, H, q_blk, vd), q.dtype),
+        # a virtual row's blocks run in order, carrying the softmax state
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(*operands)
+    )(sched, pages, base, qv, w_uk, w_uv,
+      *[p for p in pools for _ in range(ppb)])
+    return o.reshape(B, n_qb, H, q_blk, vd).transpose(0, 2, 1, 3, 4).reshape(
+        B, H, T, vd)

@@ -82,23 +82,27 @@ def ragged_prefill_attend(q, k_new, v_new, k_pages, v_pages, tables, start,
     return o[:, :, :T0].transpose(0, 2, 1, 3, 4).reshape(B, T0, H, D)
 
 
-@partial(jax.jit, static_argnames=("nope", "q_blk", "interpret"))
+@partial(jax.jit, static_argnames=("nope", "scale", "q_blk", "interpret"))
 def mla_ragged_prefill_attend(q, ckv_pages, krope_pages, wkv_b, tables, start,
-                              n_live, *, nope: int, q_blk: int = 128,
-                              ckv_scale=None, krope_scale=None,
-                              interpret: bool = None):
+                              n_live, *, nope: int, scale: float = None,
+                              q_blk: int = 512, ckv_scale=None,
+                              krope_scale=None, interpret: bool = None):
     """Ragged MLA chunk-prefill attend against the post-write latent pages.
 
     q: [B, T, H, nope+rope] (rope part already roped); ckv_pages:
     [P, ps, L]; krope_pages: [P, ps, R]; wkv_b: [L, H, nope + v_head_dim];
-    tables: [B, n_pages].  Per-head K/V are materialized page-by-page inside
-    the kernel (``ckv @ w_uk`` ++ krope, ``ckv @ w_uv``) with the reference
-    einsum's rounding.  Returns [B, T, H, v_head_dim].  ``ckv_scale``/
-    ``krope_scale``: [P, ps] bf16 scales when the latent pages are int8."""
+    tables: [B, n_pages].  Per-head K/V are materialized a block of pages at
+    a time inside the kernel (``ckv @ w_uk`` ++ krope, ``ckv @ w_uv``) with
+    the reference einsum's rounding.  ``scale`` defaults to
+    ``(nope + rope) ** -0.5``.  A query block spans the whole chunk up to
+    ``q_blk`` tokens: each block re-materializes the K/V it walks, so one
+    block a chunk row does that work once.  Returns [B, T, H, v_head_dim].
+    ``ckv_scale``/``krope_scale``: [P, ps] bf16 scales when the latent pages
+    are int8."""
     B, T, H, E = q.shape
-    scale = 1.0 / math.sqrt(E)
+    scale = 1.0 / math.sqrt(E) if scale is None else scale
     qg = q.transpose(0, 2, 1, 3)                       # [B, H, T, E]
-    blk = fit_q_block(T, 1, tables.shape[1] * ckv_pages.shape[1], q_blk)
+    blk = min(q_blk, -(-T // 16) * 16)
     qg, T0 = _pad_q(qg, blk)
     w = wkv_b.transpose(1, 0, 2)                       # [H, L, nope+vd]
     w_uk, w_uv = w[..., :nope], w[..., nope:]

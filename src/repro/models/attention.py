@@ -63,12 +63,14 @@ def chunked_attention(
     softcap: float = 0.0,
     q_offset=0,              # absolute position of q[0] relative to k[0];
                              # int (static) or [B] int32 (per-row, traced)
+    scale: Optional[float] = None,   # default D ** -0.5
     unroll: bool = False,
 ) -> jax.Array:
     B, Sq, H, D = q.shape
     K = k.shape[2]
     G = H // K
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     q_block = min(q_block, Sq)
     # pad Sq to a multiple of q_block
     pad = (-Sq) % q_block

@@ -285,9 +285,9 @@ class AttentionBackend:
         raise NotImplementedError
 
     def mla_prefill_attend(self, q, ckv_pages, krope_pages, wkv_b, tables,
-                           start, n_live, *, nope: int, q_block: int = 512,
-                           unroll: bool = False, ckv_scale=None,
-                           krope_scale=None):
+                           start, n_live, *, nope: int, scale: float = None,
+                           q_block: int = 512, unroll: bool = False,
+                           ckv_scale=None, krope_scale=None):
         """Ragged MLA prefill attend: materialized-K semantics against the
         post-write latent pages (see ``mla.mla_materialized_prefill_attend``,
         the reference formulation).  q: [B, T, H, nope+rope]; returns
@@ -403,12 +403,12 @@ class ReferenceBackend(AttentionBackend):
         return o.astype(q.dtype)
 
     def mla_prefill_attend(self, q, ckv_pages, krope_pages, wkv_b, tables,
-                           start, n_live, *, nope: int, q_block: int = 512,
-                           unroll: bool = False, ckv_scale=None,
-                           krope_scale=None):
+                           start, n_live, *, nope: int, scale: float = None,
+                           q_block: int = 512, unroll: bool = False,
+                           ckv_scale=None, krope_scale=None):
         o = mla.mla_materialized_prefill_attend(
             q, ckv_pages, krope_pages, wkv_b, tables, start, n_live,
-            nope=nope, q_block=q_block, unroll=unroll,
+            nope=nope, scale=scale, q_block=q_block, unroll=unroll,
             ckv_scale=ckv_scale, krope_scale=krope_scale)
         return o.astype(q.dtype)
 
@@ -463,10 +463,10 @@ class PallasBackend(ReferenceBackend):
                                      v_scale=v_scale)
 
     def mla_prefill_attend(self, q, ckv_pages, krope_pages, wkv_b, tables,
-                           start, n_live, *, nope: int, q_block: int = 512,
-                           unroll: bool = False, ckv_scale=None,
-                           krope_scale=None):
+                           start, n_live, *, nope: int, scale: float = None,
+                           q_block: int = 512, unroll: bool = False,
+                           ckv_scale=None, krope_scale=None):
         return mla_ragged_prefill_attend(q, ckv_pages, krope_pages, wkv_b,
                                          tables, start, n_live, nope=nope,
-                                         ckv_scale=ckv_scale,
+                                         scale=scale, ckv_scale=ckv_scale,
                                          krope_scale=krope_scale)

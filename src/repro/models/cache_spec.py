@@ -41,6 +41,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Tuple
 
+# Physical page 0 of every paged pool is reserved: idle rows' tables point
+# at it and padding writes land in it.
+NULL_PAGE = 0
+
 
 def window_pages(window: int, page_size: int) -> int:
     """Ring horizon in pages for a sliding window.

@@ -1,6 +1,19 @@
-"""Shared neural layers: norms, activations, MLPs, RoPE, embeddings."""
+"""Shared neural layers: norms, activations, MLPs, RoPE, embeddings.
+
+Norms take their epsilon from the configuration (``norm_eps``).  Rotary
+frequencies are ``rope_theta`` powers, or with ``rope_scaling == "yarn"``
+the YaRN blend (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``): each
+frequency is interpolated (divided by ``rope_factor``) below the
+correction range that ``yarn_beta_fast`` / ``yarn_beta_slow`` rotations
+over ``rope_original_max_pos`` bound, kept as is above it, and blended by
+a linear ramp inside it, at every position.  Attention then scales its
+softmax by ``yarn_mscale(rope_factor, yarn_mscale_all_dim)**2``
+(``softmax_scale``); the cos/sin scale ``mscale / mscale_all_dim`` must be
+1, as it is for DeepSeek-V2, and anything else is refused.
+"""
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -18,7 +31,8 @@ def norm_defs(cfg: ArchConfig, d: int):
     return {"scale": ParamDef((d,), ("embed",), init="ones")}
 
 
-def apply_norm(cfg: ArchConfig, p, x, eps: float = 1e-5):
+def apply_norm(cfg: ArchConfig, p, x):
+    eps = cfg.norm_eps
     if cfg.norm_fp32:
         xf = x.astype(jnp.float32)
         if cfg.norm == "layernorm":
@@ -42,6 +56,8 @@ def apply_norm(cfg: ArchConfig, p, x, eps: float = 1e-5):
 
 
 def rmsnorm(x, scale, eps: float = 1e-5):
+    """RMSNorm of a sub-layer's own vector (MLA's latent and query norms
+    pass the configuration's ``norm_eps``; SSM's gated norm keeps 1e-5)."""
     xf = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(xf), -1, keepdims=True)
     return (xf * jax.lax.rsqrt(ms + eps) * scale.astype(jnp.float32)).astype(x.dtype)
@@ -87,9 +103,48 @@ def apply_mlp(cfg: ArchConfig, p, x):
 
 # ----------------------------------------------------------------------- RoPE
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor ``0.1 * mscale * ln(factor) + 1``
+    (1 for no extension)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(cfg: ArchConfig, dim: int):
+    """Rotary dims (of ``dim // 2``) between which YaRN's ramp runs: those
+    that turn ``yarn_beta_fast`` and ``yarn_beta_slow`` times over the
+    original context, floored and ceiled, clipped to the dims."""
+    def at(rot):
+        return (dim * math.log(cfg.rope_original_max_pos / (rot * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+    low = math.floor(at(cfg.yarn_beta_fast))
+    high = math.ceil(at(cfg.yarn_beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def softmax_scale(cfg: ArchConfig, qk_dim: int) -> float:
+    """``qk_dim ** -0.5``, times YaRN's ``mscale(mscale_all_dim)**2``."""
+    scale = 1.0 / math.sqrt(qk_dim)
+    if cfg.rope_scaling == "yarn" and cfg.yarn_mscale_all_dim:
+        m = yarn_mscale(cfg.rope_factor, cfg.yarn_mscale_all_dim)
+        scale = scale * m * m
+    return scale
+
+
 def rope_freqs(cfg: ArchConfig, head_dim: int) -> jax.Array:
     half = head_dim // 2
-    return 1.0 / (cfg.rope_theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    extra = 1.0 / (cfg.rope_theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if cfg.rope_scaling != "yarn":
+        return extra
+    if yarn_mscale(cfg.rope_factor, cfg.yarn_mscale) != yarn_mscale(
+            cfg.rope_factor, cfg.yarn_mscale_all_dim):
+        raise NotImplementedError("yarn with a cos/sin scale other than 1")
+    low, high = yarn_correction_range(cfg, head_dim)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low) / (high - low),
+                    0.0, 1.0)
+    keep = 1.0 - ramp                      # 1: extrapolate, 0: interpolate
+    return extra / cfg.rope_factor * (1.0 - keep) + extra * keep
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, freqs: jax.Array) -> jax.Array:

@@ -6,10 +6,14 @@ decode uses the *absorbed* formulation so the per-token cache is only
 the decode_32k cell fit, and is the TPU-native analogue of the paper-era concern of
 shipping the full weight matrix to every mapper (here: shipping the full KV to every
 chip) being the bottleneck.
+
+Every path — full, paged prefill, decode, verify — scales its scores by
+``mla_scale(cfg)``: ``(nope + rope) ** -0.5``, times YaRN's
+``mscale(rope_factor, yarn_mscale_all_dim) ** 2`` when the rotary is
+yarn-scaled (``layers.softmax_scale``), and the query and latent norms
+use the configuration's ``norm_eps``.
 """
 from __future__ import annotations
-
-import math
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +21,7 @@ import jax.numpy as jnp
 from ..configs.base import ArchConfig
 from .attention import (NEG_INF, chunked_attention, dequant_int8,
                         gather_pages, quantize_int8)
-from .layers import apply_rope, rmsnorm
+from .layers import apply_rope, rmsnorm, softmax_scale
 from .params import ParamDef
 
 
@@ -40,9 +44,21 @@ def mla_defs(cfg: ArchConfig):
     return defs
 
 
+def mla_scale(cfg: ArchConfig) -> float:
+    """The softmax scale of every MLA path: ``(nope + rope) ** -0.5``, times
+    YaRN's ``mscale**2`` when the rotary is yarn-scaled."""
+    return softmax_scale(cfg, cfg.nope_head_dim + cfg.rope_head_dim)
+
+
+def latent_norm(cfg: ArchConfig, p, ckv_full):
+    """The normed rank-``kv_lora`` latent of ``x @ wkv_a``."""
+    return rmsnorm(ckv_full[..., :cfg.kv_lora_rank], p["kv_norm"],
+                   cfg.norm_eps)
+
+
 def _queries(cfg: ArchConfig, p, x):
     if cfg.q_lora_rank:
-        cq = rmsnorm(x @ p["wq_a"], p["q_norm"])
+        cq = rmsnorm(x @ p["wq_a"], p["q_norm"], cfg.norm_eps)
         q = jnp.einsum("bsl,lhe->bshe", cq, p["wq_b"])
     else:
         q = jnp.einsum("bsd,dhe->bshe", x, p["wq"])
@@ -60,7 +76,7 @@ def mla_full_block(cfg: ArchConfig, p, x, freqs, *, positions=None, q_block=512,
     q_rope = apply_rope(q_rope, positions, freqs)
 
     ckv_full = x @ p["wkv_a"]
-    ckv = rmsnorm(ckv_full[..., :cfg.kv_lora_rank], p["kv_norm"])
+    ckv = latent_norm(cfg, p, ckv_full)
     k_rope = ckv_full[..., cfg.kv_lora_rank:][:, :, None, :]       # [B,S,1,rope]
     k_rope = apply_rope(k_rope, positions, freqs)
 
@@ -68,7 +84,8 @@ def mla_full_block(cfg: ArchConfig, p, x, freqs, *, positions=None, q_block=512,
     k_nope, v = kv[..., :nope], kv[..., nope:]
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:-1] + (rope_d,))], -1)
     qq = jnp.concatenate([q_nope, q_rope], -1)
-    o = chunked_attention(qq, k, v, causal=True, q_block=q_block, unroll=unroll)
+    o = chunked_attention(qq, k, v, causal=True, q_block=q_block,
+                          scale=mla_scale(cfg), unroll=unroll)
     return jnp.einsum("bshe,hed->bsd", o, p["wo"])
 
 
@@ -125,7 +142,7 @@ def mla_paged_prefill_block(cfg: ArchConfig, p, x, cache, meta, freqs,
     q_rope = apply_rope(q_rope, positions, freqs)
 
     ckv_full = x @ p["wkv_a"]
-    ckv = rmsnorm(ckv_full[..., :cfg.kv_lora_rank], p["kv_norm"])
+    ckv = latent_norm(cfg, p, ckv_full)
     krope = apply_rope(ckv_full[..., cfg.kv_lora_rank:][:, :, None, :],
                        positions, freqs)[:, :, 0, :]
 
@@ -141,8 +158,8 @@ def mla_paged_prefill_block(cfg: ArchConfig, p, x, cache, meta, freqs,
 
     qq = jnp.concatenate([q_nope, q_rope], -1)
     o = backend.mla_prefill_attend(qq, cc, cr, p["wkv_b"], tables, start,
-                                   n_live, nope=nope, q_block=q_block,
-                                   unroll=unroll, **scales)
+                                   n_live, nope=nope, scale=mla_scale(cfg),
+                                   q_block=q_block, unroll=unroll, **scales)
     new_cache = {"ckv": cc, "krope": cr}
     new_cache.update(scales)
     return jnp.einsum("bshe,hed->bsd", o, p["wo"]), new_cache
@@ -150,8 +167,9 @@ def mla_paged_prefill_block(cfg: ArchConfig, p, x, cache, meta, freqs,
 
 def mla_materialized_prefill_attend(q, ckv_pages, krope_pages, wkv_b, tables,
                                     start, n_live, *, nope: int,
-                                    q_block: int = 512, unroll: bool = False,
-                                    ckv_scale=None, krope_scale=None):
+                                    scale: float = None, q_block: int = 512,
+                                    unroll: bool = False, ckv_scale=None,
+                                    krope_scale=None):
     """The reference MLA prefill attend: gather the (post-write) latent
     pages, materialize per-head K/V from them with ``wkv_b`` exactly as
     ``mla_full_block`` does — so a cached prefix or an earlier chunk is read
@@ -172,7 +190,7 @@ def mla_materialized_prefill_attend(q, ckv_pages, krope_pages, wkv_b, tables,
         [k_nope, jnp.broadcast_to(crg[:, :, None, :],
                                   k_nope.shape[:-1] + (rope_d,))], -1)
     return chunked_attention(q, k, v, causal=True, q_block=q_block,
-                             q_offset=start, unroll=unroll)
+                             q_offset=start, scale=scale, unroll=unroll)
 
 
 def mla_paged_decode_block(cfg: ArchConfig, p, x, cache, meta, freqs,
@@ -183,16 +201,16 @@ def mla_paged_decode_block(cfg: ArchConfig, p, x, cache, meta, freqs,
     ckv/krope pages, context in rank-``kv_lora`` space) is delegated to
     ``backend.mla_decode_attend``."""
     B = x.shape[0]
-    nope, rope_d = cfg.nope_head_dim, cfg.rope_head_dim
+    nope = cfg.nope_head_dim
     pos = meta["pos"]
-    scale = 1.0 / math.sqrt(nope + rope_d)
+    scale = mla_scale(cfg)
 
     q = _queries(cfg, p, x[:, None, :])[:, 0]                      # [B,H,·]
     q_nope, q_rope = q[..., :nope], q[..., nope:]
     q_rope = apply_rope(q_rope[:, None], pos[:, None], freqs)[:, 0]
 
     ckv_full = x @ p["wkv_a"]
-    ckv_new = rmsnorm(ckv_full[..., :cfg.kv_lora_rank], p["kv_norm"])
+    ckv_new = latent_norm(cfg, p, ckv_full)
     kr_new = apply_rope(ckv_full[..., None, cfg.kv_lora_rank:][:, None],
                         pos[:, None], freqs)[:, 0, 0]
 
@@ -271,9 +289,9 @@ def mla_paged_verify_block(cfg: ArchConfig, p, x, cache, meta, freqs,
     ``attention.paged_verify_attention_block`` for the rollback contract.
     Returns (out [B, Q, d], new_cache)."""
     Q = x.shape[1]
-    nope, rope_d = cfg.nope_head_dim, cfg.rope_head_dim
+    nope = cfg.nope_head_dim
     pos = meta["pos"]
-    scale = 1.0 / math.sqrt(nope + rope_d)
+    scale = mla_scale(cfg)
     positions = pos[:, None] + jnp.arange(Q)[None, :]              # [B, Q]
 
     q = _queries(cfg, p, x)                                        # [B,Q,H,·]
@@ -281,7 +299,7 @@ def mla_paged_verify_block(cfg: ArchConfig, p, x, cache, meta, freqs,
     q_rope = apply_rope(q_rope, positions, freqs)
 
     ckv_full = x @ p["wkv_a"]
-    ckv_new = rmsnorm(ckv_full[..., :cfg.kv_lora_rank], p["kv_norm"])
+    ckv_new = latent_norm(cfg, p, ckv_full)
     kr_new = apply_rope(ckv_full[..., cfg.kv_lora_rank:][:, :, None, :],
                         positions, freqs)[:, :, 0, :]
 
@@ -310,15 +328,15 @@ def mla_paged_verify_block(cfg: ArchConfig, p, x, cache, meta, freqs,
 def mla_decode_block(cfg: ArchConfig, p, x, cache, pos, freqs):
     """Absorbed one-token decode.  x: [B, d]."""
     B = x.shape[0]
-    nope, rope_d = cfg.nope_head_dim, cfg.rope_head_dim
-    scale = 1.0 / math.sqrt(nope + rope_d)
+    nope = cfg.nope_head_dim
+    scale = mla_scale(cfg)
 
     q = _queries(cfg, p, x[:, None, :])[:, 0]                      # [B,H,nope+rope]
     q_nope, q_rope = q[..., :nope], q[..., nope:]
     q_rope = apply_rope(q_rope[:, None], pos[:, None], freqs)[:, 0]
 
     ckv_full = x @ p["wkv_a"]
-    ckv_new = rmsnorm(ckv_full[..., :cfg.kv_lora_rank], p["kv_norm"])
+    ckv_new = latent_norm(cfg, p, ckv_full)
     kr_new = apply_rope(ckv_full[..., None, cfg.kv_lora_rank:][:, None], pos[:, None], freqs)[:, 0, 0]
 
     b = jnp.arange(B)

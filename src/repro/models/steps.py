@@ -15,6 +15,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import ArchConfig, ShapeConfig
@@ -140,8 +141,15 @@ def make_serve_step(cfg: ArchConfig, mesh: Optional[Mesh], kind: str,
 
        ``attn_backend`` selects the paged-attention backend the paged kinds
        route through (``reference`` gather+attend | ``pallas`` fused decode
-       kernel)."""
+       kernel).
+
+       For MoE configurations the decode and prefill kinds also carry each
+       MoE layer's held pairs and held experts touched
+       (``DecoderLM._paged_layers``) in the outputs the engine already
+       reads: appended to ``next_tokens`` after the B rows, and as one row
+       after the B logits rows (``pack_counts``)."""
     model = build_model(cfg, attn_backend)
+    moe = {"moe_stats": True} if cfg.is_moe else {}
     # each step is named after its kind, so the compiled module
     # (``jit_decode_paged``, ...) says which step ran or recompiled
     if kind == "decode":
@@ -152,10 +160,12 @@ def make_serve_step(cfg: ArchConfig, mesh: Optional[Mesh], kind: str,
         return decode
     if kind == "decode_paged":
         def decode_paged(params, kv, state, meta, tokens):
-            logits, kv, state = model.decode_paged(params, kv, state, meta,
-                                                   tokens, mesh)
+            logits, kv, state, *stats = model.decode_paged(
+                params, kv, state, meta, tokens, mesh, **moe)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             ok = jnp.isfinite(logits).all(axis=-1)
+            if stats:
+                nxt = jnp.concatenate([nxt, stats[0].reshape(-1)])
             return nxt, ok, kv, state
         return decode_paged
     if kind == "verify_paged":
@@ -168,8 +178,13 @@ def make_serve_step(cfg: ArchConfig, mesh: Optional[Mesh], kind: str,
         return verify_paged
     if kind == "prefill_paged":
         def prefill_paged(params, kv, state, meta, tokens, extras):
-            return model.prefill_paged(params, kv, state, meta, tokens,
-                                       extras, mesh)
+            logits, kv, state, *stats = model.prefill_paged(
+                params, kv, state, meta, tokens, extras, mesh, **moe)
+            if stats:
+                logits = jnp.concatenate(
+                    [logits, pack_counts(stats[0], logits.shape[-1],
+                                         logits.dtype)])
+            return logits, kv, state
         return prefill_paged
     if kind == "prefill_paged_cont":
         # continuation chunks of a long prompt: pure page work — enc-dec
@@ -187,6 +202,27 @@ def make_serve_step(cfg: ArchConfig, mesh: Optional[Mesh], kind: str,
     def prefill(params, batch):
         return model.prefill(params, batch, mesh)
     return prefill
+
+
+COUNT_DIGITS = 3      # base-256 digits of a packed count: below 2**24
+
+
+def pack_counts(counts, width: int, dtype):
+    """Non-negative int counts as one [1, width] row of ``dtype``: each
+    count's base-256 digits, least significant first — digits below 256
+    are exact in any float type, bf16 included."""
+    flat = counts.reshape(-1).astype(jnp.int32)
+    digits = jnp.stack([(flat >> (8 * i)) & 255
+                        for i in range(COUNT_DIGITS)], -1).reshape(-1)
+    return jnp.zeros((1, width), dtype).at[0, :digits.size].set(
+        digits.astype(dtype))
+
+
+def unpack_counts(row, n: int):
+    """The ``n`` counts ``pack_counts`` wrote in ``row`` (host numpy)."""
+    digits = np.asarray(row[:n * COUNT_DIGITS], np.float64).astype(
+        np.int64).reshape(n, COUNT_DIGITS)
+    return digits @ (256 ** np.arange(COUNT_DIGITS))
 
 
 def abstract_serve_args(cfg: ArchConfig, shape: ShapeConfig, mesh: Mesh):

@@ -19,12 +19,12 @@ from . import shardings
 from .attention import (attn_defs, cache_defs, decode_attention_block,
                         full_attention_block, paged_cache_defs)
 from .attn_backend import get_backend
-from .cache_spec import CacheFamilySpec, CacheSpec
+from .cache_spec import NULL_PAGE, CacheFamilySpec, CacheSpec
 from .layers import (apply_mlp, apply_norm, embed_defs, embed_tokens, lm_logits,
                      mlp_defs, norm_defs, rope_freqs)
-from .mla import (mla_cache_defs, mla_decode_block, mla_defs, mla_full_block,
-                  mla_paged_cache_defs)
-from .moe import moe_apply, moe_decode_apply, moe_defs
+from .mla import (latent_norm, mla_cache_defs, mla_decode_block, mla_defs,
+                  mla_full_block, mla_paged_cache_defs)
+from .moe import moe_apply, moe_defs, moe_serve_apply
 from .params import ParamDef, stack_tree
 from .rglru import (rglru_block, rglru_cache_defs, rglru_decode_block, rglru_defs)
 from .ssm import (ssm_block, ssm_cache_defs, ssm_decode_block, ssm_defs)
@@ -333,8 +333,9 @@ class DecoderLM:
             else:
                 a, c2 = decode_attention_block(cfg, p["attn"], h, c, pos, freqs)
             x = x + a
-            x = x + moe_decode_apply(cfg, p["moe"], apply_norm(cfg, p["ln2"], x), mesh=mesh)
-            return x, c2
+            m, _, _ = moe_serve_apply(cfg, p["moe"], apply_norm(cfg, p["ln2"], x),
+                                      jnp.ones(x.shape[:1], bool))
+            return x + m, c2
 
         def rec_step(x, p, c):
             r, c2 = rglru_decode_block(cfg, p["rec"], apply_norm(cfg, p["ln1"], x), c)
@@ -460,8 +461,7 @@ class DecoderLM:
                 if cfg.use_mla:
                     a = mla_full_block(cfg, p["attn"], h, freqs, q_block=cfg.attn_q_block, unroll=cfg.unroll)
                     ckv_full = h @ p["attn"]["wkv_a"]
-                    from .layers import rmsnorm as _rn
-                    ckv = _rn(ckv_full[..., :cfg.kv_lora_rank], p["attn"]["kv_norm"])
+                    ckv = latent_norm(cfg, p["attn"], ckv_full)
                     from .layers import apply_rope as _ar
                     krope = _ar(ckv_full[..., cfg.kv_lora_rank:][:, :, None, :],
                                 positions, freqs)[:, :, 0, :]
@@ -491,8 +491,11 @@ class DecoderLM:
                         c = {"k": k, "v": v}
                 x = x + a
                 if "moe" in p:
-                    m, _ = moe_apply(cfg, p["moe"], apply_norm(cfg, p["ln2"], x), mesh=mesh)
-                    x = x + m
+                    h2 = apply_norm(cfg, p["ln2"], x)
+                    m, _, _ = moe_serve_apply(
+                        cfg, p["moe"], h2.reshape(B * S, -1),
+                        jnp.ones((B * S,), bool))
+                    x = x + m.reshape(x.shape)
                 else:
                     x = x + apply_mlp(cfg, p["mlp"], apply_norm(cfg, p["ln2"], x))
                 return x, c
@@ -585,7 +588,55 @@ class DecoderLM:
             cfg, p["attn"], h, c, meta, freqs,
             q_block=cfg.attn_q_block, unroll=cfg.unroll)
 
-    def decode_paged(self, params, kv, state, meta, tokens, mesh=None):
+    def _paged_layers(self, params, kv, x, attend, live):
+        """The layer stack of a paged serving step.  ``attend(p, h, c)`` is
+        the step's paged attention (returns the output and the layer's new
+        pool); MoE layers run the dropless expert-share layer
+        (``moe.moe_serve_apply``) over the tokens ``live`` marks ([*x.shape
+        [:-1]] bool).  Returns (x, new_kv, stats): ``stats`` [n_moe_layers,
+        2] int32 holds each MoE layer's held pairs and held experts touched
+        (None for a dense model)."""
+        cfg = self.cfg
+
+        def dense_step(x, pc):
+            p, c = pc
+            h = apply_norm(cfg, p["ln1"], x)
+            a, c2 = attend(p, h, c)
+            x = x + a
+            x = x + apply_mlp(cfg, p["mlp"], apply_norm(cfg, p["ln2"], x))
+            return x, c2
+
+        def moe_step(x, pc):
+            p, c = pc
+            h = apply_norm(cfg, p["ln1"], x)
+            a, c2 = attend(p, h, c)
+            x = x + a
+            h = apply_norm(cfg, p["ln2"], x)
+            m, held, touched = moe_serve_apply(
+                cfg, p["moe"], h.reshape(-1, h.shape[-1]), live.reshape(-1))
+            return x + m.reshape(x.shape), (c2, jnp.stack([held, touched]))
+
+        if not cfg.is_moe:
+            x, new_kv = _scan_blocks(dense_step, x, params["blocks"], kv,
+                                     unroll=cfg.unroll)
+            return x, new_kv, None
+        k = cfg.first_k_dense
+        if not k:
+            x, (new_kv, stats) = _scan_blocks(moe_step, x, params["blocks"],
+                                              kv, unroll=cfg.unroll)
+            return x, new_kv, stats
+        head = jax.tree.map(lambda a: a[:k], kv)
+        tail = jax.tree.map(lambda a: a[k:], kv)
+        x, nhead = _scan_blocks(dense_step, x, params["dense_blocks"], head,
+                                unroll=cfg.unroll)
+        x, (ntail, stats) = _scan_blocks(moe_step, x, params["blocks"], tail,
+                                         unroll=cfg.unroll)
+        new_kv = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), nhead,
+                              ntail)
+        return x, new_kv, stats
+
+    def decode_paged(self, params, kv, state, meta, tokens, mesh=None,
+                     moe_stats: bool = False):
         """One-token continuous-batching decode step.
 
         kv: layer-stacked paged pool ({} for state-slot families); state:
@@ -594,9 +645,11 @@ class DecoderLM:
         ``attn_backend.decode_meta`` — per-slot page-table rows, [B] int32
         absolute positions, and the new token's precomputed physical write
         target, derived once by the engine instead of per block; tokens: [B]
-        int32.  Returns (logits [B, V], new_kv, new_state).  Idle rows ride
-        along masked: their table rows point at the reserved null page and
-        their state rows are overwritten at the next admission's prefill."""
+        int32.  Returns (logits [B, V], new_kv, new_state), and with
+        ``moe_stats`` the MoE layers' ``stats`` (``_paged_layers``).  Idle
+        rows ride along masked: their table rows point at the reserved null
+        page (they route no expert pair) and their state rows are
+        overwritten at the next admission's prefill."""
         cfg = self.cfg
         pos = meta["pos"]
         if cfg.family in ("ssm", "hybrid"):
@@ -607,57 +660,13 @@ class DecoderLM:
             return logits, kv, new_cache
         x = embed_tokens(params["embed"], tokens)
         freqs = self._freqs()
-
-        def dense_step(x, p, c):
-            h = apply_norm(cfg, p["ln1"], x)
-            a, c2 = self._paged_attn_decode(p, h, c, meta, freqs)
-            x = x + a
-            x = x + apply_mlp(cfg, p["mlp"], apply_norm(cfg, p["ln2"], x))
-            return x, c2
-
-        def moe_step(x, p, c):
-            h = apply_norm(cfg, p["ln1"], x)
-            a, c2 = self._paged_attn_decode(p, h, c, meta, freqs)
-            x = x + a
-            x = x + moe_decode_apply(cfg, p["moe"], apply_norm(cfg, p["ln2"], x),
-                                     mesh=mesh)
-            return x, c2
-
-        if cfg.is_moe:
-            k = cfg.first_k_dense
-            if k:
-                head = jax.tree.map(lambda a: a[:k], kv)
-                tail = jax.tree.map(lambda a: a[k:], kv)
-
-                def dbody(x, pc):
-                    p, c = pc
-                    return dense_step(x, p, c)
-                x, nhead = _scan_blocks(dbody, x, params["dense_blocks"], head,
-                                        unroll=cfg.unroll)
-
-                def mbody(x, pc):
-                    p, c = pc
-                    return moe_step(x, p, c)
-                x, ntail = _scan_blocks(mbody, x, params["blocks"], tail,
-                                        unroll=cfg.unroll)
-                new_kv = jax.tree.map(
-                    lambda a, b: jnp.concatenate([a, b]), nhead, ntail)
-            else:
-                def mbody(x, pc):
-                    p, c = pc
-                    return moe_step(x, p, c)
-                x, new_kv = _scan_blocks(mbody, x, params["blocks"], kv,
-                                         unroll=cfg.unroll)
-        else:
-            def dbody(x, pc):
-                p, c = pc
-                return dense_step(x, p, c)
-            x, new_kv = _scan_blocks(dbody, x, params["blocks"], kv,
-                                     unroll=cfg.unroll)
-
+        x, new_kv, stats = self._paged_layers(
+            params, kv, x,
+            lambda p, h, c: self._paged_attn_decode(p, h, c, meta, freqs),
+            live=meta["tables"][:, 0] != NULL_PAGE)
         x = apply_norm(cfg, params["final_norm"], x)
         logits = lm_logits(cfg, params["embed"], x)
-        return logits, new_kv, state
+        return (logits, new_kv, state) + ((stats,) if moe_stats else ())
 
     def verify_paged(self, params, kv, state, meta, tokens, mesh=None):
         """Small-q speculative verify step — ``decode_paged`` over
@@ -670,9 +679,8 @@ class DecoderLM:
         MoE, logits) is the exact per-row computation of the decode step, so
         row ``j`` of the returned logits equals the decode step's logits at
         position ``pos + j`` bit-for-bit — which is what lets the engine
-        accept drafted tokens without changing the greedy stream.  The MoE
-        path routes each slot's Q tokens as one group at full capacity
-        (``cap=Q``) so capacity dropping can never couple tokens.  Returns
+        accept drafted tokens without changing the greedy stream (the MoE
+        layer is dropless, so no token's output depends on another's).  Returns
         (logits [B, Q, V], new_kv, state).  Speculation is gated to paged
         decoder-only families (``serving.speculate.speculation_k``), so the
         state-slot route of ``decode_paged`` has no verify twin."""
@@ -681,60 +689,20 @@ class DecoderLM:
             "speculative verify requires a paged cache family"
         x = embed_tokens(params["embed"], tokens)              # [B, Q, d]
         freqs = self._freqs()
-
-        def dense_step(x, p, c):
-            h = apply_norm(cfg, p["ln1"], x)
-            a, c2 = self._paged_attn_verify(p, h, c, meta, freqs)
-            x = x + a
-            x = x + apply_mlp(cfg, p["mlp"], apply_norm(cfg, p["ln2"], x))
-            return x, c2
-
-        def moe_step(x, p, c):
-            h = apply_norm(cfg, p["ln1"], x)
-            a, c2 = self._paged_attn_verify(p, h, c, meta, freqs)
-            x = x + a
-            m, _ = moe_apply(cfg, p["moe"], apply_norm(cfg, p["ln2"], x),
-                             mesh=mesh, cap=x.shape[1])
-            return x + m, c2
-
-        if cfg.is_moe:
-            k = cfg.first_k_dense
-            if k:
-                head = jax.tree.map(lambda a: a[:k], kv)
-                tail = jax.tree.map(lambda a: a[k:], kv)
-
-                def dbody(x, pc):
-                    p, c = pc
-                    return dense_step(x, p, c)
-                x, nhead = _scan_blocks(dbody, x, params["dense_blocks"],
-                                        head, unroll=cfg.unroll)
-
-                def mbody(x, pc):
-                    p, c = pc
-                    return moe_step(x, p, c)
-                x, ntail = _scan_blocks(mbody, x, params["blocks"], tail,
-                                        unroll=cfg.unroll)
-                new_kv = jax.tree.map(
-                    lambda a, b: jnp.concatenate([a, b]), nhead, ntail)
-            else:
-                def mbody(x, pc):
-                    p, c = pc
-                    return moe_step(x, p, c)
-                x, new_kv = _scan_blocks(mbody, x, params["blocks"], kv,
-                                         unroll=cfg.unroll)
-        else:
-            def dbody(x, pc):
-                p, c = pc
-                return dense_step(x, p, c)
-            x, new_kv = _scan_blocks(dbody, x, params["blocks"], kv,
-                                     unroll=cfg.unroll)
-
+        Q = tokens.shape[1]
+        live = (meta["tables"][:, :1] != NULL_PAGE) \
+            & (jnp.arange(Q)[None, :] < meta["n_q"][:, None])
+        x, new_kv, _ = self._paged_layers(
+            params, kv, x,
+            lambda p, h, c: self._paged_attn_verify(p, h, c, meta, freqs),
+            live)
         x = apply_norm(cfg, params["final_norm"], x)
         logits = lm_logits(cfg, params["embed"], x)
         return logits, new_kv, state
 
     def prefill_paged(self, params, kv, state, meta, tokens, extras=None,
-                      mesh=None, continuation: bool = False):
+                      mesh=None, continuation: bool = False,
+                      moe_stats: bool = False):
         """Chunk prefill at an offset, straight into the paged pool and/or
         the state-slot pool.  ``continuation`` is a no-op for decoder-only
         models (chunks after the first are already pure page work); enc-dec
@@ -756,7 +724,8 @@ class DecoderLM:
         already resident in the pool — radix prefix-cache hits and earlier
         chunks of the same prompt alike.  Padding rows/positions write to
         the null page.  Returns (last-real-token logits [B, V], new_kv,
-        new_state)."""
+        new_state), and with ``moe_stats`` the MoE layers' ``stats``
+        (``_paged_layers``; padding routes no expert pair)."""
         cfg = self.cfg
         slots, n_tail = meta["slots"], meta["n_tail"]
         if cfg.family in ("ssm", "hybrid"):
@@ -772,59 +741,15 @@ class DecoderLM:
             x = jnp.concatenate([img, x], axis=1)
             n_live = n_tail + cfg.n_image_tokens
         freqs = self._freqs()
-        B = x.shape[0]
-
-        def dense_step(x, p, c):
-            h = apply_norm(cfg, p["ln1"], x)
-            a, c2 = self._paged_attn_prefill(p, h, c, meta, freqs)
-            x = x + a
-            x = x + apply_mlp(cfg, p["mlp"], apply_norm(cfg, p["ln2"], x))
-            return x, c2
-
-        def moe_step(x, p, c):
-            h = apply_norm(cfg, p["ln1"], x)
-            a, c2 = self._paged_attn_prefill(p, h, c, meta, freqs)
-            x = x + a
-            m, _ = moe_apply(cfg, p["moe"], apply_norm(cfg, p["ln2"], x),
-                             mesh=mesh)
-            return x + m, c2
-
-        if cfg.is_moe:
-            k = cfg.first_k_dense
-            if k:
-                head = jax.tree.map(lambda a: a[:k], kv)
-                tail = jax.tree.map(lambda a: a[k:], kv)
-
-                def dbody(x, pc):
-                    p, c = pc
-                    return dense_step(x, p, c)
-                x, nhead = _scan_blocks(dbody, x, params["dense_blocks"], head,
-                                        unroll=cfg.unroll)
-
-                def mbody(x, pc):
-                    p, c = pc
-                    return moe_step(x, p, c)
-                x, ntail = _scan_blocks(mbody, x, params["blocks"], tail,
-                                        unroll=cfg.unroll)
-                new_kv = jax.tree.map(
-                    lambda a, b: jnp.concatenate([a, b]), nhead, ntail)
-            else:
-                def mbody(x, pc):
-                    p, c = pc
-                    return moe_step(x, p, c)
-                x, new_kv = _scan_blocks(mbody, x, params["blocks"], kv,
-                                         unroll=cfg.unroll)
-        else:
-            def dbody(x, pc):
-                p, c = pc
-                return dense_step(x, p, c)
-            x, new_kv = _scan_blocks(dbody, x, params["blocks"], kv,
-                                     unroll=cfg.unroll)
-
+        B, T = x.shape[0], x.shape[1]
+        x, new_kv, stats = self._paged_layers(
+            params, kv, x,
+            lambda p, h, c: self._paged_attn_prefill(p, h, c, meta, freqs),
+            live=jnp.arange(T)[None, :] < n_live[:, None])
         x = apply_norm(cfg, params["final_norm"], x)
         last = x[jnp.arange(B), n_live - 1]
         logits = lm_logits(cfg, params["embed"], last)
-        return logits, new_kv, state
+        return (logits, new_kv, state) + ((stats,) if moe_stats else ())
 
     # ----------------------------------------------- state-slot prefill path
 

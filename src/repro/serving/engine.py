@@ -91,7 +91,7 @@ from ..models.attn_backend import (
     decode_meta, prefill_meta, resolve_backend, verify_meta)
 from ..models.params import init_tree
 from ..models.registry import build_model, init_cache, init_params
-from ..models.steps import make_serve_step
+from ..models.steps import make_serve_step, unpack_counts
 from .admission import AdmissionController, HealthState
 from .faults import FaultInjector, FaultPlan, RequestFault
 from .kv_pool import NULL_PAGE, PagedKVPool, StateSlotPool
@@ -142,6 +142,7 @@ class _Pending:
     t0: float                         # dispatch start (step span start)
     waiting: bool                     # decode-ready slots parked behind this
     staged: bool = False              # decode launched from a staged plan
+    moe: Optional[Dict[str, int]] = None   # the step's expert pairs (MoE)
 
 
 @dataclasses.dataclass
@@ -308,6 +309,21 @@ class Engine:
             "built, by kind (walked | table)", labels=("kind",))
         self._m_pages_walked = pages.labels(kind="walked")
         self._m_pages_table = pages.labels(kind="table")
+        # expert-share accounting (MoE): the (token, expert) pairs routed,
+        # those whose expert is held here, and the held experts they touch,
+        # summed over the MoE layers, read from each step's outputs
+        self._moe_layers = cfg.n_layers - cfg.first_k_dense \
+            if cfg.is_moe else 0
+        pairs = self.metrics.counter(
+            "moe.pairs", "(token, expert) pairs of the MoE layers, by kind "
+            "(held: computed here | routed: tokens x top_k)",
+            labels=("kind",))
+        self._m_pairs_held = pairs.labels(kind="held")
+        self._m_pairs_routed = pairs.labels(kind="routed")
+        self._m_experts_touched = self.metrics.counter(
+            "moe.experts_touched", "held experts with at least one pair, "
+            "summed over MoE layers and steps, by step kind "
+            "(decode | prefill)", labels=("kind",))
         # speculative-decoding accounting: drafts proposed vs accepted
         self._m_spec_proposed = self.metrics.counter(
             "engine.spec_proposed", "draft tokens proposed by the n-gram "
@@ -690,6 +706,8 @@ class Engine:
             n_rows = 1 if pending.kind == "restore" else len(pending.payload)
             extra = {"staged": pending.staged} \
                 if pending.kind == "decode" else {}
+            if pending.moe is not None:
+                extra.update(pending.moe)
             self.tracer.step_span(pending.kind, pending.t0, t1, rows=n_rows,
                                   decode_waiting=pending.waiting, **extra)
         if pending.kind in ("decode", "verify"):
@@ -993,6 +1011,10 @@ class Engine:
         with self.tracer.phase("sync." + pending.kind):
             logits = np.asarray(pending.out_dev)  # blocks: device step done
         now = time.perf_counter()
+        if self._moe_layers:
+            self._count_moe(pending, "prefill",
+                            unpack_counts(logits[-1], 2 * self._moe_layers),
+                            sum(n for *_, n in pending.rows))
         with self.tracer.phase("emit"):
             for r, (slot_idx, req, n_done, n_chunk) in enumerate(
                     pending.rows):
@@ -1101,6 +1123,9 @@ class Engine:
             nxt = np.asarray(nxt_dev)            # blocks: device step done
             ok = np.asarray(ok_dev)
         now = time.perf_counter()
+        if self._moe_layers:
+            B = self.scfg.max_slots
+            self._count_moe(pending, "decode", nxt[B:], len(pending.rows))
         self._h_decode_step.observe(now - t_launch)
         if self.admission is not None:
             self.admission.observe_step(now - t_launch)
@@ -1118,6 +1143,18 @@ class Engine:
                 self._emit_token(slot.req.rid, len(slot.req.generated) - 1,
                                  tok, now)
                 self._maybe_retire(i, now)
+
+    def _count_moe(self, pending: _Pending, kind: str, stats, tokens: int):
+        """Count a step's expert pairs from the per-layer (held pairs, held
+        experts touched) the step returned, and keep them for its span."""
+        stats = np.asarray(stats).reshape(self._moe_layers, 2)
+        held, touched = int(stats[:, 0].sum()), int(stats[:, 1].sum())
+        routed = tokens * self.cfg.top_k * self._moe_layers
+        self._m_pairs_held.inc(held)
+        self._m_pairs_routed.inc(routed)
+        self._m_experts_touched.labels(kind=kind).inc(touched)
+        pending.moe = {"moe_held": held, "moe_routed": routed,
+                       "moe_touched": touched}
 
     # ------------------------------------------------------------- speculate
 

@@ -55,12 +55,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ArchConfig, ServeConfig
-from ..models.cache_spec import CacheFamilySpec, window_pages
+from ..models.cache_spec import (NULL_PAGE, CacheFamilySpec,  # noqa: F401
+                                 window_pages)
 from ..models.params import init_tree
 from ..models.registry import build_model
 from .telemetry import MetricsRegistry
-
-NULL_PAGE = 0
 
 
 class PagedKVPool:

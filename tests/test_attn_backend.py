@@ -143,27 +143,47 @@ def test_decode_attend_softcap():
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("B,H,L,R,ps,maxp", [
+MLA_CASES = [
+    # (B, H, L, R, ps, maxp)
     (3, 4, 16, 8, 8, 5),
     (2, 8, 32, 16, 4, 6),
     (1, 2, 8, 4, 16, 2),
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    # the latent walk takes 128 tokens a step: tables of several blocks,
+    # not a multiple of the block, rows ending mid-block; with more than
+    # three rows the last is idle (null table, pos 0)
+    (5, 4, 16, 8, 16, 20),           # 2.5 blocks of 8 pages
+    (4, 8, 32, 16, 8, 37),           # 2.3 blocks of 16 pages
+]
+
+
+@pytest.mark.parametrize("B,H,L,R,ps,maxp", MLA_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, "int8"])
 def test_mla_decode_attend_matches_reference(B, H, L, R, ps, maxp, dtype):
     rng = np.random.RandomState(2)
-    q_eff = jnp.asarray(rng.randn(B, H, L), dtype)
-    q_rope = jnp.asarray(rng.randn(B, H, R), dtype)
-    P = 4 * maxp
-    cc = jnp.asarray(rng.randn(P, ps, L), dtype)
-    cr = jnp.asarray(rng.randn(P, ps, R), dtype)
+    P = max(4, B + 1) * maxp
+    scales = {}
+    if dtype == "int8":
+        q_eff = jnp.asarray(rng.randn(B, H, L), jnp.float32)
+        q_rope = jnp.asarray(rng.randn(B, H, R), jnp.float32)
+        cc, cs = quantize_int8(jnp.asarray(rng.randn(P, ps, L), jnp.float32))
+        cr, rs = quantize_int8(jnp.asarray(rng.randn(P, ps, R), jnp.float32))
+        scales = dict(ckv_scale=cs, krope_scale=rs)
+    else:
+        q_eff = jnp.asarray(rng.randn(B, H, L), dtype)
+        q_rope = jnp.asarray(rng.randn(B, H, R), dtype)
+        cc = jnp.asarray(rng.randn(P, ps, L), dtype)
+        cr = jnp.asarray(rng.randn(P, ps, R), dtype)
     tables = _tables(rng, B, maxp, P)
-    pos = jnp.asarray(np.concatenate([[0], rng.randint(
-        1, maxp * ps, size=B - 1)]) if B > 1 else [0], jnp.int32)
+    pos = np.concatenate([[0], rng.randint(1, maxp * ps, size=B - 1)]) \
+        if B > 1 else np.zeros(1, np.int64)
+    if B > 3:
+        tables, pos[-1] = tables.at[-1].set(0), 0
+    pos = jnp.asarray(pos, jnp.int32)
     scale = 1.0 / math.sqrt(L + R)
     ref = get_backend("reference").mla_decode_attend(
-        q_eff, q_rope, cc, cr, tables, pos, scale=scale)
+        q_eff, q_rope, cc, cr, tables, pos, scale=scale, **scales)
     out = get_backend("pallas").mla_decode_attend(
-        q_eff, q_rope, cc, cr, tables, pos, scale=scale)
+        q_eff, q_rope, cc, cr, tables, pos, scale=scale, **scales)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol, rtol=tol)

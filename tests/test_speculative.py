@@ -144,10 +144,27 @@ def test_int8_verify_attend_matches_reference(Q):
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("Q", [1, 2, 4])
-def test_mla_verify_attend_matches_reference(Q):
-    B, H, L, R, ps, maxp = 3, 4, 16, 8, 8, 5
-    P = 4 * maxp
+# (B, H, L, R, ps, maxp): one block of pages, and tables of 2.4 blocks
+# (the latent walk takes 128 tokens a step) whose last row is idle
+MLA_WALK = [(3, 4, 16, 8, 8, 5), (5, 4, 16, 8, 16, 19)]
+
+
+def _idle_last(tables, pos, n_q):
+    """With more than three rows, the last is idle: null table, pos 0, one
+    query."""
+    if tables.shape[0] > 3:
+        tables = tables.at[-1].set(0)
+        pos, n_q = pos.at[-1].set(0), n_q.at[-1].set(1)
+    return tables, pos, n_q
+
+
+# the one-block cases keep their ids ([1], [2], [4])
+@pytest.mark.parametrize("Q,shape", [
+    pytest.param(Q, shape, id=f"{Q}" + ("-walk" if i else ""))
+    for i, shape in enumerate(MLA_WALK) for Q in (1, 2, 4)])
+def test_mla_verify_attend_matches_reference(Q, shape):
+    B, H, L, R, ps, maxp = shape
+    P = max(4, B + 1) * maxp
     rng = np.random.RandomState(20 + Q)
     q_eff = jnp.asarray(rng.randn(B, Q, H, L), jnp.float32)
     q_rope = jnp.asarray(rng.randn(B, Q, H, R), jnp.float32)
@@ -158,6 +175,7 @@ def test_mla_verify_attend_matches_reference(Q):
         [[0], rng.randint(1, maxp * ps - Q, size=B - 1)]), jnp.int32)
     n_q = jnp.asarray(np.concatenate(
         [[1], rng.randint(1, Q + 1, size=B - 1)]), jnp.int32)
+    tables, pos, n_q = _idle_last(tables, pos, n_q)
     scale = 1.0 / math.sqrt(L + R)
     ref = get_backend("reference").mla_verify_attend(
         q_eff, q_rope, cc, cr, tables, pos, n_q, scale=scale)
@@ -242,8 +260,16 @@ def test_verify_qlen1_reproduces_decode_bitexact(window, softcap, int8, maxp):
 
 
 def test_mla_verify_qlen1_reproduces_decode_bitexact():
-    B, H, L, R, ps, maxp = 3, 4, 16, 8, 8, 5
-    P = 4 * maxp
+    _mla_verify_qlen1_is_decode(MLA_WALK[0])
+
+
+def test_mla_verify_qlen1_reproduces_decode_bitexact_multiblock():
+    _mla_verify_qlen1_is_decode(MLA_WALK[1])
+
+
+def _mla_verify_qlen1_is_decode(shape):
+    B, H, L, R, ps, maxp = shape
+    P = max(4, B + 1) * maxp
     rng = np.random.RandomState(50)
     q_eff = jnp.asarray(rng.randn(B, H, L), jnp.float32)
     q_rope = jnp.asarray(rng.randn(B, H, R), jnp.float32)
@@ -253,6 +279,7 @@ def test_mla_verify_qlen1_reproduces_decode_bitexact():
     pos = jnp.asarray(np.concatenate(
         [[0], rng.randint(1, maxp * ps, size=B - 1)]), jnp.int32)
     ones = jnp.ones((B,), jnp.int32)
+    tables, pos, ones = _idle_last(tables, pos, ones)
     scale = 1.0 / math.sqrt(L + R)
     pal = get_backend("pallas")
     np.testing.assert_array_equal(

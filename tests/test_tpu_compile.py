@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.grouped_matmul import expert_matmul
 from repro.kernels.paged_attention import (mla_paged_attention_decode,
                                            mla_paged_attention_verify,
                                            paged_attention_decode,
@@ -149,6 +150,51 @@ def test_verify_mla_deepseek_v2(one_chip):
              ((B, 5, H, R), BF16), ((P, PS, L), BF16), ((P, PS, R), BF16),
              ((B, n), I32), ((B,), I32), ((B,), I32),
              scale=1 / math.sqrt(192))
+
+
+# the deepseek-v2 cell (bench/configs/deepseek-v2-236b-serve.json): 28 slots
+# of 16,384 tokens (1,024-page tables) over a pool of 28 x 1,024 + 1 pages,
+# a 512-token prefill chunk against the whole table, and the held experts'
+# grouped matmuls (20 experts, D=5120, F=1536) at a decode step's pairs
+# (28 rows x top-6, padded to the row tile) and a prefill chunk's
+DSV2_CELL = dict(B=28, n_pages=1024, P=28 * 1024 + 1)
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_decode_mla_deepseek_v2_cell(one_chip, kv):
+    H, L, R = DSV2["H"], DSV2["L"], DSV2["R"]
+    rows, n, P = DSV2_CELL["B"], DSV2_CELL["n_pages"], DSV2_CELL["P"]
+    dt = I8 if kv == "int8" else BF16
+    scales = ({"ckv_scale": ((P, PS), BF16), "krope_scale": ((P, PS), BF16)}
+              if kv == "int8" else {})
+    _compile(one_chip, mla_paged_attention_decode, ((rows, H, L), BF16),
+             ((rows, H, R), BF16), ((P, PS, L), dt), ((P, PS, R), dt),
+             ((rows, n), I32), ((rows,), I32), scale=0.1147, **scales)
+
+
+def test_verify_mla_deepseek_v2_cell(one_chip):
+    H, L, R = DSV2["H"], DSV2["L"], DSV2["R"]
+    rows, n, P = DSV2_CELL["B"], DSV2_CELL["n_pages"], DSV2_CELL["P"]
+    _compile(one_chip, mla_paged_attention_verify, ((rows, 2, H, L), BF16),
+             ((rows, 2, H, R), BF16), ((P, PS, L), BF16), ((P, PS, R), BF16),
+             ((rows, n), I32), ((rows,), I32), ((rows,), I32), scale=0.1147)
+
+
+def test_prefill_mla_deepseek_v2_cell(one_chip):
+    H, L, R = DSV2["H"], DSV2["L"], DSV2["R"]
+    n, P = DSV2_CELL["n_pages"], DSV2_CELL["P"]
+    _compile(one_chip, mla_ragged_prefill_attend,
+             ((1, 512, H, DSV2["nope"] + R), BF16), ((P, PS, L), BF16),
+             ((P, PS, R), BF16), ((L, H, DSV2["nope"] + DSV2["vd"]), BF16),
+             ((1, n), I32), ((1,), I32), ((1,), I32), nope=DSV2["nope"],
+             scale=0.1147)
+
+
+@pytest.mark.parametrize("rows", [192, 3072])
+@pytest.mark.parametrize("k,n,out", [(5120, 1536, BF16), (1536, 5120, F32)])
+def test_grouped_matmul_deepseek_v2_experts(one_chip, rows, k, n, out):
+    _compile(one_chip, expert_matmul, ((rows, k), BF16), ((20, k, n), BF16),
+             ((20,), I32), out_dtype=out)
 
 
 def test_prefill_qwen2(one_chip):
