@@ -4,9 +4,17 @@ One decode step reads every live token of a request's KV straight out of the
 paged pool: the per-request page table rides in as a *scalar-prefetch*
 operand, so the K/V BlockSpec index maps resolve physical page ids before
 the body runs and the pipeline DMAs exactly the pages the request owns — the
-``pool[tables]`` gather that the XLA reference path materializes in HBM
-never exists here.  This is the TPU-native shape of vLLM/SGLang
+``pool[layer, tables]`` gather that the XLA reference path materializes in
+HBM never exists here.  This is the TPU-native shape of vLLM/SGLang
 PagedAttention: walk the page table, attend in place.
+
+The pool is the whole layer-stacked, lane-dense ``[L, P, ps, W]`` array the
+model's layer loop carries (kernels/README.md, "Pool layout"): the layer
+index is the first scalar-prefetch operand, and every page block is
+``(1, 1, ps, W)`` at ``(layer, page, 0, 0)``.  A GQA slot holds its KV
+heads side by side in lanes (head ``h`` at ``[h * D, (h + 1) * D)``), a
+latent slot ``ckv`` then ``krope``; the bodies take them as static lane
+slices.
 
 Two kernel bodies cover every paged decode family in ``models.cache_spec``:
 
@@ -45,11 +53,7 @@ row's extent keeps the page it held a step before, and costs no DMA.  Each
 KV head's scores are one ``[rows, ppb * ps]`` dot over the block's pages in
 table order, folded into the online softmax once per block, slots past the
 extent masked ``-inf``.  An idle row (null table, ``pos`` 0) costs one
-step, whose one live page is the null page it attends.  Pages are fetched
-by BlockSpecs, not by ``make_async_copy`` from an HBM ref: Mosaic pads an
-HBM ref's two minor dims to its tiling and refuses a slice of a pool whose
-head dim is under 128 lanes (qwen2's 64), or of the ``[P, ps, K]`` int8
-scales.
+step, whose one live page is the null page it attends.
 
 Each decode body has a small-q *verify* twin (``_walk_kernel`` /
 ``_mla_walk_kernel`` with ``verify``) for speculative decoding: the q
@@ -124,19 +128,21 @@ def _slot_mask(s, first, pos, *, window, ring):
     return (k_abs >= 0) & (k_abs <= pos) & (k_abs > pos - window)
 
 
-def page_head(ref, kh, scale_ref=None):
-    """KV head ``kh`` (static) of a whole-page block ``ref`` [1, ps, K, D] as
-    fp32 [ps, D], dequantized in-register when ``scale_ref`` ([1, ps, K]
-    per-token-per-head scales) is given: f32(q8) * f32(bf16 scale) — the
-    page DMA moved int8, half the bf16 bytes.
-
-    The block spans every KV head of the page because the (8, 128) tiling
-    rule forbids a one-head block of the [P, ps, K, D] pool; a static head
-    index is a strided sublane load Mosaic accepts for bf16 and int8 alike."""
-    x = ref[0, :, kh, :].astype(jnp.float32)
+def page_head(ref, kh, D, scale_ref=None):
+    """KV head ``kh`` (static) of a lane-dense page block ``ref``
+    [1, 1, ps, W] as fp32 [ps, D] — its static lane slice [kh * D,
+    (kh + 1) * D) — dequantized in-register when ``scale_ref`` ([1, 1, ps,
+    K] per-token-per-head scales) is given: f32(q8) * f32(bf16 scale), the
+    page DMA having moved int8, half the bf16 bytes."""
+    x = ref[0, 0, :, kh * D:(kh + 1) * D].astype(jnp.float32)
     if scale_ref is not None:
-        x = x * scale_ref[0].astype(jnp.float32)[:, kh:kh + 1]
+        x = x * scale_ref[0, 0].astype(jnp.float32)[:, kh:kh + 1]
     return x
+
+
+def layer_operand(layer):
+    """The layer index as the kernels' [1] int32 scalar-prefetch operand."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 def _fold_page(s, v, first, pos, m_ref, l_ref, acc_ref, *, window=0,
@@ -214,13 +220,14 @@ def walk_schedule(tables, last, page_size, ppb):
     return sched, pages.reshape(-1), ends[-1]
 
 
-def _walk_kernel(sched_ref, pages_ref, pos_ref, *refs, page_size: int,
-                 n_pages: int, ppb: int, scale: float, softcap: float,
-                 window: int, quantized: bool, G: int, verify: bool):
-    """Body shared by GQA decode and verify (``verify``: a fourth scalar
+def _walk_kernel(layer_ref, sched_ref, pages_ref, pos_ref, *refs,
+                 page_size: int, n_pages: int, ppb: int, scale: float,
+                 softcap: float, window: int, quantized: bool, G: int,
+                 verify: bool):
+    """Body shared by GQA decode and verify (``verify``: a fifth scalar
     operand ``n_q``): one step per live block of ``ppb`` pages (module
     docstring, "The decode walk")."""
-    del pages_ref          # read by the index maps only
+    del layer_ref, pages_ref          # read by the index maps only
     nq_ref, refs = (refs[0], refs[1:]) if verify else (None, refs)
     q_ref, refs = refs[0], refs[1:]
     n_ops = 4 if quantized else 2
@@ -247,11 +254,12 @@ def _walk_kernel(sched_ref, pages_ref, pos_ref, *refs, page_size: int,
         qi = jax.lax.broadcasted_iota(jnp.int32, (q_ref.shape[2], 1), 0) // G
         pos, row_ok = pos + qi, qi < nq_ref[r]
     first = blk * ppb * ps
+    D = q_ref.shape[3]
     for kh in range(q_ref.shape[1]):       # each KV head of the block
         # the block's pages, in table order: slot t is token first + t
-        k = jnp.concatenate([page_head(k_refs[j], kh, ks_refs[j])
+        k = jnp.concatenate([page_head(k_refs[j], kh, D, ks_refs[j])
                              for j in range(ppb)], axis=0)        # [T, D]
-        v = jnp.concatenate([page_head(v_refs[j], kh, vs_refs[j])
+        v = jnp.concatenate([page_head(v_refs[j], kh, D, vs_refs[j])
                              for j in range(ppb)], axis=0)
         # scale after the dot, the reference ordering, so the two backends'
         # fp32 scores round identically
@@ -274,36 +282,31 @@ def _walk_kernel(sched_ref, pages_ref, pos_ref, *refs, page_size: int,
                 o_ref.dtype)
 
 
-def _walk_call(q, k_pages, v_pages, tables, pos, n_q, k_scale, v_scale, *,
-               G, interpret, **kw):
+def _walk_call(q, k_pages, v_pages, layer, tables, pos, n_q, k_scale,
+               v_scale, *, G, interpret, **kw):
     """``pallas_call`` of the decode walk: q [B, K, rows, D] (rows = G for
-    decode, Q*G for verify with ``n_q``); each pool (and int8 scale) is
-    passed once per page slot of a block, its index map reading the slot's
-    page from the schedule."""
+    decode, Q*G for verify with ``n_q``); each layer-stacked pool (and int8
+    scale) is passed once per page slot of a block, its index map reading
+    the layer operand and the slot's page from the schedule."""
     B, K, rows, D = q.shape
-    ps, n_pages = k_pages.shape[1], tables.shape[1]
+    ps, n_pages = k_pages.shape[2], tables.shape[1]
     quantized = k_scale is not None
-    blocks = [((ps, K, D), k_pages.dtype)] * 2
+    blocks = [(k_pages.shape[2:], k_pages.dtype)] * 2
     if quantized:
-        blocks += [((ps, K), k_scale.dtype)] * 2
+        blocks += [(k_scale.shape[2:], k_scale.dtype)] * 2
     ppb = pages_per_block(ps, n_pages, blocks)
     n_blk = -(-n_pages // ppb)
     sched, pages, n_steps = walk_schedule(
         tables, pos if n_q is None else pos + n_q - 1, ps, ppb)
-    prefetch = [sched, pages, pos] + ([] if n_q is None else [n_q])
+    prefetch = [layer_operand(layer), sched, pages, pos] + (
+        [] if n_q is None else [n_q])
     pools = [k_pages, v_pages] + ([k_scale, v_scale] if quantized else [])
-
-    def page_spec(pool, j):
-        block = (1,) + pool.shape[1:]
-        return pl.BlockSpec(block, lambda s, sched_ref, pages_ref, *_: (
-            pages_ref[sched_ref[s] * ppb + j],) + (0,) * (len(block) - 1))
-
-    q_spec = pl.BlockSpec((1, K, rows, D), lambda s, sched_ref, *_: (
+    q_spec = pl.BlockSpec((1, K, rows, D), lambda s, _, sched_ref, *__: (
         sched_ref[s] // n_blk, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(n_steps,),
-        in_specs=[q_spec] + [page_spec(p, j) for p in pools
+        in_specs=[q_spec] + [walk_page_spec(p, j, ppb) for p in pools
                              for j in range(ppb)],
         out_specs=q_spec,
         scratch_shapes=_online_scratch((K, rows), D),
@@ -321,6 +324,17 @@ def _walk_call(q, k_pages, v_pages, tables, pos, n_q, k_scale, v_scale, *,
     )(*prefetch, q, *[p for p in pools for _ in range(ppb)])
 
 
+def walk_page_spec(pool, j, ppb):
+    """Page slot ``j`` of a walk step: one whole page [1, 1, ps, W] of the
+    layer-stacked ``pool``, at the layer operand and the slot's scheduled
+    page.  Its last two dims are the pool's, as the (8, 128) tiling rule
+    requires; lane-dense pages make them one contiguous row-major tile."""
+    return pl.BlockSpec((1, 1) + pool.shape[2:],
+                        lambda s, layer_ref, sched_ref, pages_ref, *_: (
+                            layer_ref[0], pages_ref[sched_ref[s] * ppb + j],
+                            0, 0))
+
+
 def _online_scratch(rows, D):
     """fp32 (m, l, acc) online-softmax state for query rows ``rows`` (a
     shape tuple, e.g. (K, G) or (H,)) of width ``D``."""
@@ -329,70 +343,73 @@ def _online_scratch(rows, D):
             pltpu.VMEM(rows + (D,), jnp.float32)]
 
 
-def paged_decode_fwd(q, k_pages, v_pages, tables, pos, *, scale: float,
-                     softcap: float = 0.0, window: int = 0,
+def paged_decode_fwd(q, k_pages, v_pages, layer, tables, pos, *,
+                     scale: float, softcap: float = 0.0, window: int = 0,
                      k_scale=None, v_scale=None, interpret: bool = False):
-    """q: [B, K, G, D]; k_pages/v_pages: [P, ps, K, D]; tables: [B, n_pages]
-    int32 physical page ids; pos: [B] int32 absolute positions.  Returns
-    [B, K, G, D].  ``window > 0`` treats the table as a page ring of
-    ``n_pages * ps`` token slots.  ``k_scale``/``v_scale``: [P, ps, K] bf16
-    per-token-per-head absmax scales when the pool is int8-quantized — the
-    kernel dequantizes in-register after the page DMA.  One grid step per
-    live block of ``pages_per_block`` pages; each page is fetched once and
-    every KV head attends it."""
-    return _walk_call(q, k_pages, v_pages, tables, pos, None, k_scale,
+    """q: [B, K, G, D]; k_pages/v_pages: [L, P, ps, W] layer-stacked
+    lane-dense pools (KV head h at lanes [h * D, (h + 1) * D)); layer: the
+    int32 layer index; tables: [B, n_pages] int32 physical page ids; pos:
+    [B] int32 absolute positions.  Returns [B, K, G, D].  ``window > 0``
+    treats the table as a page ring of ``n_pages * ps`` token slots.
+    ``k_scale``/``v_scale``: [L, P, ps, K] bf16 per-token-per-head absmax
+    scales when the pool is int8-quantized — the kernel dequantizes
+    in-register after the page DMA.  One grid step per live block of
+    ``pages_per_block`` pages; each page is fetched once and every KV head
+    attends it."""
+    return _walk_call(q, k_pages, v_pages, layer, tables, pos, None, k_scale,
                       v_scale, G=q.shape[2], scale=scale, softcap=softcap,
                       window=window, interpret=interpret)
 
 
-def paged_verify_fwd(q, k_pages, v_pages, tables, pos, n_q, *, scale: float,
-                     softcap: float = 0.0, window: int = 0,
+def paged_verify_fwd(q, k_pages, v_pages, layer, tables, pos, n_q, *,
+                     scale: float, softcap: float = 0.0, window: int = 0,
                      k_scale=None, v_scale=None, interpret: bool = False):
     """Small-q speculative verify: q [B, K, Q, G, D] — per row the last
     emitted token plus its draft, padded to Q; pos [B] base positions; n_q
-    [B] live query counts (1 + draft length).  Same page-table / ring /
-    int8-scale contract and the same walk as ``paged_decode_fwd``, the
-    extent reaching the last live query's page; each block is fetched once
-    per row with all Q queries' masks evaluated against it.  Returns
+    [B] live query counts (1 + draft length).  Same pool / page-table /
+    ring / int8-scale contract and the same walk as ``paged_decode_fwd``,
+    the extent reaching the last live query's page; each block is fetched
+    once per row with all Q queries' masks evaluated against it.  Returns
     [B, K, Q, G, D]; dead query rows (j >= n_q) are exact zeros."""
     B, K, Q, G, D = q.shape
     # the (Q, G) query rows flatten outside the kernel: an in-kernel
     # [Q, G, D] -> [Q*G, D] reshape is a shape cast Mosaic refuses for G % 8
-    o = _walk_call(q.reshape(B, K, Q * G, D), k_pages, v_pages, tables, pos,
-                   n_q, k_scale, v_scale, G=G, scale=scale, softcap=softcap,
-                   window=window, interpret=interpret)
+    o = _walk_call(q.reshape(B, K, Q * G, D), k_pages, v_pages, layer,
+                   tables, pos, n_q, k_scale, v_scale, G=G, scale=scale,
+                   softcap=softcap, window=window, interpret=interpret)
     return o.reshape(B, K, Q, G, D)
 
 
-def _mla_page(ckv_ref, krope_ref, cs_ref, rs_ref):
-    """One latent page as fp32 ([ps, L], [ps, R]).  int8 pages carry one
-    scale per latent token slot ([1, ps, 1] blocks; the latent vector is the
-    quantization granule), so the dequantized ckv that feeds the latent
-    accumulator picks up the scales too."""
-    ckv = ckv_ref[0].astype(jnp.float32)
-    kr = krope_ref[0].astype(jnp.float32)
+def _mla_page(ref, L, R, cs_ref, rs_ref):
+    """One lane-dense latent page [1, 1, ps, W] as fp32 (ckv [ps, L] from
+    lanes [0, L), krope [ps, R] from lanes [L, L + R)).  int8 pages carry
+    one scale per latent token slot and payload ([1, 1, ps, 1] blocks; the
+    latent vector is the quantization granule), so the dequantized ckv that
+    feeds the latent accumulator picks up the scales too."""
+    ckv = ref[0, 0, :, :L].astype(jnp.float32)
+    kr = ref[0, 0, :, L:L + R].astype(jnp.float32)
     if cs_ref is not None:
-        ckv = ckv * cs_ref[0].astype(jnp.float32)
-        kr = kr * rs_ref[0].astype(jnp.float32)
+        ckv = ckv * cs_ref[0, 0].astype(jnp.float32)
+        kr = kr * rs_ref[0, 0].astype(jnp.float32)
     return ckv, kr
 
 
-def _mla_walk_kernel(sched_ref, pages_ref, pos_ref, *refs, page_size: int,
-                     n_pages: int, ppb: int, scale: float, quantized: bool,
-                     H: int, verify: bool):
-    """Absorbed-latent decode and verify (``verify``: a fourth scalar
+def _mla_walk_kernel(layer_ref, sched_ref, pages_ref, pos_ref, *refs,
+                     page_size: int, n_pages: int, ppb: int, scale: float,
+                     quantized: bool, H: int, verify: bool):
+    """Absorbed-latent decode and verify (``verify``: a fifth scalar
     operand ``n_q``) on the decode walk: one step per live block of ``ppb``
     latent pages.  Scores ``q_eff·ckv + q_rope·krope`` of every query row
     against the block's tokens in table order, scaled after the dots, folded
     into the online softmax with the latent ``ckv`` as values."""
-    del pages_ref          # read by the index maps only
+    del layer_ref, pages_ref          # read by the index maps only
     nq_ref, refs = (refs[0], refs[1:]) if verify else (None, refs)
     qe_ref, qr_ref, refs = refs[0], refs[1], refs[2:]
-    n_ops = 4 if quantized else 2
+    n_ops = 3 if quantized else 1
     pages = [refs[a * ppb:(a + 1) * ppb] for a in range(n_ops)]
     o_ref, m_scr, l_scr, acc_scr = refs[n_ops * ppb:]
-    ckv_refs, kr_refs = pages[:2]
-    cs_refs, rs_refs = pages[2:] if quantized else ([None] * ppb,) * 2
+    lat_refs = pages[0]
+    cs_refs, rs_refs = pages[1:] if quantized else ([None] * ppb,) * 2
     ps, n_blk = page_size, -(-n_pages // ppb)
     s = pl.program_id(0)
     r, blk = sched_ref[s] // n_blk, sched_ref[s] % n_blk
@@ -409,7 +426,8 @@ def _mla_walk_kernel(sched_ref, pages_ref, pos_ref, *refs, page_size: int,
         # row j*H + h is query j of head h, at absolute position pos + j
         qi = jax.lax.broadcasted_iota(jnp.int32, (qe_ref.shape[1], 1), 0) // H
         pos, row_ok = pos + qi, qi < nq_ref[r]
-    block = [_mla_page(ckv_refs[j], kr_refs[j], cs_refs[j], rs_refs[j])
+    L, R = qe_ref.shape[2], qr_ref.shape[2]
+    block = [_mla_page(lat_refs[j], L, R, cs_refs[j], rs_refs[j])
              for j in range(ppb)]
     ckv = jnp.concatenate([c for c, _ in block], axis=0)          # [T, L]
     kr = jnp.concatenate([k for _, k in block], axis=0)           # [T, R]
@@ -431,61 +449,57 @@ def _mla_walk_kernel(sched_ref, pages_ref, pos_ref, *refs, page_size: int,
         o_ref[0] = _normalized(l_scr, acc_scr).astype(o_ref.dtype)
 
 
-def scale_view(s):
-    """[P, ps] latent scales as [P, ps, 1]: a page's block then equals the
-    array's last two dims, which the (8, 128) tiling rule requires."""
-    return s.reshape(s.shape[:2] + (1,))
+def latent_pools(pages, ckv_scale, krope_scale):
+    """The layer-stacked arrays a latent walk fetches a page of: the
+    lane-dense latent pages, and the int8 [L, P, ps] scales viewed as
+    [L, P, ps, 1] (a page's block then equals the array's last two dims,
+    which the (8, 128) tiling rule requires)."""
+    if ckv_scale is None:
+        return [pages]
+    return [pages] + [s.reshape(s.shape + (1,))
+                      for s in (ckv_scale, krope_scale)]
 
 
-def mla_pages_per_block(ckv_pages, krope_pages, n_pages, quantized):
-    """``pages_per_block`` with the latent page shapes."""
-    ps = ckv_pages.shape[1]
-    blocks = [((ps, ckv_pages.shape[2]), ckv_pages.dtype),
-              ((ps, krope_pages.shape[2]), krope_pages.dtype)]
-    if quantized:
-        blocks += [((ps, 1), jnp.bfloat16)] * 2
-    return pages_per_block(ps, n_pages, blocks)
+def mla_pages_per_block(pools, n_pages):
+    """``pages_per_block`` with the latent pools' page shapes."""
+    return pages_per_block(pools[0].shape[2], n_pages,
+                           [(p.shape[2:], p.dtype) for p in pools])
 
 
-def _mla_walk_call(q_eff, q_rope, ckv_pages, krope_pages, tables, pos, n_q,
-                   ckv_scale, krope_scale, *, H, scale, interpret):
+def _mla_walk_call(q_eff, q_rope, pages, layer, tables, pos, n_q, ckv_scale,
+                   krope_scale, *, H, scale, interpret):
     """``pallas_call`` of the latent walk: q_eff [B, rows, L], q_rope
-    [B, rows, R] (rows = H for decode, Q*H for verify with ``n_q``); each
-    latent pool (and int8 scale view) is passed once per page slot of a
-    block, its index map reading the slot's page from the schedule."""
+    [B, rows, R] (rows = H for decode, Q*H for verify with ``n_q``); the
+    layer-stacked latent pages (and int8 scale views) are passed once per
+    page slot of a block, each index map reading the layer operand and the
+    slot's page from the schedule."""
     B, rows, L = q_eff.shape
     R = q_rope.shape[-1]
-    ps, n_pages = ckv_pages.shape[1], tables.shape[1]
-    quantized = ckv_scale is not None
-    ppb = mla_pages_per_block(ckv_pages, krope_pages, n_pages, quantized)
+    ps, n_pages = pages.shape[2], tables.shape[1]
+    pools = latent_pools(pages, ckv_scale, krope_scale)
+    ppb = mla_pages_per_block(pools, n_pages)
     n_blk = -(-n_pages // ppb)
-    sched, pages, n_steps = walk_schedule(
+    sched, sched_pages, n_steps = walk_schedule(
         tables, pos if n_q is None else pos + n_q - 1, ps, ppb)
-    prefetch = [sched, pages, pos] + ([] if n_q is None else [n_q])
-    pools = [ckv_pages, krope_pages]
-    if quantized:
-        pools += [scale_view(ckv_scale), scale_view(krope_scale)]
-
-    def page_spec(pool, j):
-        return pl.BlockSpec((1,) + pool.shape[1:],
-                            lambda s, sched_ref, pages_ref, *_: (
-                                pages_ref[sched_ref[s] * ppb + j], 0, 0))
+    prefetch = [layer_operand(layer), sched, sched_pages, pos] + (
+        [] if n_q is None else [n_q])
 
     def row_spec(width):
-        return pl.BlockSpec((1, rows, width), lambda s, sched_ref, *_: (
+        return pl.BlockSpec((1, rows, width), lambda s, _, sched_ref, *__: (
             sched_ref[s] // n_blk, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(n_steps,),
-        in_specs=[row_spec(L), row_spec(R)] + [page_spec(p, j) for p in pools
-                                               for j in range(ppb)],
+        in_specs=[row_spec(L), row_spec(R)] + [
+            walk_page_spec(p, j, ppb) for p in pools for j in range(ppb)],
         out_specs=row_spec(L),
         scratch_shapes=_online_scratch((rows,), L),
     )
     return pl.pallas_call(
         functools.partial(_mla_walk_kernel, page_size=ps, n_pages=n_pages,
-                          ppb=ppb, scale=scale, quantized=quantized, H=H,
+                          ppb=ppb, scale=scale,
+                          quantized=ckv_scale is not None, H=H,
                           verify=n_q is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_eff.shape, q_eff.dtype),
@@ -496,23 +510,24 @@ def _mla_walk_call(q_eff, q_rope, ckv_pages, krope_pages, tables, pos, n_q,
     )(*prefetch, q_eff, q_rope, *[p for p in pools for _ in range(ppb)])
 
 
-def mla_paged_decode_fwd(q_eff, q_rope, ckv_pages, krope_pages, tables, pos,
-                         *, scale: float, ckv_scale=None, krope_scale=None,
+def mla_paged_decode_fwd(q_eff, q_rope, pages, layer, tables, pos, *,
+                         scale: float, ckv_scale=None, krope_scale=None,
                          interpret: bool = False):
-    """q_eff: [B, H, L] (w_uk-absorbed queries); q_rope: [B, H, R];
-    ckv_pages: [P, ps, L]; krope_pages: [P, ps, R]; tables: [B, n_pages];
-    pos: [B].  Returns the latent context [B, H, L].  ``ckv_scale``/
-    ``krope_scale``: [P, ps] bf16 per-token absmax scales when the latent
-    pages are int8-quantized.  One grid step per live block of
-    ``pages_per_block`` latent pages (the GQA walk's schedule)."""
-    return _mla_walk_call(q_eff, q_rope, ckv_pages, krope_pages, tables, pos,
-                          None, ckv_scale, krope_scale, H=q_eff.shape[1],
+    """q_eff: [B, H, L] (w_uk-absorbed queries); q_rope: [B, H, R]; pages:
+    [Lyr, P, ps, W] layer-stacked lane-dense latent pages (ckv at lanes
+    [0, L), krope at [L, L + R)); layer: the int32 layer index; tables:
+    [B, n_pages]; pos: [B].  Returns the latent context [B, H, L].
+    ``ckv_scale``/``krope_scale``: [Lyr, P, ps] bf16 per-token absmax scales
+    when the latent pages are int8-quantized.  One grid step per live block
+    of ``pages_per_block`` latent pages (the GQA walk's schedule)."""
+    return _mla_walk_call(q_eff, q_rope, pages, layer, tables, pos, None,
+                          ckv_scale, krope_scale, H=q_eff.shape[1],
                           scale=scale, interpret=interpret)
 
 
-def mla_paged_verify_fwd(q_eff, q_rope, ckv_pages, krope_pages, tables, pos,
-                         n_q, *, scale: float, ckv_scale=None,
-                         krope_scale=None, interpret: bool = False):
+def mla_paged_verify_fwd(q_eff, q_rope, pages, layer, tables, pos, n_q, *,
+                         scale: float, ckv_scale=None, krope_scale=None,
+                         interpret: bool = False):
     """Small-q absorbed-latent verify: q_eff [B, Q, H, L] / q_rope
     [B, Q, H, R] against the latent pages, with pos/n_q as in
     ``paged_verify_fwd`` and the same walk as ``mla_paged_decode_fwd``, the
@@ -522,7 +537,7 @@ def mla_paged_verify_fwd(q_eff, q_rope, ckv_pages, krope_pages, tables, pos,
     R = q_rope.shape[-1]
     # query rows flatten outside the kernel (no in-kernel shape cast)
     ctx = _mla_walk_call(q_eff.reshape(B, Q * H, L),
-                         q_rope.reshape(B, Q * H, R), ckv_pages, krope_pages,
-                         tables, pos, n_q, ckv_scale, krope_scale, H=H,
-                         scale=scale, interpret=interpret)
+                         q_rope.reshape(B, Q * H, R), pages, layer, tables,
+                         pos, n_q, ckv_scale, krope_scale, H=H, scale=scale,
+                         interpret=interpret)
     return ctx.reshape(B, Q, H, L)

@@ -5,8 +5,11 @@ its own offset (``start``), with its own live length — against that row's
 paged KV.  The per-row page table rides in as a *scalar-prefetch* operand so
 the K/V BlockSpec index maps resolve ``tables[b, j]`` before the body runs
 and the pipeline DMAs exactly the physical pages the row owns: the
-``pool[tables]`` gather the XLA reference path materializes in HBM never
-exists here, and no row pays for another row's prompt length.
+``pool[layer, tables]`` gather the XLA reference path materializes in HBM
+never exists here, and no row pays for another row's prompt length.  The
+pool is the layer-stacked lane-dense ``[L, P, ps, W]`` array, read at the
+layer index (the first scalar-prefetch operand; kernels/README.md, "Pool
+layout").
 
 Three kernel bodies cover every paged prefill family in
 ``models.cache_spec``:
@@ -48,9 +51,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..paged_attention.kernel import (_init, _mla_page, live_pages,
+from ..paged_attention.kernel import (_init, _mla_page, latent_pools,
+                                      layer_operand, live_pages,
                                       mla_pages_per_block, page_head,
-                                      scale_view, walk_schedule)
+                                      walk_schedule)
 
 # the reference mask constant (models.attention.NEG_INF): finite, so a
 # fully-masked row softmaxes to the same uniform distribution the reference
@@ -129,32 +133,43 @@ def _pv_accumulate(acc_scr, s_scr, seg, v, v_dtype):
         p, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def _kv_head(ref, kh, scale_ref=None):
-    """KV head ``kh`` (a grid index) of a whole-page block [1, ps, K, D] as
-    fp32 [ps, D]: a select over the page's static head slices — Mosaic
-    cannot prove a dynamic sublane index aligned to the packed tiling."""
-    x = page_head(ref, 0, scale_ref)
-    for h in range(1, ref.shape[2]):
-        x = jnp.where(kh == h, page_head(ref, h, scale_ref), x)
+def _kv_head(ref, kh, K, D, scale_ref=None):
+    """KV head ``kh`` (a grid index) of a lane-dense page block
+    [1, 1, ps, W] as fp32 [ps, D]: a select over the page's ``K`` static
+    head lane slices — Mosaic takes no dynamic lane offset that is not a
+    multiple of 128."""
+    x = page_head(ref, 0, D, scale_ref)
+    for h in range(1, K):
+        x = jnp.where(kh == h, page_head(ref, h, D, scale_ref), x)
     return x
 
 
-def _page_spec(ps, K, D, page_of):
-    """Whole-page [1, ps, K, D] block (all KV heads) of page ``page_of``;
-    the last two block dims equal the pool's, as Mosaic's tiling requires."""
-    return pl.BlockSpec((1, ps, K, D), lambda *a: (page_of(*a), 0, 0, 0))
+def _page_spec(shape, page_of):
+    """One whole page (``shape`` = the pool's [ps, W], or its scales'
+    [ps, K]) of the layer operand's layer of a layer-stacked pool, at page
+    ``page_of(b, kh, qb, i, tables, start, n_live)``; the last two block
+    dims equal the pool's, as Mosaic's tiling requires."""
+    return pl.BlockSpec((1, 1) + tuple(shape),
+                        lambda b, kh, qb, i, lr, *refs: (
+                            lr[0], page_of(b, kh, qb, i, *refs), 0, 0))
 
 
-def _scale_spec(ps, K, page_of):
-    return pl.BlockSpec((1, ps, K), lambda *a: (page_of(*a), 0, 0))
+def _lane_pages(x, page_size, width):
+    """Fresh chunk K or V [B, T, K, D] as lane-dense pages
+    [B, T / page_size, page_size, width] (the pool's token-slot layout)."""
+    B, T, K, D = x.shape
+    flat = jnp.pad(x.reshape(B, T, K * D), ((0, 0), (0, 0),
+                                            (0, width - K * D)))
+    return flat.reshape(B, T // page_size, page_size, width)
 
 
 # ------------------------------------------------------------- vanilla GQA
 
-def _ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref, k_ref,
-                           v_ref, *rest, page_size: int, n_pages: int,
-                           q_blk: int, G: int, scale: float, softcap: float,
-                           v_dtype, quantized: bool):
+def _ragged_prefill_kernel(layer_ref, tables_ref, start_ref, n_live_ref,
+                           q_ref, k_ref, v_ref, *rest, page_size: int,
+                           n_pages: int, q_blk: int, G: int, K: int,
+                           scale: float, softcap: float, v_dtype,
+                           quantized: bool):
     if quantized:
         ks_ref, vs_ref, o_ref, s_scr, acc_scr = rest
     else:
@@ -165,7 +180,7 @@ def _ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref, k_ref,
     qb = pl.program_id(2)
     i = pl.program_id(3)
     start = start_ref[b]
-    rows = q_ref.shape[2]                    # q_blk * G (token, group) rows
+    rows, D = q_ref.shape[2], q_ref.shape[3]  # q_blk * G (token, group) rows
     j = jnp.where(i < n_pages, i, i - n_pages)
     # absolute query position of each (token, head-group) row
     q_abs = start + qb * q_blk \
@@ -182,7 +197,7 @@ def _ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref, k_ref,
         @pl.when(live_page)
         def _():
             s = _scores_t(q_ref[0, 0].astype(jnp.float32),      # [rows, D]
-                          _kv_head(k_ref, kh, ks_ref), scale, softcap)
+                          _kv_head(k_ref, kh, K, D, ks_ref), scale, softcap)
             _store_scores(s_scr, j * page_size, s, k_abs <= q_abs)
 
         @pl.when(jnp.logical_not(live_page))
@@ -200,24 +215,25 @@ def _ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref, k_ref,
     @pl.when(i >= n_pages)
     def _():
         _pv_accumulate(acc_scr, s_scr, j * page_size,
-                       _kv_head(v_ref, kh, vs_ref), v_dtype)
+                       _kv_head(v_ref, kh, K, D, vs_ref), v_dtype)
 
     @pl.when(i == 2 * n_pages - 1)
     def _():
         o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
 
 
-def ragged_prefill_fwd(q, k_pages, v_pages, tables, start, n_live, *,
+def ragged_prefill_fwd(q, k_pages, v_pages, layer, tables, start, n_live, *,
                        scale: float, softcap: float = 0.0, q_blk: int = 128,
                        k_scale=None, v_scale=None, interpret: bool = False):
     """q: [B, K, T, G, D] roped chunk queries (T padded to a q_blk multiple);
-    k_pages/v_pages: [P, ps, K, D] *post-write* pool; tables: [B, n_pages]
-    int32; start/n_live: [B] int32.  Returns [B, K, T, G, D].
-    ``k_scale``/``v_scale``: [P, ps, K] bf16 absmax scales when the pool is
-    int8 (the fresh chunk was quantized on write, so every page — prefix and
-    chunk alike — dequantizes through the same scale pool)."""
+    k_pages/v_pages: [L, P, ps, W] layer-stacked lane-dense *post-write*
+    pool, read at layer ``layer``; tables: [B, n_pages] int32;
+    start/n_live: [B] int32.  Returns [B, K, T, G, D].
+    ``k_scale``/``v_scale``: [L, P, ps, K] bf16 absmax scales when the pool
+    is int8 (the fresh chunk was quantized on write, so every page — prefix
+    and chunk alike — dequantizes through the same scale pool)."""
     B, K, T, G, D = q.shape
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     n_pages = tables.shape[1]
     n_qb = T // q_blk
     quantized = k_scale is not None
@@ -226,7 +242,7 @@ def ragged_prefill_fwd(q, k_pages, v_pages, tables, start, n_live, *,
     # runs keep fp32 probabilities exactly like the reference dequant path
     kernel = functools.partial(
         _ragged_prefill_kernel, page_size=ps, n_pages=n_pages, q_blk=q_blk,
-        G=G, scale=scale, softcap=softcap,
+        G=G, K=K, scale=scale, softcap=softcap,
         v_dtype=jnp.float32 if quantized else v_pages.dtype,
         quantized=quantized)
 
@@ -236,18 +252,13 @@ def ragged_prefill_fwd(q, k_pages, v_pages, tables, start, n_live, *,
     # (token, group) rows flatten outside the kernel: an in-kernel
     # [T, G, D] -> [T*G, D] reshape is a shape cast Mosaic refuses
     q_spec = pl.BlockSpec((1, 1, q_blk * G, D),
-                          lambda b, kh, qb, i, tr, st, nl: (b, kh, qb, 0))
-    in_specs = [q_spec, _page_spec(ps, K, D, page_of),
-                _page_spec(ps, K, D, page_of)]
-    operands = [tables, start, n_live, q.reshape(B, K, T * G, D), k_pages,
-                v_pages]
-    if quantized:
-        in_specs += [_scale_spec(ps, K, page_of), _scale_spec(ps, K, page_of)]
-        operands += [k_scale, v_scale]
+                          lambda b, kh, qb, i, *_: (b, kh, qb, 0))
+    pools = [k_pages, v_pages] + ([k_scale, v_scale] if quantized else [])
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, K, n_qb, 2 * n_pages),
-        in_specs=in_specs,
+        in_specs=[q_spec] + [_page_spec(p.shape[2:], page_of)
+                             for p in pools],
         out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((n_pages * ps, q_blk * G), jnp.float32),
@@ -263,18 +274,19 @@ def ragged_prefill_fwd(q, k_pages, v_pages, tables, start, n_live, *,
                                  "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(*operands)
+    )(layer_operand(layer), tables, start, n_live,
+      q.reshape(B, K, T * G, D), *pools)
     return o.reshape(B, K, T, G, D)
 
 
 # ------------------------------------------------------ sliding-window ring
 
-def _windowed_ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref,
-                                    kn_ref, vn_ref, k_ref, v_ref, *rest,
-                                    page_size: int, n_ring: int, n_fresh: int,
-                                    q_blk: int, G: int, window: int,
-                                    scale: float, softcap: float, v_dtype,
-                                    quantized: bool):
+def _windowed_ragged_prefill_kernel(layer_ref, tables_ref, start_ref,
+                                    n_live_ref, q_ref, kn_ref, vn_ref, k_ref,
+                                    v_ref, *rest, page_size: int, n_ring: int,
+                                    n_fresh: int, q_blk: int, G: int, K: int,
+                                    window: int, scale: float, softcap: float,
+                                    v_dtype, quantized: bool):
     if quantized:
         ks_ref, vs_ref, o_ref, s_scr, acc_scr = rest
     else:
@@ -286,7 +298,7 @@ def _windowed_ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref,
     i = pl.program_id(3)
     start = start_ref[b]
     n_live = n_live_ref[b]
-    rows = q_ref.shape[2]
+    rows, D = q_ref.shape[2], q_ref.shape[3]
     n_kv = n_ring + n_fresh
     j = jnp.where(i < n_kv, i, i - n_kv)
     ring_n = n_ring * page_size
@@ -309,7 +321,8 @@ def _windowed_ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref,
             valid = (k_abs >= 0) & (k_abs > q_abs - window)
             # only the resident ring pages are int8; the fresh chunk's K/V
             # below ride in at model dtype
-            s = _scores_t(q, _kv_head(k_ref, kh, ks_ref), scale, softcap)
+            s = _scores_t(q, _kv_head(k_ref, kh, K, D, ks_ref), scale,
+                          softcap)
             _store_scores(s_scr, j * page_size, s, valid)
 
         @pl.when(j >= n_ring)
@@ -318,7 +331,7 @@ def _windowed_ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref,
             k_abs = start + jf * page_size + col
             valid = (k_abs <= q_abs) & (k_abs > q_abs - window) \
                 & (jf * page_size + col < n_live)
-            s = _scores_t(q, _kv_head(kn_ref, kh), scale, softcap)
+            s = _scores_t(q, _kv_head(kn_ref, kh, K, D), scale, softcap)
             _store_scores(s_scr, j * page_size, s, valid)
 
     @pl.when(i == n_kv - 1)
@@ -331,8 +344,8 @@ def _windowed_ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref,
 
     @pl.when(i >= n_kv)
     def _():
-        vsel = jnp.where(j < n_ring, _kv_head(v_ref, kh, vs_ref),
-                         _kv_head(vn_ref, kh))
+        vsel = jnp.where(j < n_ring, _kv_head(v_ref, kh, K, D, vs_ref),
+                         _kv_head(vn_ref, kh, K, D))
         _pv_accumulate(acc_scr, s_scr, j * page_size, vsel, v_dtype)
 
     @pl.when(i == 2 * n_kv - 1)
@@ -340,19 +353,20 @@ def _windowed_ragged_prefill_kernel(tables_ref, start_ref, n_live_ref, q_ref,
         o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
 
 
-def windowed_ragged_prefill_fwd(q, k_new, v_new, k_pages, v_pages, tables,
-                                start, n_live, *, window: int, scale: float,
-                                softcap: float = 0.0, q_blk: int = 128,
-                                k_scale=None, v_scale=None,
+def windowed_ragged_prefill_fwd(q, k_new, v_new, k_pages, v_pages, layer,
+                                tables, start, n_live, *, window: int,
+                                scale: float, softcap: float = 0.0,
+                                q_blk: int = 128, k_scale=None, v_scale=None,
                                 interpret: bool = False):
     """q: [B, K, T, G, D]; k_new/v_new: [B, T, K, D] fresh roped chunk K/V
-    (T a multiple of the page size); k_pages/v_pages: [P, ps, K, D]
-    *pre-write* pool; tables: [B, n_ring] ring tables.  Returns
-    [B, K, T, G, D].  ``k_scale``/``v_scale``: [P, ps, K] bf16 scales for
-    the int8 ring pages; the fresh chunk stays at model dtype (it is
-    quantized only when written back after the attend)."""
+    (T a multiple of the page size); k_pages/v_pages: [L, P, ps, W]
+    layer-stacked lane-dense *pre-write* pool, read at layer ``layer``;
+    tables: [B, n_ring] ring tables.  Returns [B, K, T, G, D].
+    ``k_scale``/``v_scale``: [L, P, ps, K] bf16 scales for the int8 ring
+    pages; the fresh chunk stays at model dtype (it is quantized only when
+    written back after the attend)."""
     B, K, T, G, D = q.shape
-    ps = k_pages.shape[1]
+    ps, W = k_pages.shape[2], k_pages.shape[3]
     Tk = k_new.shape[1]                   # fresh K/V length (un-padded chunk)
     assert Tk % ps == 0, (Tk, ps)
     n_ring = tables.shape[1]
@@ -362,7 +376,7 @@ def windowed_ragged_prefill_fwd(q, k_new, v_new, k_pages, v_pages, tables,
     quantized = k_scale is not None
     kernel = functools.partial(
         _windowed_ragged_prefill_kernel, page_size=ps, n_ring=n_ring,
-        n_fresh=n_fresh, q_blk=q_blk, G=G, window=window, scale=scale,
+        n_fresh=n_fresh, q_blk=q_blk, G=G, K=K, window=window, scale=scale,
         softcap=softcap,
         v_dtype=jnp.float32 if quantized else v_pages.dtype,
         quantized=quantized)
@@ -371,27 +385,18 @@ def windowed_ragged_prefill_fwd(q, k_new, v_new, k_pages, v_pages, tables,
         j = jnp.where(i < n_kv, i, i - n_kv)
         return tr[b, jnp.minimum(j, n_ring - 1)]
 
-    def fresh_map(b, kh, qb, i, tr, st, nl):
+    def fresh_map(b, kh, qb, i, *_):
         j = jnp.where(i < n_kv, i, i - n_kv)
         return (b, jnp.clip(j - n_ring, 0, n_fresh - 1), 0, 0)
 
     q_spec = pl.BlockSpec((1, 1, q_blk * G, D),
-                          lambda b, kh, qb, i, tr, st, nl: (b, kh, qb, 0))
-    in_specs = [
-        q_spec,
-        pl.BlockSpec((1, ps, K, D), fresh_map),
-        pl.BlockSpec((1, ps, K, D), fresh_map),
-        _page_spec(ps, K, D, ring_page),
-        _page_spec(ps, K, D, ring_page),
-    ]
-    operands = [tables, start, n_live, q.reshape(B, K, T * G, D), k_new,
-                v_new, k_pages, v_pages]
-    if quantized:
-        in_specs += [_scale_spec(ps, K, ring_page),
-                     _scale_spec(ps, K, ring_page)]
-        operands += [k_scale, v_scale]
+                          lambda b, kh, qb, i, *_: (b, kh, qb, 0))
+    pools = [k_pages, v_pages] + ([k_scale, v_scale] if quantized else [])
+    in_specs = [q_spec, pl.BlockSpec((1, 1, ps, W), fresh_map),
+                pl.BlockSpec((1, 1, ps, W), fresh_map)] + [
+        _page_spec(p.shape[2:], ring_page) for p in pools]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, K, n_qb, 2 * n_kv),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -409,15 +414,17 @@ def windowed_ragged_prefill_fwd(q, k_new, v_new, k_pages, v_pages, tables,
                                  "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(*operands)
+    )(layer_operand(layer), tables, start, n_live,
+      q.reshape(B, K, T * G, D), _lane_pages(k_new, ps, W),
+      _lane_pages(v_new, ps, W), *pools)
     return o.reshape(B, K, T, G, D)
 
 
 # ------------------------------------------------------ MLA materialized-K
 
-def _mla_prefill_walk_kernel(sched_ref, pages_ref, base_ref, q_ref, wuk_ref,
-                             wuv_ref, *refs, page_size: int, n_pages: int,
-                             ppb: int, scale: float, kv_dtype,
+def _mla_prefill_walk_kernel(layer_ref, sched_ref, pages_ref, base_ref,
+                             q_ref, wuk_ref, wuv_ref, *refs, page_size: int,
+                             n_pages: int, ppb: int, scale: float, kv_dtype,
                              quantized: bool):
     """One step of the MLA prefill walk, for the grid step's group of
     heads: one block of ``ppb`` latent pages against one query block
@@ -429,13 +436,14 @@ def _mla_prefill_walk_kernel(sched_ref, pages_ref, base_ref, q_ref, wuk_ref,
     at the row's true max, rounds those to the value dtype (the reference's
     ``softmax(s).astype(v.dtype)``) and accumulates them against the
     materialized V (``ckv @ w_uv``) in fp32."""
-    del pages_ref          # read by the index maps only
-    n_ops = 4 if quantized else 2
+    del layer_ref, pages_ref          # read by the index maps only
+    n_ops = 3 if quantized else 1
     pages = [refs[a * ppb:(a + 1) * ppb] for a in range(n_ops)]
     o_ref, m_scr, l_scr, acc_scr = refs[n_ops * ppb:]
-    ckv_refs, kr_refs = pages[:2]
-    cs_refs, rs_refs = pages[2:] if quantized else ([None] * ppb,) * 2
+    lat_refs = pages[0]
+    cs_refs, rs_refs = pages[1:] if quantized else ([None] * ppb,) * 2
     ps, n_blk = page_size, -(-n_pages // ppb)
+    L, R = wuk_ref.shape[1], q_ref.shape[3] - wuk_ref.shape[2]
     step = pl.program_id(1)
     vrow, blk = sched_ref[step] // n_blk, sched_ref[step] % n_blk
     second = vrow % 2 == 1
@@ -447,7 +455,7 @@ def _mla_prefill_walk_kernel(sched_ref, pages_ref, base_ref, q_ref, wuk_ref,
     def _():
         _init(m_scr, l_scr, acc_scr)
 
-    block = [_mla_page(ckv_refs[j], kr_refs[j], cs_refs[j], rs_refs[j])
+    block = [_mla_page(lat_refs[j], L, R, cs_refs[j], rs_refs[j])
              for j in range(ppb)]
     ckv = jnp.concatenate([c for c, _ in block], axis=0).astype(kv_dtype)
     kr = jnp.concatenate([k for _, k in block], axis=0).astype(kv_dtype)
@@ -503,18 +511,19 @@ def mla_head_group(H: int) -> int:
     return g
 
 
-def mla_ragged_prefill_fwd(q, ckv_pages, krope_pages, w_uk, w_uv, tables,
-                           start, n_live, *, scale: float, q_blk: int = 512,
+def mla_ragged_prefill_fwd(q, pages, layer, w_uk, w_uv, tables, start,
+                           n_live, *, scale: float, q_blk: int = 512,
                            ckv_scale=None, krope_scale=None,
                            interpret: bool = False):
     """q: [B, H, T, nope+rope] (rope part roped; T a ``q_blk`` multiple);
-    ckv_pages: [P, ps, L]; krope_pages: [P, ps, R]; w_uk: [H, L, nope];
-    w_uv: [H, L, vd] (head-major, so a head group's block spans the array's
-    last two dims); tables: [B, n_pages].  Returns the attended values
-    [B, H, T, vd].  ``ckv_scale``/``krope_scale``: [P, ps] bf16 scales when
-    the latent pages are int8 — the dequantized latent is fp32, so the
-    in-kernel K/V materialization stays fp32 (``kv_dtype``) exactly like
-    the reference dequant einsum.
+    pages: [Lyr, P, ps, W] layer-stacked lane-dense latent pages (ckv at
+    lanes [0, L), krope at [L, L + R)), read at layer ``layer``; w_uk:
+    [H, L, nope]; w_uv: [H, L, vd] (head-major, so a head group's block
+    spans the array's last two dims); tables: [B, n_pages].  Returns the
+    attended values [B, H, T, vd].  ``ckv_scale``/``krope_scale``:
+    [Lyr, P, ps] bf16 scales when the latent pages are int8 — the
+    dequantized latent is fp32, so the in-kernel K/V materialization stays
+    fp32 (``kv_dtype``) exactly like the reference dequant einsum.
 
     The decode walk (``paged_attention.kernel.walk_schedule``) over virtual
     rows: each chunk row's ``T / q_blk`` query blocks, block ``i`` at base
@@ -526,40 +535,40 @@ def mla_ragged_prefill_fwd(q, ckv_pages, krope_pages, w_uk, w_uv, tables,
     the caller."""
     del n_live
     B, H, T, E = q.shape
-    L, vd = ckv_pages.shape[2], w_uv.shape[2]
-    ps, n_pages = ckv_pages.shape[1], tables.shape[1]
+    L, vd = w_uk.shape[1], w_uv.shape[2]
+    ps, n_pages = pages.shape[2], tables.shape[1]
     n_qb = T // q_blk
     quantized = ckv_scale is not None
     G = mla_head_group(H)
-    ppb = mla_pages_per_block(ckv_pages, krope_pages, n_pages, quantized)
+    pools = latent_pools(pages, ckv_scale, krope_scale)
+    ppb = mla_pages_per_block(pools, n_pages)
     n_blk = -(-n_pages // ppb)
     V = B * n_qb
     base = (start[:, None] + q_blk * jnp.arange(n_qb, dtype=jnp.int32)
             ).reshape(V)
     # each query block is walked twice in a row (the two passes)
-    sched, pages, n_steps = walk_schedule(
+    sched, sched_pages, n_steps = walk_schedule(
         jnp.repeat(tables, 2 * n_qb, axis=0),
         jnp.repeat(base, 2) + q_blk - 1, ps, ppb)
     qv = q.reshape(B, H, n_qb, q_blk, E).transpose(0, 2, 1, 3, 4).reshape(
         V, H, q_blk, E)
-    pools = [ckv_pages, krope_pages]
-    if quantized:
-        pools += [scale_view(ckv_scale), scale_view(krope_scale)]
 
     def page_spec(pool, j):
-        return pl.BlockSpec((1,) + pool.shape[1:],
-                            lambda g, s, sched_ref, pages_ref, _: (
+        return pl.BlockSpec((1, 1) + pool.shape[2:],
+                            lambda g, s, layer_ref, sched_ref, pages_ref, _: (
+                                layer_ref[0],
                                 pages_ref[sched_ref[s] * ppb + j], 0, 0))
 
     def row_spec(width):
-        return pl.BlockSpec((1, G, q_blk, width), lambda g, s, sched_ref, *_: (
-            sched_ref[s] // n_blk // 2, g, 0, 0))
+        return pl.BlockSpec((1, G, q_blk, width),
+                            lambda g, s, _, sched_ref, *__: (
+                                sched_ref[s] // n_blk // 2, g, 0, 0))
 
     def head_spec(width):
         return pl.BlockSpec((G, L, width), lambda g, s, *_: (g, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(H // G, n_steps),
         in_specs=[row_spec(E), head_spec(w_uk.shape[2]), head_spec(vd)]
         + [page_spec(p, j) for p in pools for j in range(ppb)],
@@ -572,7 +581,7 @@ def mla_ragged_prefill_fwd(q, ckv_pages, krope_pages, w_uk, w_uv, tables,
         functools.partial(
             _mla_prefill_walk_kernel, page_size=ps, n_pages=n_pages, ppb=ppb,
             scale=scale, quantized=quantized,
-            kv_dtype=jnp.float32 if quantized else ckv_pages.dtype),
+            kv_dtype=jnp.float32 if quantized else pages.dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((V, H, q_blk, vd), q.dtype),
         # a virtual row's blocks run in order, carrying the softmax state
@@ -580,7 +589,7 @@ def mla_ragged_prefill_fwd(q, ckv_pages, krope_pages, w_uk, w_uv, tables,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(sched, pages, base, qv, w_uk, w_uv,
+    )(layer_operand(layer), sched, sched_pages, base, qv, w_uk, w_uv,
       *[p for p in pools for _ in range(ppb)])
     return o.reshape(B, n_qb, H, q_blk, vd).transpose(0, 2, 1, 3, 4).reshape(
         B, H, T, vd)

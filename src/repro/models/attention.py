@@ -226,7 +226,9 @@ def cache_defs(cfg: ArchConfig, batch: int, max_len: int, window: int = 0):
 
 def paged_cache_defs(cfg: ArchConfig, num_pages: int, page_size: int,
                      kv_dtype: str = "bf16"):
-    """One layer's share of the paged KV pool: [P, page_size, K, D] per tensor.
+    """One layer's share of the paged KV pool: [P, page_size, W] per tensor,
+    each token slot one lane-dense row of ``W = page_width(K * D)`` lanes
+    (``pack_heads``: KV head h at lanes [h * D, (h + 1) * D)).
 
     Unlike ``cache_defs`` there is no batch dim — requests own disjoint page
     sets and a per-request page table maps logical pages to physical ones.
@@ -236,24 +238,84 @@ def paged_cache_defs(cfg: ArchConfig, num_pages: int, page_size: int,
     [P, page_size, K]) that share the payload's page axis — a physical page
     id addresses payload and scales together, so refcounting, radix sharing
     and COW forks need no separate scale accounting."""
-    hd = cfg.head_dim_
+    K = cfg.n_kv_heads
+    width = page_width(K * cfg.head_dim_)
     payload_dt = jnp.int8 if kv_dtype == "int8" else jnp.bfloat16
     defs = {
-        "k": ParamDef((num_pages, page_size, cfg.n_kv_heads, hd),
-                      (None, "seq", "kv_heads", "head_dim"),
+        "k": ParamDef((num_pages, page_size, width), (None, "seq", None),
                       dtype=payload_dt, init="zeros"),
-        "v": ParamDef((num_pages, page_size, cfg.n_kv_heads, hd),
-                      (None, "seq", "kv_heads", "head_dim"),
+        "v": ParamDef((num_pages, page_size, width), (None, "seq", None),
                       dtype=payload_dt, init="zeros"),
     }
     if kv_dtype == "int8":
-        defs["k_scale"] = ParamDef((num_pages, page_size, cfg.n_kv_heads),
+        defs["k_scale"] = ParamDef((num_pages, page_size, K),
                                    (None, "seq", "kv_heads"),
                                    dtype=jnp.bfloat16, init="zeros")
-        defs["v_scale"] = ParamDef((num_pages, page_size, cfg.n_kv_heads),
+        defs["v_scale"] = ParamDef((num_pages, page_size, K),
                                    (None, "seq", "kv_heads"),
                                    dtype=jnp.bfloat16, init="zeros")
     return defs
+
+
+# ------------------------------------------------------ pool page layout
+#
+# Every paged pool leaf is one layer-stacked array [L, P, page_size, W] with
+# W a multiple of 128 lanes (kernels/README.md, "Pool layout").  XLA keeps
+# such an array row-major, the layout the Pallas kernels read, so the layer
+# loop carries it and writes each step's token slots in place; the kernels
+# take the whole stack and the layer index.
+
+LANES = 128
+
+
+def page_width(features: int) -> int:
+    """Lanes of one token slot: ``features`` rounded up to whole 128-lane
+    rows (the padding lanes hold zeros)."""
+    return -(-features // LANES) * LANES
+
+
+def pack_heads(x: jax.Array, width: int) -> jax.Array:
+    """Per-head K or V [..., K, D] as lane-dense token slots [..., width]:
+    head h at lanes [h * D, (h + 1) * D), zeros past K * D."""
+    flat = x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
+    return jnp.pad(flat, [(0, 0)] * (flat.ndim - 1)
+                   + [(0, width - flat.shape[-1])])
+
+
+def head_view(slots: jax.Array, K: int, D: int) -> jax.Array:
+    """Lane-dense token slots [..., W] as per-head [..., K, D]."""
+    return slots[..., :K * D].reshape(slots.shape[:-1] + (K, D))
+
+
+def write_slots(cache, layer, meta, new):
+    """Scatter a step's new token slots into layer ``layer`` of the pool, in
+    place on the carried stack: ``new`` maps leaf names to values shaped
+    ``meta["write_page"].shape + slot``; every other leaf passes through."""
+    wp, wo = meta["write_page"], meta["write_off"]
+    out = dict(cache)
+    for name, val in new.items():
+        out[name] = cache[name].at[layer, wp, wo].set(
+            val.astype(cache[name].dtype))
+    return out
+
+
+def kv_slots(cache, k, v):
+    """The new token slots of per-head ``k``/``v`` [..., K, D]: lane-dense
+    payloads, absmax-quantized with their scale leaves when the pool is
+    int8."""
+    W = cache["k"].shape[-1]
+    if "k_scale" not in cache:
+        return {"k": pack_heads(k, W), "v": pack_heads(v, W)}
+    kq, ks = quantize_int8(k)
+    vq, vs = quantize_int8(v)
+    return {"k": pack_heads(kq, W), "v": pack_heads(vq, W), "k_scale": ks,
+            "v_scale": vs}
+
+
+def kv_scales(cache):
+    """The scale leaves an attend core takes ({} for a bf16 pool)."""
+    return ({"k_scale": cache["k_scale"], "v_scale": cache["v_scale"]}
+            if "k_scale" in cache else {})
 
 
 # ------------------------------------------------- int8 KV quantization
@@ -294,13 +356,16 @@ def dequant_int8(q: jax.Array, s: jax.Array) -> jax.Array:
 # vanilla, sliding-window, and MLA paged blocks used to hand-roll separately.
 # The reference attention backend (models.attn_backend) is built from these.
 
-def gather_pages(pages: jax.Array, tables: jax.Array) -> jax.Array:
-    """Materialize the logical per-request view of a paged pool.
+def gather_pages(pages: jax.Array, layer, tables: jax.Array) -> jax.Array:
+    """Materialize the logical per-request view of one layer of a paged
+    pool.
 
-    pages: [P, ps, ...]; tables: [B, n] int32 physical page ids.  Returns
-    [B, n * ps, ...] — request b's pages concatenated in table order."""
+    pages: [L, P, ps, ...]; layer: int32 scalar; tables: [B, n] int32
+    physical page ids.  Returns [B, n * ps, ...] — request b's pages of
+    layer ``layer`` concatenated in table order."""
     B, n = tables.shape
-    return pages[tables].reshape((B, n * pages.shape[1]) + pages.shape[2:])
+    return pages[layer, tables].reshape((B, n * pages.shape[2])
+                                        + pages.shape[3:])
 
 
 def decode_valid_mask(pos: jax.Array, n: int, *, window: int = 0) -> jax.Array:
@@ -411,12 +476,14 @@ def masked_multi_token_attend(q, kg, vg, valid, *, scale: float,
 # output projection.  The attend itself is delegated to ``backend`` (see
 # models.attn_backend) — reference gather+attend or the fused Pallas kernel.
 
-def paged_prefill_attention_block(cfg: ArchConfig, p, x, cache, meta, freqs,
-                                  backend, *, q_block=512, unroll=False):
+def paged_prefill_attention_block(cfg: ArchConfig, p, x, cache, layer, meta,
+                                  freqs, backend, *, q_block=512,
+                                  unroll=False):
     """Multi-token (chunk) prefill step against the paged KV pool.
 
-    x: [B, T, d] chunk activations; cache: {"k","v": [P, ps, K, D]} one
-    layer's pages; meta: the flat per-step prefill metadata from
+    x: [B, T, d] chunk activations; cache: the layer-stacked pool
+    ({"k","v": [L, P, ps, W]}, plus int8 scale leaves), of which this block
+    is layer ``layer``; meta: the flat per-step prefill metadata from
     ``attn_backend.prefill_meta`` — page-table rows, per-row chunk offsets
     (``start``: absolute position of x[:, 0]), live counts, and the
     precomputed physical (page, offset) write target of every chunk position
@@ -427,102 +494,66 @@ def paged_prefill_attention_block(cfg: ArchConfig, p, x, cache, meta, freqs,
     causal masking, so a prefix written by an earlier request (radix cache
     hit) or an earlier chunk of this request is read exactly as if this call
     had prefilled it itself.  Sliding-window layers attend the chunk's fresh
-    K/V plus the page *ring* as gathered before the chunk's scatter
+    K/V plus the page *ring* as read before the chunk's scatter
     (``ring_chunk_attention``); the attend core is delegated to ``backend``
     (reference gather+attend or the fused ragged-prefill kernel).  Returns
     (out [B, T, d], new_cache)."""
     B, T, _ = x.shape
-    quantized = "k_scale" in cache
     tables, start, n_live = meta["tables"], meta["start"], meta["n_live"]
     q, k, v = qkv(cfg, p, x)
     positions = start[:, None] + jnp.arange(T)[None, :]              # [B, T]
     if freqs is not None:
         q = apply_rope(q, positions, freqs)
         k = apply_rope(k, positions, freqs)
-    wp, wo = meta["write_page"], meta["write_off"]
-
-    def scatter(kx, vx):
-        ck = cache["k"].at[wp, wo].set(kx.astype(cache["k"].dtype))
-        cv = cache["v"].at[wp, wo].set(vx.astype(cache["v"].dtype))
-        return ck, cv
-
-    if quantized:
-        kq, ks = quantize_int8(k)
-        vq, vs = quantize_int8(v)
-        ck, cv = scatter(kq, vq)
-        cks = cache["k_scale"].at[wp, wo].set(ks)
-        cvs = cache["v_scale"].at[wp, wo].set(vs)
+    new = kv_slots(cache, k, v)
+    kw = dict(kv_heads=cfg.n_kv_heads, softcap=cfg.attn_logit_softcap,
+              q_block=q_block, unroll=unroll)
     window = cfg.sliding_window
     if window:
-        # ring modulus contract: the ring is the full table width the engine
-        # passes (>= window_pages; may carry slack pages for speculation)
-        ring_tables = tables
         # the ring must be read *before* the chunk's writes recycle slots
-        # still holding in-window keys of this chunk's earliest queries;
-        # quantized mode passes the pre-write scales alongside (fresh chunk
-        # K/V ride in unquantized — only resident pages are int8)
-        scales = ({"k_scale": cache["k_scale"],
-                   "v_scale": cache["v_scale"]} if quantized else {})
+        # still holding in-window keys of this chunk's earliest queries; its
+        # modulus is the full table width the engine passes (>= window_pages;
+        # may carry slack pages for speculation).  The fresh chunk K/V ride
+        # in unquantized — only resident pages are int8
         o = backend.prefill_attend(
-            q, k, v, cache["k"], cache["v"], ring_tables, start, n_live,
-            window=window, softcap=cfg.attn_logit_softcap, q_block=q_block,
-            unroll=unroll, **scales)
-        if not quantized:
-            ck, cv = scatter(k, v)
+            q, k, v, cache["k"], cache["v"], layer, tables, start, n_live,
+            window=window, **kw, **kv_scales(cache))
+        cache = write_slots(cache, layer, meta, new)
     else:
-        if not quantized:
-            ck, cv = scatter(k, v)
-        scales = ({"k_scale": cks, "v_scale": cvs} if quantized else {})
+        cache = write_slots(cache, layer, meta, new)
         o = backend.prefill_attend(
-            q, k, v, ck, cv, tables, start, n_live, window=0,
-            softcap=cfg.attn_logit_softcap, q_block=q_block, unroll=unroll,
-            **scales)
-    new_cache = {"k": ck, "v": cv}
-    if quantized:
-        new_cache.update(k_scale=cks, v_scale=cvs)
-    return jnp.einsum("bshe,hed->bsd", o, p["wo"]), new_cache
+            q, k, v, cache["k"], cache["v"], layer, tables, start, n_live,
+            window=0, **kw, **kv_scales(cache))
+    return jnp.einsum("bshe,hed->bsd", o, p["wo"]), cache
 
 
-def paged_decode_attention_block(cfg: ArchConfig, p, x, cache, meta, freqs,
-                                 backend):
+def paged_decode_attention_block(cfg: ArchConfig, p, x, cache, layer, meta,
+                                 freqs, backend):
     """One-token decode step against the paged KV pool.
 
-    x: [B, d] slot activations; cache: {"k","v": [P, ps, K, D]} (one layer's
-    pages, shared by all slots); meta: the flat per-step metadata from
-    ``attn_backend.decode_meta`` — page-table rows, absolute positions, and
-    the precomputed physical (page, offset) write target of the new token
-    (ring-aware for sliding-window layers, recycling the page that just aged
-    out of the window).  The attend reads the pages through ``backend`` with
-    positions > pos masked (window layers: masked by absolute position
-    recovered from the ring layout), so stale data in partially-filled or
-    recycled pages is softmax-zero.  Returns (out [B, d], new_cache)."""
-    quantized = "k_scale" in cache
+    x: [B, d] slot activations; cache: the layer-stacked pool (shared by all
+    slots), of which this block is layer ``layer``; meta: the flat per-step
+    metadata from ``attn_backend.decode_meta`` — page-table rows, absolute
+    positions, and the precomputed physical (page, offset) write target of
+    the new token (ring-aware for sliding-window layers, recycling the page
+    that just aged out of the window).  The attend reads the pages through
+    ``backend`` with positions > pos masked (window layers: masked by
+    absolute position recovered from the ring layout), so stale data in
+    partially-filled or recycled pages is softmax-zero.  Returns
+    (out [B, d], new_cache)."""
     pos = meta["pos"]
     q, k, v = decode_qkv(cfg, p, x, pos, freqs)
-    wp, wo = meta["write_page"], meta["write_off"]
-    scales = {}
-    if quantized:
-        k, ks = quantize_int8(k)
-        v, vs = quantize_int8(v)
-        cks = cache["k_scale"].at[wp, wo].set(ks)
-        cvs = cache["v_scale"].at[wp, wo].set(vs)
-        scales = {"k_scale": cks, "v_scale": cvs}
-    ck = cache["k"].at[wp, wo].set(k.astype(cache["k"].dtype))
-    cv = cache["v"].at[wp, wo].set(v.astype(cache["v"].dtype))
-    tables = meta["tables"]
-    window = cfg.sliding_window
-    o = backend.decode_attend(q, ck, cv, tables, pos,
+    cache = write_slots(cache, layer, meta, kv_slots(cache, k, v))
+    o = backend.decode_attend(q, cache["k"], cache["v"], layer,
+                              meta["tables"], pos, kv_heads=cfg.n_kv_heads,
                               scale=1.0 / math.sqrt(cfg.head_dim_),
-                              softcap=cfg.attn_logit_softcap, window=window,
-                              **scales)
-    out = jnp.einsum("bhe,hed->bd", o, p["wo"])
-    new_cache = {"k": ck, "v": cv}
-    new_cache.update(scales)
-    return out, new_cache
+                              softcap=cfg.attn_logit_softcap,
+                              window=cfg.sliding_window, **kv_scales(cache))
+    return jnp.einsum("bhe,hed->bd", o, p["wo"]), cache
 
 
-def paged_verify_attention_block(cfg: ArchConfig, p, x, cache, meta, freqs,
-                                 backend):
+def paged_verify_attention_block(cfg: ArchConfig, p, x, cache, layer, meta,
+                                 freqs, backend):
     """Small-q speculative verify step against the paged KV pool.
 
     x: [B, Q, d] — per slot the last emitted token plus its draft, padded to
@@ -537,31 +568,20 @@ def paged_verify_attention_block(cfg: ArchConfig, p, x, cache, meta, freqs,
     and attend are the exact per-row ops of the decode block, which is what
     keeps accepted tokens bit-identical to the non-speculative stream.
     Returns (out [B, Q, d], new_cache)."""
-    quantized = "k_scale" in cache
     pos, Q = meta["pos"], x.shape[1]
     q, k, v = qkv(cfg, p, x)
     positions = pos[:, None] + jnp.arange(Q)[None, :]             # [B, Q]
     if freqs is not None:
         q = apply_rope(q, positions, freqs)
         k = apply_rope(k, positions, freqs)
-    wp, wo = meta["write_page"], meta["write_off"]
-    scales = {}
-    if quantized:
-        k, ks = quantize_int8(k)
-        v, vs = quantize_int8(v)
-        cks = cache["k_scale"].at[wp, wo].set(ks)
-        cvs = cache["v_scale"].at[wp, wo].set(vs)
-        scales = {"k_scale": cks, "v_scale": cvs}
-    ck = cache["k"].at[wp, wo].set(k.astype(cache["k"].dtype))
-    cv = cache["v"].at[wp, wo].set(v.astype(cache["v"].dtype))
-    o = backend.verify_attend(q, ck, cv, meta["tables"], pos, meta["n_q"],
+    cache = write_slots(cache, layer, meta, kv_slots(cache, k, v))
+    o = backend.verify_attend(q, cache["k"], cache["v"], layer,
+                              meta["tables"], pos, meta["n_q"],
+                              kv_heads=cfg.n_kv_heads,
                               scale=1.0 / math.sqrt(cfg.head_dim_),
                               softcap=cfg.attn_logit_softcap,
-                              window=cfg.sliding_window, **scales)
-    out = jnp.einsum("bqhe,hed->bqd", o, p["wo"])
-    new_cache = {"k": ck, "v": cv}
-    new_cache.update(scales)
-    return out, new_cache
+                              window=cfg.sliding_window, **kv_scales(cache))
+    return jnp.einsum("bqhe,hed->bqd", o, p["wo"]), cache
 
 
 def decode_attention_block(cfg: ArchConfig, p, x, cache, pos, freqs, *, window=0):

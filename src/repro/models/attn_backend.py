@@ -4,8 +4,9 @@ The serving hot loop is paged attention — prefill writes K/V (or MLA latent)
 through per-request page tables, decode reads every live token back per step.
 How that read happens is a *backend* choice, orthogonal to the cache family:
 
-* ``reference`` — the XLA gather+attend formulation (``pool[tables]``
-  materializes the logical view in HBM, then dense masked attention) — the
+* ``reference`` — the XLA gather+attend formulation
+  (``pool[layer, tables]`` materializes the logical view in HBM, then dense
+  masked attention) — the
   parity oracle every other backend is verified against.  Its decode attends
   keep the probability-weighted sum in fp32 and round to cache dtype only at
   the block output, the same single rounding point as the fused kernel's
@@ -188,32 +189,38 @@ class AttentionBackend:
     name = "abstract"
 
     # -------- public entry points: the only paged-attention call sites
+    #
+    # ``cache`` is the whole layer-stacked pool ({leaf: [L, P, ps, W]},
+    # kernels/README.md "Pool layout") and ``layer`` the index of the layer
+    # being run: each block writes its token slots into that layer in place
+    # and the attend core reads it there.
 
-    def paged_prefill(self, cfg: ArchConfig, p, x, cache, meta, freqs, *,
-                      q_block: int = 512, unroll: bool = False):
+    def paged_prefill(self, cfg: ArchConfig, p, x, cache, layer, meta, freqs,
+                      *, q_block: int = 512, unroll: bool = False):
         """Multi-token chunk prefill at an offset into the paged pool.
         ``meta`` is the flat per-step metadata from ``prefill_meta``.  Routes
         by cache family (MLA latent / sliding-window ring / vanilla KV);
         returns (out [B, T, d], new_cache)."""
         if cfg.use_mla:
             return mla.mla_paged_prefill_block(
-                cfg, p, x, cache, meta, freqs, backend=self,
+                cfg, p, x, cache, layer, meta, freqs, backend=self,
                 q_block=q_block, unroll=unroll)
         return attention.paged_prefill_attention_block(
-            cfg, p, x, cache, meta, freqs, backend=self,
+            cfg, p, x, cache, layer, meta, freqs, backend=self,
             q_block=q_block, unroll=unroll)
 
-    def paged_decode(self, cfg: ArchConfig, p, x, cache, meta, freqs):
+    def paged_decode(self, cfg: ArchConfig, p, x, cache, layer, meta, freqs):
         """One-token decode against the paged pool.  ``meta`` is the flat
         per-step metadata from ``decode_meta``; returns (out [B, d],
         new_cache)."""
         if cfg.use_mla:
-            return mla.mla_paged_decode_block(cfg, p, x, cache, meta, freqs,
-                                              backend=self)
-        return attention.paged_decode_attention_block(cfg, p, x, cache, meta,
-                                                      freqs, backend=self)
+            return mla.mla_paged_decode_block(cfg, p, x, cache, layer, meta,
+                                              freqs, backend=self)
+        return attention.paged_decode_attention_block(cfg, p, x, cache, layer,
+                                                      meta, freqs,
+                                                      backend=self)
 
-    def paged_verify(self, cfg: ArchConfig, p, x, cache, meta, freqs):
+    def paged_verify(self, cfg: ArchConfig, p, x, cache, layer, meta, freqs):
         """Small-q speculative verify against the paged pool: ``x`` is
         [B, Q, d] (last emitted token + draft, padded to Q), ``meta`` is the
         flat metadata from ``verify_meta``.  All Q tokens' K/V scatter into
@@ -222,37 +229,42 @@ class AttentionBackend:
         surviving queries and are overwritten by the next step's writes.
         Returns (out [B, Q, d], new_cache)."""
         if cfg.use_mla:
-            return mla.mla_paged_verify_block(cfg, p, x, cache, meta, freqs,
-                                              backend=self)
-        return attention.paged_verify_attention_block(cfg, p, x, cache, meta,
-                                                      freqs, backend=self)
+            return mla.mla_paged_verify_block(cfg, p, x, cache, layer, meta,
+                                              freqs, backend=self)
+        return attention.paged_verify_attention_block(cfg, p, x, cache, layer,
+                                                      meta, freqs,
+                                                      backend=self)
 
     # -------- attend cores (override to fuse)
     #
-    # Every core takes optional scale pools (``k_scale``/``v_scale``
-    # [P, ps, K] bf16, or ``ckv_scale``/``krope_scale`` [P, ps] for MLA).
-    # ``None`` (the default) means the payload pools hold bf16 values and
-    # the core behaves exactly as before; non-None means the payloads are
-    # int8 and must be dequantized ``f32(q) * f32(s)`` before use.
+    # Every core reads layer ``layer`` (int32 scalar) of layer-stacked
+    # lane-dense pools: GQA ``k_pages``/``v_pages`` [L, P, ps, W] with KV
+    # head h at lanes [h * D, (h + 1) * D) of ``kv_heads`` heads
+    # (``attention.pack_heads``), MLA ``pages`` [L, P, ps, W] with ckv at
+    # lanes [0, L) and krope at [L, L + R) (``mla.latent_slots``).  Optional
+    # scale pools (``k_scale``/``v_scale`` [L, P, ps, K] bf16, or
+    # ``ckv_scale``/``krope_scale`` [L, P, ps] for MLA): ``None`` (the
+    # default) means the payload pools hold bf16 values; non-None means the
+    # payloads are int8 and must be dequantized ``f32(q) * f32(s)`` before
+    # use.
 
-    def decode_attend(self, q, k_pages, v_pages, tables, pos, *, scale: float,
-                      softcap: float = 0.0, window: int = 0,
-                      k_scale=None, v_scale=None):
-        """q: [B, H, D]; pools [P, ps, K, D]; tables [B, n] (ring when
-        ``window > 0``); pos [B].  Returns [B, H, D]."""
+    def decode_attend(self, q, k_pages, v_pages, layer, tables, pos, *,
+                      kv_heads: int, scale: float, softcap: float = 0.0,
+                      window: int = 0, k_scale=None, v_scale=None):
+        """q: [B, H, D]; tables [B, n] (ring when ``window > 0``); pos [B].
+        Returns [B, H, D]."""
         raise NotImplementedError
 
-    def mla_decode_attend(self, q_eff, q_rope, ckv_pages, krope_pages, tables,
-                          pos, *, scale: float, ckv_scale=None,
-                          krope_scale=None):
+    def mla_decode_attend(self, q_eff, q_rope, pages, layer, tables, pos, *,
+                          scale: float, ckv_scale=None, krope_scale=None):
         """Absorbed-latent scores + latent context: q_eff [B, H, L] /
-        q_rope [B, H, R] against [P, ps, L] / [P, ps, R] pages.  Returns the
-        latent context [B, H, L]."""
+        q_rope [B, H, R] against the latent pages.  Returns the latent
+        context [B, H, L]."""
         raise NotImplementedError
 
-    def verify_attend(self, q, k_pages, v_pages, tables, pos, n_q, *,
-                      scale: float, softcap: float = 0.0, window: int = 0,
-                      k_scale=None, v_scale=None):
+    def verify_attend(self, q, k_pages, v_pages, layer, tables, pos, n_q, *,
+                      kv_heads: int, scale: float, softcap: float = 0.0,
+                      window: int = 0, k_scale=None, v_scale=None):
         """Small-q verify attend: q [B, Q, H, D] (query j of row b sits at
         absolute position ``pos[b] + j``) against the *post-write* pool.
         Mask: token position <= pos + j (ring-recovered when ``window > 0``)
@@ -260,32 +272,30 @@ class AttentionBackend:
         Returns [B, Q, H, D]."""
         raise NotImplementedError
 
-    def mla_verify_attend(self, q_eff, q_rope, ckv_pages, krope_pages,
-                          tables, pos, n_q, *, scale: float, ckv_scale=None,
-                          krope_scale=None):
+    def mla_verify_attend(self, q_eff, q_rope, pages, layer, tables, pos, n_q,
+                          *, scale: float, ckv_scale=None, krope_scale=None):
         """Small-q absorbed-latent verify attend: q_eff [B, Q, H, L] /
         q_rope [B, Q, H, R] against the post-write latent pages, masked as
         ``verify_attend``.  Returns the latent context [B, Q, H, L]."""
         raise NotImplementedError
 
-    def prefill_attend(self, q, k, v, k_pages, v_pages, tables, start, n_live,
-                       *, window: int = 0, softcap: float = 0.0,
-                       q_block: int = 512, unroll: bool = False,
-                       k_scale=None, v_scale=None):
+    def prefill_attend(self, q, k, v, k_pages, v_pages, layer, tables, start,
+                       n_live, *, kv_heads: int, window: int = 0,
+                       softcap: float = 0.0, q_block: int = 512,
+                       unroll: bool = False, k_scale=None, v_scale=None):
         """Ragged multi-token prefill attend against the paged pool.
 
         q: [B, T, H, D] roped chunk queries at per-row offsets ``start``;
         n_live: [B] real chunk tokens.  ``window == 0``: the chunk's K/V are
-        already resident — ``k_pages``/``v_pages`` are the *post-write* pool
-        and ``k``/``v`` are unused.  ``window > 0``: ``k_pages``/``v_pages``
-        are the *pre-write* page ring (``tables`` truncated to the ring
-        horizon) and ``k``/``v`` [B, T, K, D] carry the chunk's fresh roped
-        K/V (always unquantized — only resident pages are int8).  Returns
-        [B, T, H, D_v]."""
+        already resident — the pools are *post-write* and ``k``/``v`` are
+        unused.  ``window > 0``: the pools are *pre-write* (``tables``
+        truncated to the ring horizon) and ``k``/``v`` [B, T, K, D] carry
+        the chunk's fresh roped K/V (always unquantized — only resident
+        pages are int8).  Returns [B, T, H, D_v]."""
         raise NotImplementedError
 
-    def mla_prefill_attend(self, q, ckv_pages, krope_pages, wkv_b, tables,
-                           start, n_live, *, nope: int, scale: float = None,
+    def mla_prefill_attend(self, q, pages, layer, wkv_b, tables, start,
+                           n_live, *, nope: int, scale: float = None,
                            q_block: int = 512, unroll: bool = False,
                            ckv_scale=None, krope_scale=None):
         """Ragged MLA prefill attend: materialized-K semantics against the
@@ -295,12 +305,16 @@ class AttentionBackend:
         raise NotImplementedError
 
 
-def _gather_dequant(pages, scale_pages, tables):
-    """Materialize the logical fp32 view of an int8 pool: gather payload and
-    scale pages through the same table, dequant ``f32(q) * f32(s)``."""
-    g = attention.gather_pages(pages, tables)
-    return attention.dequant_int8(g, attention.gather_pages(scale_pages,
-                                                            tables))
+def _gather_heads(pages, scale_pages, layer, tables, K: int, D: int):
+    """The logical [B, S, K, D] view of one layer of a GQA pool; int8 pools
+    gather their scale pages through the same table and dequantize
+    ``f32(q) * f32(s)``."""
+    g = attention.head_view(attention.gather_pages(pages, layer, tables), K,
+                            D)
+    if scale_pages is None:
+        return g
+    return attention.dequant_int8(
+        g, attention.gather_pages(scale_pages, layer, tables))
 
 
 @register_backend
@@ -315,99 +329,77 @@ class ReferenceBackend(AttentionBackend):
 
     name = "reference"
 
-    def decode_attend(self, q, k_pages, v_pages, tables, pos, *, scale: float,
-                      softcap: float = 0.0, window: int = 0,
-                      k_scale=None, v_scale=None):
-        if k_scale is not None:
-            kg = _gather_dequant(k_pages, k_scale, tables)
-            vg = _gather_dequant(v_pages, v_scale, tables)
-        else:
-            kg = attention.gather_pages(k_pages, tables)
-            vg = attention.gather_pages(v_pages, tables)
+    def decode_attend(self, q, k_pages, v_pages, layer, tables, pos, *,
+                      kv_heads: int, scale: float, softcap: float = 0.0,
+                      window: int = 0, k_scale=None, v_scale=None):
+        D = q.shape[-1]
+        kg = _gather_heads(k_pages, k_scale, layer, tables, kv_heads, D)
+        vg = _gather_heads(v_pages, v_scale, layer, tables, kv_heads, D)
         valid = attention.decode_valid_mask(pos, kg.shape[1], window=window)
         o = attention.masked_token_attend(q, kg, vg, valid, scale=scale,
                                           softcap=softcap)
         return o.astype(q.dtype)
 
-    def mla_decode_attend(self, q_eff, q_rope, ckv_pages, krope_pages, tables,
-                          pos, *, scale: float, ckv_scale=None,
-                          krope_scale=None):
-        if ckv_scale is not None:
-            ccg = _gather_dequant(ckv_pages, ckv_scale, tables)
-            crg = _gather_dequant(krope_pages, krope_scale, tables)
-        else:
-            ccg = attention.gather_pages(ckv_pages, tables)
-            crg = attention.gather_pages(krope_pages, tables)
+    def mla_decode_attend(self, q_eff, q_rope, pages, layer, tables, pos, *,
+                          scale: float, ckv_scale=None, krope_scale=None):
+        ccg, crg = mla.gather_latent(pages, layer, tables, q_eff.shape[-1],
+                                     q_rope.shape[-1], ckv_scale,
+                                     krope_scale)
         valid = attention.decode_valid_mask(pos, ccg.shape[1])
         ctx = mla.mla_latent_attend(q_eff, q_rope, ccg, crg, valid,
                                     scale=scale)
         return ctx.astype(q_eff.dtype)
 
-    def verify_attend(self, q, k_pages, v_pages, tables, pos, n_q, *,
-                      scale: float, softcap: float = 0.0, window: int = 0,
-                      k_scale=None, v_scale=None):
-        if k_scale is not None:
-            kg = _gather_dequant(k_pages, k_scale, tables)
-            vg = _gather_dequant(v_pages, v_scale, tables)
-        else:
-            kg = attention.gather_pages(k_pages, tables)
-            vg = attention.gather_pages(v_pages, tables)
+    def verify_attend(self, q, k_pages, v_pages, layer, tables, pos, n_q, *,
+                      kv_heads: int, scale: float, softcap: float = 0.0,
+                      window: int = 0, k_scale=None, v_scale=None):
+        D = q.shape[-1]
+        kg = _gather_heads(k_pages, k_scale, layer, tables, kv_heads, D)
+        vg = _gather_heads(v_pages, v_scale, layer, tables, kv_heads, D)
         valid = attention.verify_valid_mask(pos, n_q, q.shape[1],
                                             kg.shape[1], window=window)
         o = attention.masked_multi_token_attend(q, kg, vg, valid,
                                                 scale=scale, softcap=softcap)
         return o.astype(q.dtype)
 
-    def mla_verify_attend(self, q_eff, q_rope, ckv_pages, krope_pages,
-                          tables, pos, n_q, *, scale: float, ckv_scale=None,
-                          krope_scale=None):
-        if ckv_scale is not None:
-            ccg = _gather_dequant(ckv_pages, ckv_scale, tables)
-            crg = _gather_dequant(krope_pages, krope_scale, tables)
-        else:
-            ccg = attention.gather_pages(ckv_pages, tables)
-            crg = attention.gather_pages(krope_pages, tables)
+    def mla_verify_attend(self, q_eff, q_rope, pages, layer, tables, pos, n_q,
+                          *, scale: float, ckv_scale=None, krope_scale=None):
+        ccg, crg = mla.gather_latent(pages, layer, tables, q_eff.shape[-1],
+                                     q_rope.shape[-1], ckv_scale,
+                                     krope_scale)
         valid = attention.verify_valid_mask(pos, n_q, q_eff.shape[1],
                                             ccg.shape[1])
         ctx = mla.mla_latent_verify_attend(q_eff, q_rope, ccg, crg, valid,
                                            scale=scale)
         return ctx.astype(q_eff.dtype)
 
-    def prefill_attend(self, q, k, v, k_pages, v_pages, tables, start, n_live,
-                       *, window: int = 0, softcap: float = 0.0,
-                       q_block: int = 512, unroll: bool = False,
-                       k_scale=None, v_scale=None):
+    def prefill_attend(self, q, k, v, k_pages, v_pages, layer, tables, start,
+                       n_live, *, kv_heads: int, window: int = 0,
+                       softcap: float = 0.0, q_block: int = 512,
+                       unroll: bool = False, k_scale=None, v_scale=None):
+        D = q.shape[-1]
+        kg = _gather_heads(k_pages, k_scale, layer, tables, kv_heads, D)
+        vg = _gather_heads(v_pages, v_scale, layer, tables, kv_heads, D)
         if window == 0:
-            if k_scale is not None:
-                kg = _gather_dequant(k_pages, k_scale, tables)
-                vg = _gather_dequant(v_pages, v_scale, tables)
-            else:
-                kg = attention.gather_pages(k_pages, tables)
-                vg = attention.gather_pages(v_pages, tables)
             o = attention.chunked_attention(
                 q, kg, vg, causal=True, q_block=q_block, softcap=softcap,
                 q_offset=start, unroll=unroll)
             return o.astype(q.dtype)
         if k_scale is not None:
-            kr = _gather_dequant(k_pages, k_scale, tables)
-            vr = _gather_dequant(v_pages, v_scale, tables)
             # the fresh chunk K/V stay unquantized; promote to fp32 so the
             # ring concat and the probability cast are fp32 end to end
             k, v = k.astype(jnp.float32), v.astype(jnp.float32)
-        else:
-            kr = attention.gather_pages(k_pages, tables)
-            vr = attention.gather_pages(v_pages, tables)
         o = attention.ring_chunk_attention(
-            q, k, v, kr, vr, start, n_live,
+            q, k, v, kg, vg, start, n_live,
             window=window, softcap=softcap, q_block=q_block, unroll=unroll)
         return o.astype(q.dtype)
 
-    def mla_prefill_attend(self, q, ckv_pages, krope_pages, wkv_b, tables,
-                           start, n_live, *, nope: int, scale: float = None,
+    def mla_prefill_attend(self, q, pages, layer, wkv_b, tables, start,
+                           n_live, *, nope: int, scale: float = None,
                            q_block: int = 512, unroll: bool = False,
                            ckv_scale=None, krope_scale=None):
         o = mla.mla_materialized_prefill_attend(
-            q, ckv_pages, krope_pages, wkv_b, tables, start, n_live,
+            q, pages, layer, wkv_b, tables, start, n_live,
             nope=nope, scale=scale, q_block=q_block, unroll=unroll,
             ckv_scale=ckv_scale, krope_scale=krope_scale)
         return o.astype(q.dtype)
@@ -421,52 +413,50 @@ class PallasBackend(ReferenceBackend):
 
     name = "pallas"
 
-    def decode_attend(self, q, k_pages, v_pages, tables, pos, *, scale: float,
-                      softcap: float = 0.0, window: int = 0,
-                      k_scale=None, v_scale=None):
-        return paged_attention_decode(q, k_pages, v_pages, tables, pos,
-                                      scale=scale, softcap=softcap,
-                                      window=window, k_scale=k_scale,
-                                      v_scale=v_scale)
+    def decode_attend(self, q, k_pages, v_pages, layer, tables, pos, *,
+                      kv_heads: int, scale: float, softcap: float = 0.0,
+                      window: int = 0, k_scale=None, v_scale=None):
+        return paged_attention_decode(q, k_pages, v_pages, layer, tables, pos,
+                                      kv_heads=kv_heads, scale=scale,
+                                      softcap=softcap, window=window,
+                                      k_scale=k_scale, v_scale=v_scale)
 
-    def mla_decode_attend(self, q_eff, q_rope, ckv_pages, krope_pages, tables,
-                          pos, *, scale: float, ckv_scale=None,
-                          krope_scale=None):
-        return mla_paged_attention_decode(q_eff, q_rope, ckv_pages,
-                                          krope_pages, tables, pos,
-                                          scale=scale, ckv_scale=ckv_scale,
+    def mla_decode_attend(self, q_eff, q_rope, pages, layer, tables, pos, *,
+                          scale: float, ckv_scale=None, krope_scale=None):
+        return mla_paged_attention_decode(q_eff, q_rope, pages, layer, tables,
+                                          pos, scale=scale,
+                                          ckv_scale=ckv_scale,
                                           krope_scale=krope_scale)
 
-    def verify_attend(self, q, k_pages, v_pages, tables, pos, n_q, *,
-                      scale: float, softcap: float = 0.0, window: int = 0,
-                      k_scale=None, v_scale=None):
-        return paged_attention_verify(q, k_pages, v_pages, tables, pos, n_q,
-                                      scale=scale, softcap=softcap,
-                                      window=window, k_scale=k_scale,
-                                      v_scale=v_scale)
+    def verify_attend(self, q, k_pages, v_pages, layer, tables, pos, n_q, *,
+                      kv_heads: int, scale: float, softcap: float = 0.0,
+                      window: int = 0, k_scale=None, v_scale=None):
+        return paged_attention_verify(q, k_pages, v_pages, layer, tables, pos,
+                                      n_q, kv_heads=kv_heads, scale=scale,
+                                      softcap=softcap, window=window,
+                                      k_scale=k_scale, v_scale=v_scale)
 
-    def mla_verify_attend(self, q_eff, q_rope, ckv_pages, krope_pages,
-                          tables, pos, n_q, *, scale: float, ckv_scale=None,
-                          krope_scale=None):
-        return mla_paged_attention_verify(q_eff, q_rope, ckv_pages,
-                                          krope_pages, tables, pos, n_q,
-                                          scale=scale, ckv_scale=ckv_scale,
+    def mla_verify_attend(self, q_eff, q_rope, pages, layer, tables, pos, n_q,
+                          *, scale: float, ckv_scale=None, krope_scale=None):
+        return mla_paged_attention_verify(q_eff, q_rope, pages, layer, tables,
+                                          pos, n_q, scale=scale,
+                                          ckv_scale=ckv_scale,
                                           krope_scale=krope_scale)
 
-    def prefill_attend(self, q, k, v, k_pages, v_pages, tables, start, n_live,
-                       *, window: int = 0, softcap: float = 0.0,
-                       q_block: int = 512, unroll: bool = False,
-                       k_scale=None, v_scale=None):
-        return ragged_prefill_attend(q, k, v, k_pages, v_pages, tables,
-                                     start, n_live, window=window,
-                                     softcap=softcap, k_scale=k_scale,
-                                     v_scale=v_scale)
+    def prefill_attend(self, q, k, v, k_pages, v_pages, layer, tables, start,
+                       n_live, *, kv_heads: int, window: int = 0,
+                       softcap: float = 0.0, q_block: int = 512,
+                       unroll: bool = False, k_scale=None, v_scale=None):
+        return ragged_prefill_attend(q, k, v, k_pages, v_pages, layer, tables,
+                                     start, n_live, kv_heads=kv_heads,
+                                     window=window, softcap=softcap,
+                                     k_scale=k_scale, v_scale=v_scale)
 
-    def mla_prefill_attend(self, q, ckv_pages, krope_pages, wkv_b, tables,
-                           start, n_live, *, nope: int, scale: float = None,
+    def mla_prefill_attend(self, q, pages, layer, wkv_b, tables, start,
+                           n_live, *, nope: int, scale: float = None,
                            q_block: int = 512, unroll: bool = False,
                            ckv_scale=None, krope_scale=None):
-        return mla_ragged_prefill_attend(q, ckv_pages, krope_pages, wkv_b,
-                                         tables, start, n_live, nope=nope,
+        return mla_ragged_prefill_attend(q, pages, layer, wkv_b, tables,
+                                         start, n_live, nope=nope,
                                          scale=scale, ckv_scale=ckv_scale,
                                          krope_scale=krope_scale)

@@ -21,7 +21,7 @@ from .cache_spec import CacheFamilySpec, CacheSpec
 from .layers import (apply_mlp, apply_norm, apply_rope, embed_defs, embed_tokens,
                      lm_logits, mlp_defs, norm_defs, rope_freqs)
 from .params import ParamDef, stack_tree
-from .transformer import _remat, _scan_blocks, _scan_blocks_emit
+from .transformer import _remat, _scan_blocks, _scan_blocks_emit, scan_layers
 
 ENC_LEN_DECODE = 4096   # encoder length assumed for standalone decode cells
 
@@ -268,23 +268,23 @@ class EncDecLM:
         freqs = rope_freqs(cfg, cfg.head_dim_)
         x = embed_tokens(params["embed"], tokens)
 
-        def body(x, pc):
-            p, (cself, ccross) = pc
+        def body(carry, pl_):
+            (x, kv), ((p, ccross), layer) = carry, pl_
             h = apply_norm(cfg, p["ln1"], x)
-            a, c2 = self.attn_backend.paged_decode(cfg, p["self_attn"], h,
-                                                   cself, meta, freqs)
+            a, kv = self.attn_backend.paged_decode(cfg, p["self_attn"], h,
+                                                   kv, layer, meta, freqs)
             x = x + a
             hx = apply_norm(cfg, p["ln_x"], x)
             x = x + self._cross_decode(p, hx, ccross["k"], ccross["v"])
             x = x + apply_mlp(cfg, p["mlp"], apply_norm(cfg, p["ln2"], x))
-            return x, (c2, ccross)
+            return (x, kv), None
 
-        x, (nself, ncross) = _scan_blocks(
-            body, x, params["dec_blocks"], (kv, state["cross"]),
-            unroll=cfg.unroll)
+        x, kv, _ = scan_layers(body, x, kv,
+                               (params["dec_blocks"], state["cross"]),
+                               unroll=cfg.unroll)
         x = apply_norm(cfg, params["final_norm"], x)
         logits = lm_logits(cfg, params["embed"], x)
-        return logits, nself, {"cross": ncross}
+        return logits, kv, state
 
     def prefill_paged(self, params, kv, state, meta, tokens, extras=None,
                       mesh=None, continuation: bool = False):
@@ -311,11 +311,11 @@ class EncDecLM:
         slots, n_tail = meta["slots"], meta["n_tail"]
         x = embed_tokens(params["embed"], tokens)
 
-        def body(x, pc):
-            p, cself = pc
+        def body(carry, pl_):
+            (x, kv), (p, layer) = carry, pl_
             h = apply_norm(cfg, p["ln1"], x)
-            a, c2 = self.attn_backend.paged_prefill(
-                cfg, p["self_attn"], h, cself, meta, freqs,
+            a, kv = self.attn_backend.paged_prefill(
+                cfg, p["self_attn"], h, kv, layer, meta, freqs,
                 q_block=cfg.attn_q_block, unroll=cfg.unroll)
             x = x + a
             hx = apply_norm(cfg, p["ln_x"], x)
@@ -327,21 +327,17 @@ class EncDecLM:
             if "bk" in p["cross_attn"]:
                 ck, cv = ck + p["cross_attn"]["bk"], cv + p["cross_attn"]["bv"]
             x = x + apply_mlp(cfg, p["mlp"], apply_norm(cfg, p["ln2"], x))
-            return x, (c2, {"k": ck, "v": cv})
+            return (x, kv), {"k": ck, "v": cv}
 
-        def f(carry, pc):
-            x = carry
-            x, out = body(x, pc)
-            return x, out
-        x, (nself, ncross) = jax.lax.scan(f, x, (params["dec_blocks"], kv),
-                                          unroll=cfg.unroll)
+        x, kv, ncross = scan_layers(body, x, kv, params["dec_blocks"],
+                                    unroll=cfg.unroll)
         new_state = jax.tree.map(
             lambda a, nw: a.at[:, slots].set(nw.astype(a.dtype), mode="drop"),
             state, {"cross": ncross})
         x = apply_norm(cfg, params["final_norm"], x)
         last = x[jnp.arange(B), n_tail - 1]
         logits = lm_logits(cfg, params["embed"], last)
-        return logits, nself, new_state
+        return logits, kv, new_state
 
     def _prefill_paged_continue(self, params, kv, state, meta, tokens,
                                 mesh=None):
@@ -358,11 +354,11 @@ class EncDecLM:
         cv = state["cross"]["v"][:, rows]
         x = embed_tokens(params["embed"], tokens)
 
-        def body(x, pc):
-            p, cself, ckl, cvl = pc
+        def body(carry, pl_):
+            (x, kv), ((p, ckl, cvl), layer) = carry, pl_
             h = apply_norm(cfg, p["ln1"], x)
-            a, c2 = self.attn_backend.paged_prefill(
-                cfg, p["self_attn"], h, cself, meta, freqs,
+            a, kv = self.attn_backend.paged_prefill(
+                cfg, p["self_attn"], h, kv, layer, meta, freqs,
                 q_block=cfg.attn_q_block, unroll=cfg.unroll)
             x = x + a
             hx = apply_norm(cfg, p["ln_x"], x)
@@ -374,15 +370,11 @@ class EncDecLM:
                                   q_block=cfg.attn_q_block, unroll=cfg.unroll)
             x = x + jnp.einsum("bshe,hed->bsd", o, p["cross_attn"]["wo"])
             x = x + apply_mlp(cfg, p["mlp"], apply_norm(cfg, p["ln2"], x))
-            return x, c2
+            return (x, kv), None
 
-        def f(carry, pc):
-            x = carry
-            x, c2 = body(x, pc)
-            return x, c2
-        x, nself = jax.lax.scan(f, x, (params["dec_blocks"], kv, ck, cv),
-                                unroll=cfg.unroll)
+        x, kv, _ = scan_layers(body, x, kv, (params["dec_blocks"], ck, cv),
+                               unroll=cfg.unroll)
         x = apply_norm(cfg, params["final_norm"], x)
         last = x[jnp.arange(B), n_tail - 1]
         logits = lm_logits(cfg, params["embed"], last)
-        return logits, nself, state
+        return logits, kv, state

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
 from .attention import (NEG_INF, chunked_attention, dequant_int8,
-                        gather_pages, quantize_int8)
+                        gather_pages, page_width, quantize_int8, write_slots)
 from .layers import apply_rope, rmsnorm, softmax_scale
 from .params import ParamDef
 
@@ -99,20 +99,19 @@ def mla_cache_defs(cfg: ArchConfig, batch: int, max_len: int):
 def mla_paged_cache_defs(cfg: ArchConfig, num_pages: int, page_size: int,
                          kv_dtype: str = "bf16"):
     """One layer's share of the paged latent pool: the absorbed cache payload
-    (rank-``kv_lora`` latent + roped rope-head key) per token slot.
+    per token slot in one lane-dense ``latent`` row — the rank-``kv_lora``
+    latent ``ckv`` at lanes [0, kv_lora), the roped rope-head key ``krope``
+    after it, zeros up to ``page_width(kv_lora + rope)`` (``latent_slots``).
 
     ``kv_dtype == "int8"`` quantizes both payloads per token slot (the
-    latent has one shared "kv head", so the scale leaves are [P, page_size]
-    bf16), sharing the page axis exactly as the vanilla KV defs do."""
-    payload_dt = jnp.int8 if kv_dtype == "int8" else jnp.bfloat16
-    defs = {
-        "ckv": ParamDef((num_pages, page_size, cfg.kv_lora_rank),
-                        (None, "seq", "lora"), dtype=payload_dt,
-                        init="zeros"),
-        "krope": ParamDef((num_pages, page_size, cfg.rope_head_dim),
-                          (None, "seq", None), dtype=payload_dt,
-                          init="zeros"),
-    }
+    latent has one shared "kv head", so the scale leaves ``ckv_scale`` /
+    ``krope_scale`` are [P, page_size] bf16), sharing the page axis exactly
+    as the vanilla KV defs do."""
+    width = page_width(cfg.kv_lora_rank + cfg.rope_head_dim)
+    defs = {"latent": ParamDef(
+        (num_pages, page_size, width), (None, "seq", None),
+        dtype=jnp.int8 if kv_dtype == "int8" else jnp.bfloat16,
+        init="zeros")}
     if kv_dtype == "int8":
         defs["ckv_scale"] = ParamDef((num_pages, page_size), (None, "seq"),
                                      dtype=jnp.bfloat16, init="zeros")
@@ -121,9 +120,38 @@ def mla_paged_cache_defs(cfg: ArchConfig, num_pages: int, page_size: int,
     return defs
 
 
-def mla_paged_prefill_block(cfg: ArchConfig, p, x, cache, meta, freqs,
+def latent_slots(cache, ckv, krope):
+    """The new token slots of latent ``ckv`` [..., L] and roped ``krope``
+    [..., R]: one lane-dense row each, quantized with their scale leaves
+    when the pool is int8."""
+    scales = {}
+    if "ckv_scale" in cache:
+        ckv, cs = quantize_int8(ckv)
+        krope, rs = quantize_int8(krope)
+        scales = {"ckv_scale": cs, "krope_scale": rs}
+    dt = cache["latent"].dtype
+    row = jnp.concatenate([ckv.astype(dt), krope.astype(dt)], -1)
+    pad = cache["latent"].shape[-1] - row.shape[-1]
+    row = jnp.pad(row, [(0, 0)] * (row.ndim - 1) + [(0, pad)])
+    return {"latent": row, **scales}
+
+
+def latent_view(slots, L: int, R: int):
+    """Lane-dense latent slots [..., W] as (ckv [..., L], krope [..., R])."""
+    return slots[..., :L], slots[..., L:L + R]
+
+
+def latent_scales(cache):
+    """The scale leaves a latent attend core takes ({} for a bf16 pool)."""
+    return ({"ckv_scale": cache["ckv_scale"],
+             "krope_scale": cache["krope_scale"]}
+            if "ckv_scale" in cache else {})
+
+
+def mla_paged_prefill_block(cfg: ArchConfig, p, x, cache, layer, meta, freqs,
                             backend, *, q_block=512, unroll=False):
-    """Multi-token MLA chunk prefill, straight into the latent pages.
+    """Multi-token MLA chunk prefill, straight into layer ``layer`` of the
+    latent pages.
 
     Mirrors ``paged_prefill_attention_block``: the chunk's latent is written
     token-granularly through the page table (``meta`` carries the
@@ -145,45 +173,44 @@ def mla_paged_prefill_block(cfg: ArchConfig, p, x, cache, meta, freqs,
     ckv = latent_norm(cfg, p, ckv_full)
     krope = apply_rope(ckv_full[..., cfg.kv_lora_rank:][:, :, None, :],
                        positions, freqs)[:, :, 0, :]
-
-    wp, wo_ = meta["write_page"], meta["write_off"]
-    scales = {}
-    if "ckv_scale" in cache:
-        ckv, cs = quantize_int8(ckv)
-        krope, rs = quantize_int8(krope)
-        scales = {"ckv_scale": cache["ckv_scale"].at[wp, wo_].set(cs),
-                  "krope_scale": cache["krope_scale"].at[wp, wo_].set(rs)}
-    cc = cache["ckv"].at[wp, wo_].set(ckv.astype(cache["ckv"].dtype))
-    cr = cache["krope"].at[wp, wo_].set(krope.astype(cache["krope"].dtype))
+    cache = write_slots(cache, layer, meta, latent_slots(cache, ckv, krope))
 
     qq = jnp.concatenate([q_nope, q_rope], -1)
-    o = backend.mla_prefill_attend(qq, cc, cr, p["wkv_b"], tables, start,
-                                   n_live, nope=nope, scale=mla_scale(cfg),
-                                   q_block=q_block, unroll=unroll, **scales)
-    new_cache = {"ckv": cc, "krope": cr}
-    new_cache.update(scales)
-    return jnp.einsum("bshe,hed->bsd", o, p["wo"]), new_cache
+    o = backend.mla_prefill_attend(qq, cache["latent"], layer, p["wkv_b"],
+                                   tables, start, n_live, nope=nope,
+                                   scale=mla_scale(cfg), q_block=q_block,
+                                   unroll=unroll, **latent_scales(cache))
+    return jnp.einsum("bshe,hed->bsd", o, p["wo"]), cache
 
 
-def mla_materialized_prefill_attend(q, ckv_pages, krope_pages, wkv_b, tables,
-                                    start, n_live, *, nope: int,
+def gather_latent(pages, layer, tables, L: int, R: int, ckv_scale=None,
+                  krope_scale=None):
+    """The logical (ckv [B, S, L], krope [B, S, R]) view of one layer of the
+    latent pool, dequantized to fp32 through the gathered scale pages when
+    the pool is int8."""
+    ccg, crg = latent_view(gather_pages(pages, layer, tables), L, R)
+    if ckv_scale is not None:
+        ccg = dequant_int8(ccg, gather_pages(ckv_scale, layer, tables))
+        crg = dequant_int8(crg, gather_pages(krope_scale, layer, tables))
+    return ccg, crg
+
+
+def mla_materialized_prefill_attend(q, pages, layer, wkv_b, tables, start,
+                                    n_live, *, nope: int,
                                     scale: float = None, q_block: int = 512,
                                     unroll: bool = False, ckv_scale=None,
                                     krope_scale=None):
-    """The reference MLA prefill attend: gather the (post-write) latent
-    pages, materialize per-head K/V from them with ``wkv_b`` exactly as
-    ``mla_full_block`` does — so a cached prefix or an earlier chunk is read
-    as if this call had prefilled it itself — and run the chunked XLA
-    attend.  q: [B, T, H, nope+rope] (rope part already roped).  int8 pages
-    arrive with their per-token-slot scale pools (``ckv_scale`` /
-    ``krope_scale``) and are dequantized to fp32 after the gather.  Returns
-    the attended values [B, T, H, v_head_dim]."""
+    """The reference MLA prefill attend: gather layer ``layer`` of the
+    (post-write) latent pages, materialize per-head K/V from them with
+    ``wkv_b`` exactly as ``mla_full_block`` does — so a cached prefix or an
+    earlier chunk is read as if this call had prefilled it itself — and run
+    the chunked XLA attend.  q: [B, T, H, nope+rope] (rope part already
+    roped).  int8 pages arrive with their per-token-slot scale pools
+    (``ckv_scale`` / ``krope_scale``) and are dequantized to fp32 after the
+    gather.  Returns the attended values [B, T, H, v_head_dim]."""
     rope_d = q.shape[-1] - nope
-    ccg = gather_pages(ckv_pages, tables)
-    crg = gather_pages(krope_pages, tables)
-    if ckv_scale is not None:
-        ccg = dequant_int8(ccg, gather_pages(ckv_scale, tables))
-        crg = dequant_int8(crg, gather_pages(krope_scale, tables))
+    ccg, crg = gather_latent(pages, layer, tables, wkv_b.shape[0], rope_d,
+                             ckv_scale, krope_scale)
     kv = jnp.einsum("bsl,lhe->bshe", ccg, wkv_b)
     k_nope, v = kv[..., :nope], kv[..., nope:]
     k = jnp.concatenate(
@@ -193,17 +220,15 @@ def mla_materialized_prefill_attend(q, ckv_pages, krope_pages, wkv_b, tables,
                              q_offset=start, scale=scale, unroll=unroll)
 
 
-def mla_paged_decode_block(cfg: ArchConfig, p, x, cache, meta, freqs,
+def mla_paged_decode_block(cfg: ArchConfig, p, x, cache, layer, meta, freqs,
                            backend):
-    """Absorbed one-token decode against the latent pages (the paged twin of
-    ``mla_decode_block``).  ``meta`` is the flat per-step metadata from
-    ``attn_backend.decode_meta``; the latent-space attend (scores against
-    ckv/krope pages, context in rank-``kv_lora`` space) is delegated to
-    ``backend.mla_decode_attend``."""
-    B = x.shape[0]
+    """Absorbed one-token decode against layer ``layer`` of the latent pages
+    (the paged twin of ``mla_decode_block``).  ``meta`` is the flat per-step
+    metadata from ``attn_backend.decode_meta``; the latent-space attend
+    (scores against the ckv/krope lanes, context in rank-``kv_lora`` space)
+    is delegated to ``backend.mla_decode_attend``."""
     nope = cfg.nope_head_dim
     pos = meta["pos"]
-    scale = mla_scale(cfg)
 
     q = _queries(cfg, p, x[:, None, :])[:, 0]                      # [B,H,·]
     q_nope, q_rope = q[..., :nope], q[..., nope:]
@@ -213,27 +238,17 @@ def mla_paged_decode_block(cfg: ArchConfig, p, x, cache, meta, freqs,
     ckv_new = latent_norm(cfg, p, ckv_full)
     kr_new = apply_rope(ckv_full[..., None, cfg.kv_lora_rank:][:, None],
                         pos[:, None], freqs)[:, 0, 0]
-
-    wp, wo_ = meta["write_page"], meta["write_off"]
-    scales = {}
-    if "ckv_scale" in cache:
-        ckv_new, cs = quantize_int8(ckv_new)
-        kr_new, rs = quantize_int8(kr_new)
-        scales = {"ckv_scale": cache["ckv_scale"].at[wp, wo_].set(cs),
-                  "krope_scale": cache["krope_scale"].at[wp, wo_].set(rs)}
-    cc = cache["ckv"].at[wp, wo_].set(ckv_new.astype(cache["ckv"].dtype))
-    cr = cache["krope"].at[wp, wo_].set(kr_new.astype(cache["krope"].dtype))
+    cache = write_slots(cache, layer, meta,
+                        latent_slots(cache, ckv_new, kr_new))
 
     w_uk = p["wkv_b"][..., :nope]                                  # [L,H,nope]
     q_eff = jnp.einsum("bhn,lhn->bhl", q_nope, w_uk)
-    ctx = backend.mla_decode_attend(q_eff, q_rope, cc, cr, meta["tables"],
-                                    pos, scale=scale, **scales)
+    ctx = backend.mla_decode_attend(q_eff, q_rope, cache["latent"], layer,
+                                    meta["tables"], pos, scale=mla_scale(cfg),
+                                    **latent_scales(cache))
     w_uv = p["wkv_b"][..., nope:]                                  # [L, H, v]
     o = jnp.einsum("bhl,lhv->bhv", ctx, w_uv)
-    out = jnp.einsum("bhv,hvd->bd", o, p["wo"])
-    new_cache = {"ckv": cc, "krope": cr}
-    new_cache.update(scales)
-    return out, new_cache
+    return jnp.einsum("bhv,hvd->bd", o, p["wo"]), cache
 
 
 def mla_latent_attend(q_eff, q_rope, cc, cr, valid, *, scale: float):
@@ -279,19 +294,18 @@ def mla_latent_verify_attend(q_eff, q_rope, cc, cr, valid, *, scale: float):
     return ctx.astype(cc.dtype)
 
 
-def mla_paged_verify_block(cfg: ArchConfig, p, x, cache, meta, freqs,
+def mla_paged_verify_block(cfg: ArchConfig, p, x, cache, layer, meta, freqs,
                            backend):
-    """Small-q speculative verify against the latent pages (the verify twin
-    of ``mla_paged_decode_block``).  x: [B, Q, d] — last emitted token plus
-    draft, padded to Q; ``meta`` from ``attn_backend.verify_meta``.
-    Write-all-then-attend: every query's latent scatters first (dead rows to
-    the null page), then the absorbed attend masks per query — see
-    ``attention.paged_verify_attention_block`` for the rollback contract.
-    Returns (out [B, Q, d], new_cache)."""
+    """Small-q speculative verify against layer ``layer`` of the latent pages
+    (the verify twin of ``mla_paged_decode_block``).  x: [B, Q, d] — last
+    emitted token plus draft, padded to Q; ``meta`` from
+    ``attn_backend.verify_meta``.  Write-all-then-attend: every query's
+    latent scatters first (dead rows to the null page), then the absorbed
+    attend masks per query — see ``attention.paged_verify_attention_block``
+    for the rollback contract.  Returns (out [B, Q, d], new_cache)."""
     Q = x.shape[1]
     nope = cfg.nope_head_dim
     pos = meta["pos"]
-    scale = mla_scale(cfg)
     positions = pos[:, None] + jnp.arange(Q)[None, :]              # [B, Q]
 
     q = _queries(cfg, p, x)                                        # [B,Q,H,·]
@@ -302,27 +316,18 @@ def mla_paged_verify_block(cfg: ArchConfig, p, x, cache, meta, freqs,
     ckv_new = latent_norm(cfg, p, ckv_full)
     kr_new = apply_rope(ckv_full[..., cfg.kv_lora_rank:][:, :, None, :],
                         positions, freqs)[:, :, 0, :]
-
-    wp, wo_ = meta["write_page"], meta["write_off"]
-    scales = {}
-    if "ckv_scale" in cache:
-        ckv_new, cs = quantize_int8(ckv_new)
-        kr_new, rs = quantize_int8(kr_new)
-        scales = {"ckv_scale": cache["ckv_scale"].at[wp, wo_].set(cs),
-                  "krope_scale": cache["krope_scale"].at[wp, wo_].set(rs)}
-    cc = cache["ckv"].at[wp, wo_].set(ckv_new.astype(cache["ckv"].dtype))
-    cr = cache["krope"].at[wp, wo_].set(kr_new.astype(cache["krope"].dtype))
+    cache = write_slots(cache, layer, meta,
+                        latent_slots(cache, ckv_new, kr_new))
 
     w_uk = p["wkv_b"][..., :nope]                                  # [L,H,nope]
     q_eff = jnp.einsum("bqhn,lhn->bqhl", q_nope, w_uk)
-    ctx = backend.mla_verify_attend(q_eff, q_rope, cc, cr, meta["tables"],
-                                    pos, meta["n_q"], scale=scale, **scales)
+    ctx = backend.mla_verify_attend(q_eff, q_rope, cache["latent"], layer,
+                                    meta["tables"], pos, meta["n_q"],
+                                    scale=mla_scale(cfg),
+                                    **latent_scales(cache))
     w_uv = p["wkv_b"][..., nope:]                                  # [L, H, v]
     o = jnp.einsum("bqhl,lhv->bqhv", ctx, w_uv)
-    out = jnp.einsum("bqhv,hvd->bqd", o, p["wo"])
-    new_cache = {"ckv": cc, "krope": cr}
-    new_cache.update(scales)
-    return out, new_cache
+    return jnp.einsum("bqhv,hvd->bqd", o, p["wo"]), cache
 
 
 def mla_decode_block(cfg: ArchConfig, p, x, cache, pos, freqs):

@@ -574,66 +574,62 @@ class DecoderLM:
 
     # ----- paged attention dispatch (everything routes via the backend) -----
 
-    def _paged_attn_decode(self, p, h, c, meta, freqs):
-        return self.attn_backend.paged_decode(self.cfg, p["attn"], h, c, meta,
-                                              freqs)
+    def _paged_attn_decode(self, p, h, kv, layer, meta, freqs):
+        return self.attn_backend.paged_decode(self.cfg, p["attn"], h, kv,
+                                              layer, meta, freqs)
 
-    def _paged_attn_verify(self, p, h, c, meta, freqs):
-        return self.attn_backend.paged_verify(self.cfg, p["attn"], h, c, meta,
-                                              freqs)
+    def _paged_attn_verify(self, p, h, kv, layer, meta, freqs):
+        return self.attn_backend.paged_verify(self.cfg, p["attn"], h, kv,
+                                              layer, meta, freqs)
 
-    def _paged_attn_prefill(self, p, h, c, meta, freqs):
+    def _paged_attn_prefill(self, p, h, kv, layer, meta, freqs):
         cfg = self.cfg
         return self.attn_backend.paged_prefill(
-            cfg, p["attn"], h, c, meta, freqs,
+            cfg, p["attn"], h, kv, layer, meta, freqs,
             q_block=cfg.attn_q_block, unroll=cfg.unroll)
 
     def _paged_layers(self, params, kv, x, attend, live):
-        """The layer stack of a paged serving step.  ``attend(p, h, c)`` is
-        the step's paged attention (returns the output and the layer's new
-        pool); MoE layers run the dropless expert-share layer
-        (``moe.moe_serve_apply``) over the tokens ``live`` marks ([*x.shape
-        [:-1]] bool).  Returns (x, new_kv, stats): ``stats`` [n_moe_layers,
-        2] int32 holds each MoE layer's held pairs and held experts touched
-        (None for a dense model)."""
+        """The layer stack of a paged serving step.  The layer-stacked pool
+        ``kv`` is carried through the layer loop: ``attend(p, h, kv, l)`` is
+        the step's paged attention of layer ``l``, which writes its token
+        slots into the pool in place and returns (output, pool).  MoE layers
+        run the dropless expert-share layer (``moe.moe_serve_apply``) over
+        the tokens ``live`` marks ([*x.shape[:-1]] bool).  Returns (x,
+        new_kv, stats): ``stats`` [n_moe_layers, 2] int32 holds each MoE
+        layer's held pairs and held experts touched (None for a dense
+        model)."""
         cfg = self.cfg
 
-        def dense_step(x, pc):
-            p, c = pc
+        def dense_step(carry, pl_):
+            (x, kv), (p, layer) = carry, pl_
             h = apply_norm(cfg, p["ln1"], x)
-            a, c2 = attend(p, h, c)
+            a, kv = attend(p, h, kv, layer)
             x = x + a
             x = x + apply_mlp(cfg, p["mlp"], apply_norm(cfg, p["ln2"], x))
-            return x, c2
+            return (x, kv), None
 
-        def moe_step(x, pc):
-            p, c = pc
+        def moe_step(carry, pl_):
+            (x, kv), (p, layer) = carry, pl_
             h = apply_norm(cfg, p["ln1"], x)
-            a, c2 = attend(p, h, c)
+            a, kv = attend(p, h, kv, layer)
             x = x + a
             h = apply_norm(cfg, p["ln2"], x)
             m, held, touched = moe_serve_apply(
                 cfg, p["moe"], h.reshape(-1, h.shape[-1]), live.reshape(-1))
-            return x + m.reshape(x.shape), (c2, jnp.stack([held, touched]))
+            return (x + m.reshape(x.shape), kv), jnp.stack([held, touched])
 
         if not cfg.is_moe:
-            x, new_kv = _scan_blocks(dense_step, x, params["blocks"], kv,
-                                     unroll=cfg.unroll)
-            return x, new_kv, None
+            x, kv, _ = scan_layers(dense_step, x, kv, params["blocks"],
+                                   unroll=cfg.unroll)
+            return x, kv, None
+        # the dense lead-in layers are the pool's first layers
         k = cfg.first_k_dense
-        if not k:
-            x, (new_kv, stats) = _scan_blocks(moe_step, x, params["blocks"],
-                                              kv, unroll=cfg.unroll)
-            return x, new_kv, stats
-        head = jax.tree.map(lambda a: a[:k], kv)
-        tail = jax.tree.map(lambda a: a[k:], kv)
-        x, nhead = _scan_blocks(dense_step, x, params["dense_blocks"], head,
-                                unroll=cfg.unroll)
-        x, (ntail, stats) = _scan_blocks(moe_step, x, params["blocks"], tail,
-                                         unroll=cfg.unroll)
-        new_kv = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), nhead,
-                              ntail)
-        return x, new_kv, stats
+        if k:
+            x, kv, _ = scan_layers(dense_step, x, kv, params["dense_blocks"],
+                                   unroll=cfg.unroll)
+        x, kv, stats = scan_layers(moe_step, x, kv, params["blocks"], first=k,
+                                   unroll=cfg.unroll)
+        return x, kv, stats
 
     def decode_paged(self, params, kv, state, meta, tokens, mesh=None,
                      moe_stats: bool = False):
@@ -662,7 +658,8 @@ class DecoderLM:
         freqs = self._freqs()
         x, new_kv, stats = self._paged_layers(
             params, kv, x,
-            lambda p, h, c: self._paged_attn_decode(p, h, c, meta, freqs),
+            lambda p, h, c, l: self._paged_attn_decode(p, h, c, l, meta,
+                                                       freqs),
             live=meta["tables"][:, 0] != NULL_PAGE)
         x = apply_norm(cfg, params["final_norm"], x)
         logits = lm_logits(cfg, params["embed"], x)
@@ -694,7 +691,8 @@ class DecoderLM:
             & (jnp.arange(Q)[None, :] < meta["n_q"][:, None])
         x, new_kv, _ = self._paged_layers(
             params, kv, x,
-            lambda p, h, c: self._paged_attn_verify(p, h, c, meta, freqs),
+            lambda p, h, c, l: self._paged_attn_verify(p, h, c, l, meta,
+                                                       freqs),
             live)
         x = apply_norm(cfg, params["final_norm"], x)
         logits = lm_logits(cfg, params["embed"], x)
@@ -744,7 +742,8 @@ class DecoderLM:
         B, T = x.shape[0], x.shape[1]
         x, new_kv, stats = self._paged_layers(
             params, kv, x,
-            lambda p, h, c: self._paged_attn_prefill(p, h, c, meta, freqs),
+            lambda p, h, c, l: self._paged_attn_prefill(p, h, c, l, meta,
+                                                        freqs),
             live=jnp.arange(T)[None, :] < n_live[:, None])
         x = apply_norm(cfg, params["final_norm"], x)
         last = x[jnp.arange(B), n_live - 1]
@@ -900,6 +899,18 @@ def _scan_blocks(body, x, stacked_params, stacked_cache, unroll=False):
         return x, c
     x, cs = jax.lax.scan(f, x, (stacked_params, stacked_cache), unroll=unroll)
     return x, cs
+
+
+def scan_layers(body, x, kv, stacked_params, first=0, unroll=False):
+    """scan a paged step's layers with the layer-stacked pool ``kv`` in the
+    carry: ``body((x, kv), (params_i, layer))`` runs stacked layer ``i`` as
+    pool layer ``first + i`` and returns ((x, kv), ys).  The pool stays one
+    buffer that each layer updates in place.  Returns (x, kv, ys)."""
+    n = jax.tree.leaves(stacked_params)[0].shape[0]
+    layers = first + jnp.arange(n, dtype=jnp.int32)
+    (x, kv), ys = jax.lax.scan(body, (x, kv), (stacked_params, layers),
+                               unroll=unroll)
+    return x, kv, ys
 
 
 def _scan_blocks_emit(body, x, stacked_params, unroll=False):

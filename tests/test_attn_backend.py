@@ -29,13 +29,16 @@ from repro.models.attention import quantize_int8
 from repro.models.attn_backend import (available_backends, decode_meta,
                                        get_backend, resolve_backend)
 
+from pool_layout import LAYER, gqa_pool, latent_pool, stack_layer
+
 jax.config.update("jax_platform_name", "cpu")
 
 
 def _pool(rng, P, ps, K, D, dtype):
+    """K and V pools of per-head values drawn [P, ps, K, D], stacked."""
     k = jnp.asarray(rng.randn(P, ps, K, D), dtype)
     v = jnp.asarray(rng.randn(P, ps, K, D), dtype)
-    return k, v
+    return gqa_pool(k), gqa_pool(v)
 
 
 def _tables(rng, B, maxp, P):
@@ -76,7 +79,8 @@ def test_decode_attend_matches_reference(B, H, K, D, ps, maxp, window, dtype):
                                            jnp.float32))
         vp, vs = quantize_int8(jnp.asarray(rng.randn(P, ps, K, D),
                                            jnp.float32))
-        scales = dict(k_scale=ks, v_scale=vs)
+        kp, vp = gqa_pool(kp), gqa_pool(vp)
+        scales = dict(k_scale=stack_layer(ks), v_scale=stack_layer(vs))
     else:
         q = jnp.asarray(rng.randn(B, H, D), dtype)
         kp, vp = _pool(rng, P, ps, K, D, dtype)
@@ -89,9 +93,11 @@ def test_decode_attend_matches_reference(B, H, K, D, ps, maxp, window, dtype):
     pos = jnp.asarray(pos, jnp.int32)
     scale = 1.0 / math.sqrt(D)
     ref = get_backend("reference").decode_attend(
-        q, kp, vp, tables, pos, scale=scale, window=window, **scales)
+        q, kp, vp, LAYER, tables, pos, kv_heads=K, scale=scale,
+        window=window, **scales)
     out = get_backend("pallas").decode_attend(
-        q, kp, vp, tables, pos, scale=scale, window=window, **scales)
+        q, kp, vp, LAYER, tables, pos, kv_heads=K, scale=scale,
+        window=window, **scales)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol, rtol=tol)
@@ -136,9 +142,11 @@ def test_decode_attend_softcap():
     tables = _tables(rng, B, maxp, 16)
     pos = jnp.asarray([7, 29], jnp.int32)
     ref = get_backend("reference").decode_attend(
-        q, kp, vp, tables, pos, scale=1 / math.sqrt(D), softcap=30.0)
+        q, kp, vp, LAYER, tables, pos, kv_heads=K, scale=1 / math.sqrt(D),
+        softcap=30.0)
     out = get_backend("pallas").decode_attend(
-        q, kp, vp, tables, pos, scale=1 / math.sqrt(D), softcap=30.0)
+        q, kp, vp, LAYER, tables, pos, kv_heads=K, scale=1 / math.sqrt(D),
+        softcap=30.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -167,7 +175,7 @@ def test_mla_decode_attend_matches_reference(B, H, L, R, ps, maxp, dtype):
         q_rope = jnp.asarray(rng.randn(B, H, R), jnp.float32)
         cc, cs = quantize_int8(jnp.asarray(rng.randn(P, ps, L), jnp.float32))
         cr, rs = quantize_int8(jnp.asarray(rng.randn(P, ps, R), jnp.float32))
-        scales = dict(ckv_scale=cs, krope_scale=rs)
+        scales = dict(ckv_scale=stack_layer(cs), krope_scale=stack_layer(rs))
     else:
         q_eff = jnp.asarray(rng.randn(B, H, L), dtype)
         q_rope = jnp.asarray(rng.randn(B, H, R), dtype)
@@ -180,10 +188,11 @@ def test_mla_decode_attend_matches_reference(B, H, L, R, ps, maxp, dtype):
         tables, pos[-1] = tables.at[-1].set(0), 0
     pos = jnp.asarray(pos, jnp.int32)
     scale = 1.0 / math.sqrt(L + R)
+    pages = latent_pool(cc, cr)
     ref = get_backend("reference").mla_decode_attend(
-        q_eff, q_rope, cc, cr, tables, pos, scale=scale, **scales)
+        q_eff, q_rope, pages, LAYER, tables, pos, scale=scale, **scales)
     out = get_backend("pallas").mla_decode_attend(
-        q_eff, q_rope, cc, cr, tables, pos, scale=scale, **scales)
+        q_eff, q_rope, pages, LAYER, tables, pos, scale=scale, **scales)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol, rtol=tol)

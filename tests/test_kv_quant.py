@@ -38,6 +38,8 @@ from repro.models.attention import dequant_int8, quantize_int8
 from repro.models.attn_backend import get_backend
 from repro.serving import Engine, PagedKVPool, dual_gate_verify
 
+from pool_layout import LAYER, gqa_pool, latent_pool, stack_layer
+
 jax.config.update("jax_platform_name", "cpu")
 
 try:
@@ -150,11 +152,14 @@ def _tables(rng, B, maxp, P):
 
 
 def _quant_pool(rng, P, ps, K, D):
+    """int8 K/V of per-head values drawn [P, ps, K, D] with their scales,
+    as (per-head payloads and scales, the stacked pool leaves)."""
     kf = rng.randn(P, ps, K, D).astype(np.float32)
     vf = rng.randn(P, ps, K, D).astype(np.float32)
     kq, ks = quantize_int8(jnp.asarray(kf))
     vq, vs = quantize_int8(jnp.asarray(vf))
-    return kq, ks, vq, vs
+    pools = (gqa_pool(kq), stack_layer(ks), gqa_pool(vq), stack_layer(vs))
+    return (kq, ks, vq, vs), pools
 
 
 DECODE_CASES = [
@@ -169,26 +174,28 @@ DECODE_CASES = [
 def test_int8_decode_attend_matches_reference(B, H, K, D, ps, maxp, window):
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(B, H, D), jnp.float32)
-    kq, ks, vq, vs = _quant_pool(rng, 4 * maxp, ps, K, D)
+    (kq, ks, vq, vs), (kp, ksp, vp, vsp) = _quant_pool(rng, 4 * maxp, ps, K,
+                                                        D)
     tables = _tables(rng, B, maxp, 4 * maxp)
     pos = jnp.asarray(np.concatenate(
         [[0], rng.randint(1, maxp * ps, size=B - 1)]), jnp.int32)
     scale = 1.0 / math.sqrt(D)
     ref = get_backend("reference").decode_attend(
-        q, kq, vq, tables, pos, scale=scale, window=window,
-        k_scale=ks, v_scale=vs)
+        q, kp, vp, LAYER, tables, pos, kv_heads=K, scale=scale,
+        window=window, k_scale=ksp, v_scale=vsp)
     out = get_backend("pallas").decode_attend(
-        q, kq, vq, tables, pos, scale=scale, window=window,
-        k_scale=ks, v_scale=vs)
+        q, kp, vp, LAYER, tables, pos, kv_heads=K, scale=scale,
+        window=window, k_scale=ksp, v_scale=vsp)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=2e-5, rtol=2e-5)
     # the reference dequant path must equal attending a pre-dequantized
     # fp32 pool — the scale gather can hide no rounding of its own
-    kf = jnp.asarray(dequant_int8(kq, ks))
-    vf = jnp.asarray(dequant_int8(vq, vs))
+    kf = gqa_pool(dequant_int8(kq, ks))
+    vf = gqa_pool(dequant_int8(vq, vs))
     oracle = get_backend("reference").decode_attend(
-        q, kf, vf, tables, pos, scale=scale, window=window)
+        q, kf, vf, LAYER, tables, pos, kv_heads=K, scale=scale,
+        window=window)
     np.testing.assert_allclose(np.asarray(ref, np.float32),
                                np.asarray(oracle, np.float32),
                                atol=1e-6, rtol=1e-6)
@@ -206,12 +213,12 @@ def test_int8_mla_decode_attend_matches_reference():
     pos = jnp.asarray(np.concatenate(
         [[0], rng.randint(1, maxp * ps, size=B - 1)]), jnp.int32)
     scale = 1.0 / math.sqrt(L + R)
+    pages, scales = latent_pool(cq, rq), dict(ckv_scale=stack_layer(cs),
+                                              krope_scale=stack_layer(rs))
     ref = get_backend("reference").mla_decode_attend(
-        q_eff, q_rope, cq, rq, tables, pos, scale=scale,
-        ckv_scale=cs, krope_scale=rs)
+        q_eff, q_rope, pages, LAYER, tables, pos, scale=scale, **scales)
     out = get_backend("pallas").mla_decode_attend(
-        q_eff, q_rope, cq, rq, tables, pos, scale=scale,
-        ckv_scale=cs, krope_scale=rs)
+        q_eff, q_rope, pages, LAYER, tables, pos, scale=scale, **scales)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=2e-5, rtol=2e-5)
@@ -223,16 +230,16 @@ def test_int8_prefill_attend_matches_reference():
     rng = np.random.RandomState(2)
     B, H, K, D, ps, maxp, T = 2, 4, 2, 32, 8, 5, 16
     q = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
-    kq, ks, vq, vs = _quant_pool(rng, 4 * maxp, ps, K, D)
+    _, (kp, ksp, vp, vsp) = _quant_pool(rng, 4 * maxp, ps, K, D)
     tables = _tables(rng, B, maxp, 4 * maxp)
     start = jnp.asarray([0, ps + 1], jnp.int32)
     n_live = jnp.asarray([T, T - 3], jnp.int32)
     ref = get_backend("reference").prefill_attend(
-        q, None, None, kq, vq, tables, start, n_live,
-        k_scale=ks, v_scale=vs)
+        q, None, None, kp, vp, LAYER, tables, start, n_live, kv_heads=K,
+        k_scale=ksp, v_scale=vsp)
     out = get_backend("pallas").prefill_attend(
-        q, q[:, :, :K], q[:, :, :K], kq, vq, tables, start, n_live,
-        k_scale=ks, v_scale=vs)
+        q, q[:, :, :K], q[:, :, :K], kp, vp, LAYER, tables, start, n_live,
+        kv_heads=K, k_scale=ksp, v_scale=vsp)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=2e-5, rtol=2e-5)
@@ -246,16 +253,16 @@ def test_int8_windowed_prefill_attend_matches_reference():
     q = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
     kn = jnp.asarray(rng.randn(B, T, K, D), jnp.float32)
     vn = jnp.asarray(rng.randn(B, T, K, D), jnp.float32)
-    kq, ks, vq, vs = _quant_pool(rng, 4 * n_ring, ps, K, D)
+    _, (kp, ksp, vp, vsp) = _quant_pool(rng, 4 * n_ring, ps, K, D)
     tables = _tables(rng, B, n_ring, 4 * n_ring)
     start = jnp.asarray([0, 2 * ps + 3], jnp.int32)
     n_live = jnp.asarray([T, T - 5], jnp.int32)
     ref = get_backend("reference").prefill_attend(
-        q, kn, vn, kq, vq, tables, start, n_live, window=window,
-        k_scale=ks, v_scale=vs)
+        q, kn, vn, kp, vp, LAYER, tables, start, n_live, kv_heads=K,
+        window=window, k_scale=ksp, v_scale=vsp)
     out = get_backend("pallas").prefill_attend(
-        q, kn, vn, kq, vq, tables, start, n_live, window=window,
-        k_scale=ks, v_scale=vs)
+        q, kn, vn, kp, vp, LAYER, tables, start, n_live, kv_heads=K,
+        window=window, k_scale=ksp, v_scale=vsp)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=2e-5, rtol=2e-5)
@@ -272,12 +279,12 @@ def test_int8_mla_prefill_attend_matches_reference():
     tables = _tables(rng, B, maxp, P)
     start = jnp.asarray([0, ps + 3], jnp.int32)
     n_live = jnp.asarray([T, T - 5], jnp.int32)
+    pages, scales = latent_pool(cq, rq), dict(ckv_scale=stack_layer(cs),
+                                              krope_scale=stack_layer(rs))
     ref = get_backend("reference").mla_prefill_attend(
-        q, cq, rq, wkv_b, tables, start, n_live, nope=nope,
-        ckv_scale=cs, krope_scale=rs)
+        q, pages, LAYER, wkv_b, tables, start, n_live, nope=nope, **scales)
     out = get_backend("pallas").mla_prefill_attend(
-        q, cq, rq, wkv_b, tables, start, n_live, nope=nope,
-        ckv_scale=cs, krope_scale=rs)
+        q, pages, LAYER, wkv_b, tables, start, n_live, nope=nope, **scales)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=2e-5, rtol=2e-5)

@@ -25,13 +25,16 @@ import pytest
 from repro.configs import ServeConfig, get_arch, reduced
 from repro.models.attn_backend import get_backend, prefill_meta
 
+from pool_layout import LAYER, gqa_pool, latent_pool
+
 jax.config.update("jax_platform_name", "cpu")
 
 
 def _pool(rng, P, ps, K, D, dtype):
+    """K and V pools of per-head values drawn [P, ps, K, D], stacked."""
     k = jnp.asarray(rng.randn(P, ps, K, D), dtype)
     v = jnp.asarray(rng.randn(P, ps, K, D), dtype)
-    return k, v
+    return gqa_pool(k), gqa_pool(v)
 
 
 def _tables(rng, B, maxp, P):
@@ -73,9 +76,10 @@ def test_prefill_attend_matches_reference(B, H, K, D, ps, maxp, T, dtype):
     n_live = jnp.asarray(
         np.concatenate([[T], rng.randint(1, T + 1, size=B - 1)]), jnp.int32)
     ref = get_backend("reference").prefill_attend(
-        q, None, None, kp, vp, tables, start, n_live)
+        q, None, None, kp, vp, LAYER, tables, start, n_live, kv_heads=K)
     out = get_backend("pallas").prefill_attend(
-        q, q[:, :, :K], q[:, :, :K], kp, vp, tables, start, n_live)
+        q, q[:, :, :K], q[:, :, :K], kp, vp, LAYER, tables, start, n_live,
+        kv_heads=K)
     _assert_close(out, ref, dtype)
 
 
@@ -88,10 +92,11 @@ def test_prefill_attend_softcap():
     start = jnp.asarray([0, ps], jnp.int32)
     n_live = jnp.asarray([T, T - 3], jnp.int32)
     ref = get_backend("reference").prefill_attend(
-        q, None, None, kp, vp, tables, start, n_live, softcap=30.0)
-    out = get_backend("pallas").prefill_attend(
-        q, q[:, :, :K], q[:, :, :K], kp, vp, tables, start, n_live,
+        q, None, None, kp, vp, LAYER, tables, start, n_live, kv_heads=K,
         softcap=30.0)
+    out = get_backend("pallas").prefill_attend(
+        q, q[:, :, :K], q[:, :, :K], kp, vp, LAYER, tables, start, n_live,
+        kv_heads=K, softcap=30.0)
     _assert_close(out, ref, jnp.float32)
 
 
@@ -122,9 +127,11 @@ def test_windowed_prefill_attend_matches_reference(B, H, K, D, ps, n_ring, T,
     n_live = jnp.asarray(
         np.concatenate([[T], rng.randint(1, T + 1, size=B - 1)]), jnp.int32)
     ref = get_backend("reference").prefill_attend(
-        q, kn, vn, kp, vp, tables, start, n_live, window=window)
+        q, kn, vn, kp, vp, LAYER, tables, start, n_live, kv_heads=K,
+        window=window)
     out = get_backend("pallas").prefill_attend(
-        q, kn, vn, kp, vp, tables, start, n_live, window=window)
+        q, kn, vn, kp, vp, LAYER, tables, start, n_live, kv_heads=K,
+        window=window)
     _assert_close(out, ref, dtype)
 
 
@@ -146,10 +153,11 @@ def test_mla_prefill_attend_matches_reference(B, H, L, R, nope, vd, ps, maxp,
     tables = _tables(rng, B, maxp, P)
     start = jnp.asarray([0, ps + 3][:B], jnp.int32)
     n_live = jnp.asarray([T, max(T - 5, 1)][:B], jnp.int32)
+    pages = latent_pool(cc, cr)
     ref = get_backend("reference").mla_prefill_attend(
-        q, cc, cr, wkv_b, tables, start, n_live, nope=nope)
+        q, pages, LAYER, wkv_b, tables, start, n_live, nope=nope)
     out = get_backend("pallas").mla_prefill_attend(
-        q, cc, cr, wkv_b, tables, start, n_live, nope=nope)
+        q, pages, LAYER, wkv_b, tables, start, n_live, nope=nope)
     _assert_close(out, ref, dtype)
 
 

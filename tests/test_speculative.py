@@ -42,6 +42,8 @@ from repro.models.attn_backend import get_backend, verify_meta
 from repro.serving import (Engine, NgramProposer, accept_length,
                            speculation_k)
 
+from pool_layout import LAYER, gqa_pool, latent_pool, stack_layer
+
 jax.config.update("jax_platform_name", "cpu")
 
 
@@ -57,11 +59,13 @@ def _tables(rng, B, maxp, P):
 
 
 def _quant_pool(rng, P, ps, K, D):
+    """Stacked int8 K/V pool leaves of per-head values drawn [P, ps, K, D]:
+    (k, k_scale, v, v_scale)."""
     kf = rng.randn(P, ps, K, D).astype(np.float32)
     vf = rng.randn(P, ps, K, D).astype(np.float32)
     kq, ks = quantize_int8(jnp.asarray(kf))
     vq, vs = quantize_int8(jnp.asarray(vf))
-    return kq, ks, vq, vs
+    return gqa_pool(kq), stack_layer(ks), gqa_pool(vq), stack_layer(vs)
 
 
 VERIFY_CASES = [
@@ -85,8 +89,8 @@ VERIFY_CASES = [
 def _verify_inputs(rng, B, H, K, D, ps, maxp, Q, window=0):
     P = max(4, B + 1) * maxp
     q = jnp.asarray(rng.randn(B, Q, H, D), jnp.float32)
-    kp = jnp.asarray(rng.randn(P, ps, K, D), jnp.float32)
-    vp = jnp.asarray(rng.randn(P, ps, K, D), jnp.float32)
+    kp = gqa_pool(jnp.asarray(rng.randn(P, ps, K, D), jnp.float32))
+    vp = gqa_pool(jnp.asarray(rng.randn(P, ps, K, D), jnp.float32))
     tables = _tables(rng, B, maxp, P)
     # row 0 keeps the degenerate fresh-sequence case (pos=0, single query);
     # the rest sit anywhere the Q-token window still fits the table span
@@ -108,11 +112,11 @@ def test_verify_attend_matches_reference(B, H, K, D, ps, maxp, window,
                                                  maxp, Q, window)
     scale = 1.0 / math.sqrt(D)
     ref = get_backend("reference").verify_attend(
-        q, kp, vp, tables, pos, n_q, scale=scale, softcap=softcap,
-        window=window)
+        q, kp, vp, LAYER, tables, pos, n_q, kv_heads=K, scale=scale,
+        softcap=softcap, window=window)
     out = get_backend("pallas").verify_attend(
-        q, kp, vp, tables, pos, n_q, scale=scale, softcap=softcap,
-        window=window)
+        q, kp, vp, LAYER, tables, pos, n_q, kv_heads=K, scale=scale,
+        softcap=softcap, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=2e-5, rtol=2e-5)
@@ -136,9 +140,11 @@ def test_int8_verify_attend_matches_reference(Q):
         [[1], rng.randint(1, Q + 1, size=B - 1)]), jnp.int32)
     scale = 1.0 / math.sqrt(D)
     ref = get_backend("reference").verify_attend(
-        q, kq, vq, tables, pos, n_q, scale=scale, k_scale=ks, v_scale=vs)
+        q, kq, vq, LAYER, tables, pos, n_q, kv_heads=K, scale=scale,
+        k_scale=ks, v_scale=vs)
     out = get_backend("pallas").verify_attend(
-        q, kq, vq, tables, pos, n_q, scale=scale, k_scale=ks, v_scale=vs)
+        q, kq, vq, LAYER, tables, pos, n_q, kv_heads=K, scale=scale,
+        k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=2e-5, rtol=2e-5)
@@ -177,10 +183,11 @@ def test_mla_verify_attend_matches_reference(Q, shape):
         [[1], rng.randint(1, Q + 1, size=B - 1)]), jnp.int32)
     tables, pos, n_q = _idle_last(tables, pos, n_q)
     scale = 1.0 / math.sqrt(L + R)
+    pages = latent_pool(cc, cr)
     ref = get_backend("reference").mla_verify_attend(
-        q_eff, q_rope, cc, cr, tables, pos, n_q, scale=scale)
+        q_eff, q_rope, pages, LAYER, tables, pos, n_q, scale=scale)
     out = get_backend("pallas").mla_verify_attend(
-        q_eff, q_rope, cc, cr, tables, pos, n_q, scale=scale)
+        q_eff, q_rope, pages, LAYER, tables, pos, n_q, scale=scale)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=2e-5, rtol=2e-5)
@@ -200,12 +207,12 @@ def test_int8_mla_verify_attend_matches_reference():
     n_q = jnp.asarray(np.concatenate(
         [[1], rng.randint(1, Q + 1, size=B - 1)]), jnp.int32)
     scale = 1.0 / math.sqrt(L + R)
+    pages, scales = latent_pool(cq, rq), dict(ckv_scale=stack_layer(cs),
+                                              krope_scale=stack_layer(rs))
     ref = get_backend("reference").mla_verify_attend(
-        q_eff, q_rope, cq, rq, tables, pos, n_q, scale=scale,
-        ckv_scale=cs, krope_scale=rs)
+        q_eff, q_rope, pages, LAYER, tables, pos, n_q, scale=scale, **scales)
     out = get_backend("pallas").mla_verify_attend(
-        q_eff, q_rope, cq, rq, tables, pos, n_q, scale=scale,
-        ckv_scale=cs, krope_scale=rs)
+        q_eff, q_rope, pages, LAYER, tables, pos, n_q, scale=scale, **scales)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=2e-5, rtol=2e-5)
@@ -235,26 +242,28 @@ def test_verify_qlen1_reproduces_decode_bitexact(window, softcap, int8, maxp):
     if int8:
         kp, ks, vp, vs = _quant_pool(rng, 4 * maxp, ps, K, D)
     else:
-        kp = jnp.asarray(rng.randn(4 * maxp, ps, K, D), jnp.float32)
-        vp = jnp.asarray(rng.randn(4 * maxp, ps, K, D), jnp.float32)
+        kp = gqa_pool(jnp.asarray(rng.randn(4 * maxp, ps, K, D),
+                                  jnp.float32))
+        vp = gqa_pool(jnp.asarray(rng.randn(4 * maxp, ps, K, D),
+                                  jnp.float32))
         ks = vs = None
     tables = _tables(rng, B, maxp, 4 * maxp)
     pos = jnp.asarray(np.concatenate(
         [[0], rng.randint(1, maxp * ps, size=B - 1)]), jnp.int32)
     ones = jnp.ones((B,), jnp.int32)
     scale = 1.0 / math.sqrt(D)
-    kw = dict(scale=scale, softcap=softcap, window=window,
+    kw = dict(kv_heads=K, scale=scale, softcap=softcap, window=window,
               k_scale=ks, v_scale=vs)
     pal = get_backend("pallas")
     np.testing.assert_array_equal(
-        np.asarray(pal.verify_attend(q[:, None], kp, vp, tables, pos, ones,
-                                     **kw)[:, 0]),
-        np.asarray(pal.decode_attend(q, kp, vp, tables, pos, **kw)))
+        np.asarray(pal.verify_attend(q[:, None], kp, vp, LAYER, tables, pos,
+                                     ones, **kw)[:, 0]),
+        np.asarray(pal.decode_attend(q, kp, vp, LAYER, tables, pos, **kw)))
     ref = get_backend("reference")
     np.testing.assert_allclose(
-        np.asarray(ref.verify_attend(q[:, None], kp, vp, tables, pos, ones,
-                                     **kw)[:, 0], np.float32),
-        np.asarray(ref.decode_attend(q, kp, vp, tables, pos, **kw),
+        np.asarray(ref.verify_attend(q[:, None], kp, vp, LAYER, tables, pos,
+                                     ones, **kw)[:, 0], np.float32),
+        np.asarray(ref.decode_attend(q, kp, vp, LAYER, tables, pos, **kw),
                    np.float32),
         atol=1e-6, rtol=1e-6)
 
@@ -281,12 +290,13 @@ def _mla_verify_qlen1_is_decode(shape):
     ones = jnp.ones((B,), jnp.int32)
     tables, pos, ones = _idle_last(tables, pos, ones)
     scale = 1.0 / math.sqrt(L + R)
+    pages = latent_pool(cc, cr)
     pal = get_backend("pallas")
     np.testing.assert_array_equal(
         np.asarray(pal.mla_verify_attend(q_eff[:, None], q_rope[:, None],
-                                         cc, cr, tables, pos, ones,
+                                         pages, LAYER, tables, pos, ones,
                                          scale=scale)[:, 0]),
-        np.asarray(pal.mla_decode_attend(q_eff, q_rope, cc, cr, tables,
+        np.asarray(pal.mla_decode_attend(q_eff, q_rope, pages, LAYER, tables,
                                          pos, scale=scale)))
 
 

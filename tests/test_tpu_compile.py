@@ -30,9 +30,12 @@ from repro.kernels.ragged_prefill import (mla_ragged_prefill_attend,
                                           ragged_prefill_attend)
 from repro.kernels.ragged_prefill.kernel import fit_q_block
 from repro.kernels.rbm_cd import gemm_sigmoid
+from repro.models.attention import page_width
 
 BF16, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
 
+NL = 4           # layers of a stacked pool
+LAYER = ((), I32)  # the layer index operand
 PS = 16          # serving page size
 B = 8            # decode slots
 CHUNK = 256      # prefill chunk tokens
@@ -75,7 +78,13 @@ def _compile(one_chip, fn, *shapes, **kwargs):
 
 
 def _pool(n_pages, K, D, dtype):
-    return ((n_pages, PS, K, D), dtype)
+    """A layer-stacked lane-dense GQA pool: K heads of D lanes a slot."""
+    return ((NL, n_pages, PS, page_width(K * D)), dtype)
+
+
+def _latent(n_pages, L, R, dtype):
+    """A layer-stacked lane-dense latent pool: ckv ++ krope a slot."""
+    return ((NL, n_pages, PS, page_width(L + R)), dtype)
 
 
 # published widths: qwen2-0.5b (H=14, K=2, D=64) at an 8 x 1,024-token pool;
@@ -95,11 +104,11 @@ def test_decode_qwen2(one_chip, kv):
     H, K, D, n = QWEN["H"], QWEN["K"], QWEN["D"], QWEN["n_pages"]
     P = _n_phys(n)
     dt = I8 if kv == "int8" else BF16
-    scales = ({"k_scale": ((P, PS, K), BF16), "v_scale": ((P, PS, K), BF16)}
-              if kv == "int8" else {})
+    scales = ({"k_scale": ((NL, P, PS, K), BF16),
+               "v_scale": ((NL, P, PS, K), BF16)} if kv == "int8" else {})
     _compile(one_chip, paged_attention_decode, ((B, H, D), BF16),
-             _pool(P, K, D, dt), _pool(P, K, D, dt), ((B, n), I32),
-             ((B,), I32), scale=1 / math.sqrt(D), **scales)
+             _pool(P, K, D, dt), _pool(P, K, D, dt), LAYER, ((B, n), I32),
+             ((B,), I32), kv_heads=K, scale=1 / math.sqrt(D), **scales)
 
 
 # the benchmark's decode programs (bench/configs): qwen2-0.5b's 64 slots
@@ -115,23 +124,25 @@ BENCH_DECODE = [
 def test_decode_benchmark_shapes(one_chip, shape):
     H, K, D, rows, P = (shape[k] for k in ("H", "K", "D", "B", "P"))
     _compile(one_chip, paged_attention_decode, ((rows, H, D), BF16),
-             _pool(P, K, D, BF16), _pool(P, K, D, BF16), ((rows, 256), I32),
-             ((rows,), I32), scale=1 / math.sqrt(D))
+             _pool(P, K, D, BF16), _pool(P, K, D, BF16), LAYER,
+             ((rows, 256), I32), ((rows,), I32), kv_heads=K,
+             scale=1 / math.sqrt(D))
 
 
 def test_decode_windowed_starcoder2(one_chip):
     H, K, D, n = SC2["H"], SC2["K"], SC2["D"], SC2["n_pages"]
     P = _n_phys(n)
     _compile(one_chip, paged_attention_decode, ((B, H, D), BF16),
-             _pool(P, K, D, BF16), _pool(P, K, D, BF16), ((B, n), I32),
-             ((B,), I32), scale=1 / math.sqrt(D), window=SC2["window"])
+             _pool(P, K, D, BF16), _pool(P, K, D, BF16), LAYER,
+             ((B, n), I32), ((B,), I32), kv_heads=K, scale=1 / math.sqrt(D),
+             window=SC2["window"])
 
 
 def test_decode_mla_deepseek_v2(one_chip):
     H, L, R, n = DSV2["H"], DSV2["L"], DSV2["R"], DSV2["n_pages"]
     P = _n_phys(n)
     _compile(one_chip, mla_paged_attention_decode, ((B, H, L), BF16),
-             ((B, H, R), BF16), ((P, PS, L), BF16), ((P, PS, R), BF16),
+             ((B, H, R), BF16), _latent(P, L, R, BF16), LAYER,
              ((B, n), I32), ((B,), I32), scale=1 / math.sqrt(192))
 
 
@@ -139,15 +150,16 @@ def test_verify_qwen2(one_chip):
     H, K, D, n = QWEN["H"], QWEN["K"], QWEN["D"], QWEN["n_pages"]
     P = _n_phys(n)
     _compile(one_chip, paged_attention_verify, ((B, 5, H, D), BF16),
-             _pool(P, K, D, BF16), _pool(P, K, D, BF16), ((B, n), I32),
-             ((B,), I32), ((B,), I32), scale=1 / math.sqrt(D))
+             _pool(P, K, D, BF16), _pool(P, K, D, BF16), LAYER,
+             ((B, n), I32), ((B,), I32), ((B,), I32), kv_heads=K,
+             scale=1 / math.sqrt(D))
 
 
 def test_verify_mla_deepseek_v2(one_chip):
     H, L, R, n = DSV2["H"], DSV2["L"], DSV2["R"], DSV2["n_pages"]
     P = _n_phys(n)
     _compile(one_chip, mla_paged_attention_verify, ((B, 5, H, L), BF16),
-             ((B, 5, H, R), BF16), ((P, PS, L), BF16), ((P, PS, R), BF16),
+             ((B, 5, H, R), BF16), _latent(P, L, R, BF16), LAYER,
              ((B, n), I32), ((B,), I32), ((B,), I32),
              scale=1 / math.sqrt(192))
 
@@ -165,10 +177,10 @@ def test_decode_mla_deepseek_v2_cell(one_chip, kv):
     H, L, R = DSV2["H"], DSV2["L"], DSV2["R"]
     rows, n, P = DSV2_CELL["B"], DSV2_CELL["n_pages"], DSV2_CELL["P"]
     dt = I8 if kv == "int8" else BF16
-    scales = ({"ckv_scale": ((P, PS), BF16), "krope_scale": ((P, PS), BF16)}
-              if kv == "int8" else {})
+    scales = ({"ckv_scale": ((NL, P, PS), BF16),
+               "krope_scale": ((NL, P, PS), BF16)} if kv == "int8" else {})
     _compile(one_chip, mla_paged_attention_decode, ((rows, H, L), BF16),
-             ((rows, H, R), BF16), ((P, PS, L), dt), ((P, PS, R), dt),
+             ((rows, H, R), BF16), _latent(P, L, R, dt), LAYER,
              ((rows, n), I32), ((rows,), I32), scale=0.1147, **scales)
 
 
@@ -176,7 +188,7 @@ def test_verify_mla_deepseek_v2_cell(one_chip):
     H, L, R = DSV2["H"], DSV2["L"], DSV2["R"]
     rows, n, P = DSV2_CELL["B"], DSV2_CELL["n_pages"], DSV2_CELL["P"]
     _compile(one_chip, mla_paged_attention_verify, ((rows, 2, H, L), BF16),
-             ((rows, 2, H, R), BF16), ((P, PS, L), BF16), ((P, PS, R), BF16),
+             ((rows, 2, H, R), BF16), _latent(P, L, R, BF16), LAYER,
              ((rows, n), I32), ((rows,), I32), ((rows,), I32), scale=0.1147)
 
 
@@ -184,8 +196,8 @@ def test_prefill_mla_deepseek_v2_cell(one_chip):
     H, L, R = DSV2["H"], DSV2["L"], DSV2["R"]
     n, P = DSV2_CELL["n_pages"], DSV2_CELL["P"]
     _compile(one_chip, mla_ragged_prefill_attend,
-             ((1, 512, H, DSV2["nope"] + R), BF16), ((P, PS, L), BF16),
-             ((P, PS, R), BF16), ((L, H, DSV2["nope"] + DSV2["vd"]), BF16),
+             ((1, 512, H, DSV2["nope"] + R), BF16), _latent(P, L, R, BF16),
+             LAYER, ((L, H, DSV2["nope"] + DSV2["vd"]), BF16),
              ((1, n), I32), ((1,), I32), ((1,), I32), nope=DSV2["nope"],
              scale=0.1147)
 
@@ -201,8 +213,8 @@ def test_prefill_qwen2(one_chip):
     H, K, D, n = QWEN["H"], QWEN["K"], QWEN["D"], QWEN["n_pages"]
     P = _n_phys(n)
     _compile(one_chip, ragged_prefill_attend, ((B, CHUNK, H, D), BF16),
-             None, None, _pool(P, K, D, BF16), _pool(P, K, D, BF16),
-             ((B, n), I32), ((B,), I32), ((B,), I32))
+             None, None, _pool(P, K, D, BF16), _pool(P, K, D, BF16), LAYER,
+             ((B, n), I32), ((B,), I32), ((B,), I32), kv_heads=K)
 
 
 def test_prefill_windowed_starcoder2(one_chip):
@@ -210,16 +222,17 @@ def test_prefill_windowed_starcoder2(one_chip):
     P = _n_phys(n)
     _compile(one_chip, ragged_prefill_attend, ((B, CHUNK, H, D), BF16),
              ((B, CHUNK, K, D), BF16), ((B, CHUNK, K, D), BF16),
-             _pool(P, K, D, BF16), _pool(P, K, D, BF16), ((B, n), I32),
-             ((B,), I32), ((B,), I32), window=SC2["window"])
+             _pool(P, K, D, BF16), _pool(P, K, D, BF16), LAYER,
+             ((B, n), I32), ((B,), I32), ((B,), I32), kv_heads=K,
+             window=SC2["window"])
 
 
 def test_prefill_mla_deepseek_v2(one_chip):
     H, L, R, n = DSV2["H"], DSV2["L"], DSV2["R"], DSV2["n_pages"]
     P = _n_phys(n)
     _compile(one_chip, mla_ragged_prefill_attend,
-             ((B, CHUNK, H, DSV2["nope"] + R), BF16), ((P, PS, L), BF16),
-             ((P, PS, R), BF16), ((L, H, DSV2["nope"] + DSV2["vd"]), BF16),
+             ((B, CHUNK, H, DSV2["nope"] + R), BF16), _latent(P, L, R, BF16),
+             LAYER, ((L, H, DSV2["nope"] + DSV2["vd"]), BF16),
              ((B, n), I32), ((B,), I32), ((B,), I32), nope=DSV2["nope"])
 
 
@@ -237,7 +250,7 @@ def test_prefill_widest_table(one_chip):
     assert fit_q_block(CHUNK, H // K, WIDEST) == 16
     _compile(one_chip, ragged_prefill_attend, ((1, CHUNK, H, D), BF16),
              None, None, _pool(n + 1, K, D, BF16), _pool(n + 1, K, D, BF16),
-             ((1, n), I32), ((1,), I32), ((1,), I32))
+             LAYER, ((1, n), I32), ((1,), I32), ((1,), I32), kv_heads=K)
 
 
 def test_prefill_table_past_budget_is_refused():
